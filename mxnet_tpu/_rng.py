@@ -15,6 +15,7 @@ import threading
 
 import jax
 import numpy as _np
+from jax._src import core as _core
 
 _state = threading.local()
 
@@ -73,12 +74,7 @@ def next_key():
     ps = _providers()
     if ps:
         return ps[-1].next_key()
-    try:
-        clean = jax.core.trace_state_clean()
-    except AttributeError:
-        from jax._src import core as _core
-        clean = _core.trace_state_clean()
-    if clean:
+    if _core.trace_state_clean():
         # normal eager path: async split, no device sync
         key = _global()
         key, sub = jax.random.split(key)

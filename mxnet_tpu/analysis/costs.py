@@ -31,7 +31,7 @@ llama decode loop's per-token cost is ``length ×`` the body, not 1 ×
 
 import math
 
-from jax import core as _core
+from jax.extend import core as _core
 
 from .device_specs import get_device_spec, machine_balance
 from .walker import eqn_op

@@ -23,7 +23,7 @@ scan/while/cond body repeats every iteration:
   (default 0) equations die.
 """
 
-from jax import core as _core
+from jax.extend import core as _core
 
 from . import register_rule
 from ..walker import iter_eqns

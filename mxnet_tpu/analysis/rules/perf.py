@@ -39,7 +39,7 @@ dead-man's-switch tests pass ``ignore_suppressions=True`` to prove the
 detector still fires underneath the suppression.
 """
 
-from jax import core as _core
+from jax.extend import core as _core
 
 from . import register_rule
 from ..costs import (CHEAP_PRIMS, COLLECTIVE_PRIMS, MOVEMENT_PRIMS,

@@ -22,7 +22,7 @@ rules see through ``jax.checkpoint`` and control-flow wrappers.
 import numpy as _np
 
 import jax
-from jax import core as _core
+from jax.extend import core as _core
 
 from ..context import current_context
 

@@ -171,10 +171,10 @@ def _reference_peak(jaxpr, donated, const_bytes):
     last_use = {}
     for i, eqn in enumerate(jaxpr.eqns):
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jax.extend.core.Literal):
                 last_use[id(v)] = i
     for v in jaxpr.outvars:
-        if not isinstance(v, jax.core.Literal):
+        if not isinstance(v, jax.extend.core.Literal):
             last_use[id(v)] = len(jaxpr.eqns)
     pinned = const_bytes + sum(
         _var_bytes(v) for i, v in enumerate(jaxpr.invars)
