@@ -43,7 +43,7 @@ import time
 BASELINES = {'bf16': 2085.51, 'fp32': 1076.81}
 TRAIN_BASELINE = 49.48     # K80 train img/s, perf.md:230
 BERT_BASELINE = 100.0      # V100 fp16 fine-tune anchor; none in-repo
-V5E_BF16_FLOPS = 394e12    # v5e peak bf16 TFLOP/s (MFU denominator)
+V5E_BF16_FLOPS = 197e12    # v5e peak bf16 FLOP/s (MFU denominator); int8 is 394e12
 # ResNet-50 @224 forward FLOPs per image, 2-flops-per-MAC convention:
 # 7.72e9 = the exact conv+fc FLOP census of our compiled forward HLO
 # (docs/perf_resnet.md), consistent with He et al.'s 3.8 GMACs.  Round-2

@@ -59,11 +59,21 @@ class Context:
                 devs = jax.local_devices(backend='cpu') \
                     if _has_platform('cpu') else jax.local_devices()
             else:
-                # tpu (or gpu alias): any non-cpu accelerator backend
+                # tpu (or gpu alias): any non-cpu accelerator backend.
+                # No CPU stand-in and no wrap-around: a chip that is not
+                # there is an error, not chip 0
                 devs = [d for d in jax.local_devices()
                         if d.platform != 'cpu']
                 if not devs:
-                    devs = jax.local_devices()
+                    raise RuntimeError(
+                        f'{self!r}: no accelerator device; '
+                        f'jax backend is {jax.default_backend()!r}')
+                if not 0 <= self.device_id < len(devs):
+                    raise ValueError(
+                        f'{self!r}: device_id out of range, '
+                        f'{len(devs)} accelerator device(s) present')
+                self._jax_device = devs[self.device_id]
+                return self._jax_device
             self._jax_device = devs[self.device_id % len(devs)]
         return self._jax_device
 

@@ -8,6 +8,10 @@ priority scheduling (priority=-i for comm/compute overlap) is a no-op —
 XLA's async collectives already overlap — but the argument is accepted.
 """
 
+import logging
+
+import jax
+
 from ..kvstore import create as _create_kvstore
 from ..kvstore.base import KVStoreBase
 from .. import optimizer as opt
@@ -17,6 +21,15 @@ from ..ndarray.ndarray import NDArray
 
 class _FusedUnsupported(Exception):
     """Optimizer could not be traced into the fused update executable."""
+
+
+# what an optimizer raises when it has no traceable ``step``: the base
+# class's stub, or host control flow on a traced lr/wd/t. Anything else
+# (a kernel the compiler refuses, a sharding error) is a fault and
+# propagates
+_UNTRACEABLE = (NotImplementedError, jax.errors.ConcretizationTypeError,
+                jax.errors.TracerArrayConversionError,
+                jax.errors.TracerIntegerConversionError)
 
 
 _FUSED_SENTINEL = object()
@@ -78,6 +91,7 @@ class Trainer:
                                          **optimizer_params)
         self._states = {}
         self._fused_cache = {}
+        self._fused_fallback_taken = False
 
     def _reset_kvstore(self):
         self._kv_initialized = False
@@ -275,7 +289,12 @@ class Trainer:
             return
         try:
             self._fused_update(live)
-        except _FusedUnsupported:
+        except _FusedUnsupported as e:
+            if not self._fused_fallback_taken:
+                self._fused_fallback_taken = True
+                logging.getLogger(__name__).warning(
+                    '%r does not trace into one fused update (%s); '
+                    'updating parameter by parameter', self._optimizer, e)
             for i, param in live:
                 datas = param.list_data()
                 grads = param.list_grad()
@@ -500,9 +519,10 @@ class Trainer:
                 # trace-check BEFORE advancing update counts so a failed
                 # optimizer falls back without double-counting
                 jax.eval_shape(fn, praws, graws, sraws, *zeros)
-            except Exception as e:
+            except _UNTRACEABLE as e:
                 self._fused_cache[key] = _FUSED_SENTINEL
-                raise _FusedUnsupported(str(e))
+                raise _FusedUnsupported(
+                    f'{type(e).__name__}: {e}'.splitlines()[0])
             self._fused_cache[key] = fn
         elif fn is _FUSED_SENTINEL:
             raise _FusedUnsupported('previously failed')

@@ -27,10 +27,7 @@ _NEG_INF = -1e30
 
 
 def _on_tpu():
-    try:
-        return jax.devices()[0].platform == 'tpu'
-    except Exception:
-        return False
+    return jax.devices()[0].platform == 'tpu'
 
 
 # ------------------------------------------------------------------ kernel

@@ -41,13 +41,13 @@ def _ln_kernel(x_ref, g_ref, b_ref, o_ref, *, eps, rms):
 
 
 def _block_rows(n, d):
-    """Largest power-of-two row block whose fp32 image fits the VMEM
-    budget (at least 1 row; sublane-friendly multiples of 8 preferred)."""
-    bn = max(1, _VMEM_BUDGET // (4 * d))
-    bn = 1 << (bn.bit_length() - 1)
-    while bn > 1 and n % bn:
-        bn //= 2
-    return bn
+    """Row block whose fp32 image fits the VMEM budget: the whole array
+    when it fits, else a power of two (>= 8, Mosaic's sublane rule) with
+    a ragged last block — rows are independent, so its out-of-range rows
+    compute garbage that is never written back."""
+    cap = max(8, _VMEM_BUDGET // (4 * d))
+    cap = 1 << (cap.bit_length() - 1)
+    return n if n <= cap else cap
 
 
 def _ln_pallas(x2, gamma, beta, eps, rms, interpret, out_dtype):
@@ -67,7 +67,7 @@ def _ln_pallas(x2, gamma, beta, eps, rms, interpret, out_dtype):
 
     return pl.pallas_call(
         kernel,
-        grid=(n // bn,),
+        grid=(pl.cdiv(n, bn),),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bn, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), out_dtype),

@@ -53,16 +53,21 @@ def pallas_disabled():
 
 
 def _block_rows(n, arrays):
-    """Largest power-of-two row block keeping `arrays` fp32 lane tiles
-    inside the VMEM budget (same sizing rule as fused_norms)."""
-    bn = max(1, _VMEM_BUDGET // (4 * _LANES * arrays))
-    bn = 1 << (bn.bit_length() - 1)
-    while bn > 1 and n % bn:
-        bn //= 2
-    return bn
+    """Row block for an (n, 128) fp32 view with `arrays` operands live.
+
+    Mosaic takes a block whose row count is a multiple of 8 or the whole
+    array. So: the whole array when it fits the VMEM budget (any n, also
+    6 or 12 rows), else the largest power of two inside the budget, with
+    a ragged last block (the grid is ``cdiv(n, bn)``; out-of-range rows
+    of the last block are not written back)."""
+    cap = max(8, _VMEM_BUDGET // (4 * _LANES * arrays))
+    cap = 1 << (cap.bit_length() - 1)
+    return n if n <= cap else cap
 
 
 def _tileable(*arrs):
+    """Shape-decided branch: fp32 operands whose size fills whole 128-lane
+    rows take the kernel; everything else takes the XLA update."""
     size = arrs[0].size
     return (size > 0 and size % _LANES == 0
             and all(a.dtype == jnp.float32 for a in arrs))
@@ -128,7 +133,7 @@ def adam_step(w, g, m, v, lr, wd, t, *, beta1, beta2, epsilon,
     tile = pl.BlockSpec((bn, _LANES), lambda i: (i, 0))
     ow, om, ov = pl.pallas_call(
         kernel,
-        grid=(r // bn,),
+        grid=(pl.cdiv(r, bn),),
         in_specs=[pl.BlockSpec((4,), lambda i: (0,)), tile, tile, tile,
                   tile],
         out_specs=[tile, tile, tile],
@@ -167,7 +172,7 @@ def sgd_mom_step(w, g, mom, lr, wd, *, momentum, rescale_grad=1.0,
     tile = pl.BlockSpec((bn, _LANES), lambda i: (i, 0))
     ow, omom = pl.pallas_call(
         kernel,
-        grid=(r // bn,),
+        grid=(pl.cdiv(r, bn),),
         in_specs=[pl.BlockSpec((2,), lambda i: (0,)), tile, tile, tile],
         out_specs=[tile, tile],
         out_shape=[jax.ShapeDtypeStruct((r, _LANES), jnp.float32)] * 2,

@@ -56,9 +56,15 @@ def _adam_ref(w, g, m, v, lr, wd, t):
     return w - lr * mhat / (jnp.sqrt(vhat) + EPS), m2, v2
 
 
-def test_adam_kernel_slot_updates_bit_exact():
-    w, g = _rand(0, (8, 384)), _rand(1, (8, 384))
-    m, v = _rand(2, (8, 384), 0.1), jnp.abs(_rand(3, (8, 384), 0.01))
+# 24 rows of 128 lanes; 6 rows (a BERT bias: one whole-array block);
+# 1031 rows (past the VMEM cap: 512-row blocks, ragged last one)
+_OPT_SHAPES = [(8, 384), (768,), (1031, 128)]
+
+
+@pytest.mark.parametrize('shape', _OPT_SHAPES)
+def test_adam_kernel_slot_updates_bit_exact(shape):
+    w, g = _rand(0, shape), _rand(1, shape)
+    m, v = _rand(2, shape, 0.1), jnp.abs(_rand(3, shape, 0.01))
     t, lr, wd = 5, 0.01, 0.001
     wr, mr, vr = _adam_ref(w, g, m, v, lr, wd, t)
     ow, om, ov = adam_step(w, g, m, v, lr, wd, t, beta1=B1, beta2=B2,
@@ -66,8 +72,9 @@ def test_adam_kernel_slot_updates_bit_exact():
     assert bool((om == mr).all()), 'adam mean slot must be bit-exact'
     assert bool((ov == vr).all()), 'adam var slot must be bit-exact'
     # weight: ulp-level — the traced lr operand vs the folded constant
-    # changes one contraction in the final fma
-    assert bool(jnp.allclose(ow, wr, rtol=1e-6, atol=1e-6))
+    # changes one contraction in the final fma (2e-6: the worst of
+    # 132k elements where 1e-6 covered 3k)
+    assert bool(jnp.allclose(ow, wr, rtol=1e-6, atol=2e-6))
 
 
 def test_adam_kernel_traced_hyper_no_recompile():
@@ -90,9 +97,9 @@ def test_adam_kernel_traced_hyper_no_recompile():
     assert bool(jnp.isfinite(w).all())
 
 
-def test_sgd_mom_kernel_bit_exact():
-    w, g, mom = _rand(0, (16, 128)), _rand(1, (16, 128)), \
-        _rand(2, (16, 128), 0.1)
+@pytest.mark.parametrize('shape', [(16, 128)] + _OPT_SHAPES[1:])
+def test_sgd_mom_kernel_bit_exact(shape):
+    w, g, mom = _rand(0, shape), _rand(1, shape), _rand(2, shape, 0.1)
     lr, wd, mu = 0.05, 0.01, 0.9
 
     @jax.jit
