@@ -1108,6 +1108,8 @@ def main():
         _cpu_guard.force_cpu()
 
     import mxnet_tpu as mx
+    from mxnet_tpu import _compile_cache
+    _compile_cache.place()
 
     load = _warn_contention()
     if args.model == 'train_aba':

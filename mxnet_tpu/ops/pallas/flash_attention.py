@@ -128,13 +128,15 @@ def _flash_call(q, k, v, sm_scale, causal, block_q, block_k, interpret,
         ]
         acc, m, l = pl.pallas_call(
             kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-            out_shape=out_shape, interpret=interpret)(q, k, v)
+            out_shape=out_shape, interpret=interpret,
+            name='mx_flash_attention_stats')(q, k, v)
         return acc, m[:, 0], l[:, 0]
     out_specs = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0))
     out_shape = jax.ShapeDtypeStruct((bh, t, d), q.dtype)
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret)(q, k, v)
+        out_shape=out_shape, interpret=interpret,
+        name='mx_flash_attention')(q, k, v)
 
 
 def _flash_fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):

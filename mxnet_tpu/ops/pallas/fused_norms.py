@@ -72,6 +72,7 @@ def _ln_pallas(x2, gamma, beta, eps, rms, interpret, out_dtype):
         out_specs=pl.BlockSpec((bn, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), out_dtype),
         interpret=interpret,
+        name='mx_fused_rms_norm' if rms else 'mx_fused_layer_norm',
     )(*args)
 
 

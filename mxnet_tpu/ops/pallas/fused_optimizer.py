@@ -142,6 +142,7 @@ def adam_step(w, g, m, v, lr, wd, t, *, beta1, beta2, epsilon,
         # outputs (operand indices count the hyper vector)
         input_output_aliases={1: 0, 3: 1, 4: 2},
         interpret=interpret,
+        name='mx_adam_step',
     )(hyper, w2, g2, m2, v2)
     return ow.reshape(shape), om.reshape(shape), ov.reshape(shape)
 
@@ -178,5 +179,6 @@ def sgd_mom_step(w, g, mom, lr, wd, *, momentum, rescale_grad=1.0,
         out_shape=[jax.ShapeDtypeStruct((r, _LANES), jnp.float32)] * 2,
         input_output_aliases={1: 0, 3: 1},
         interpret=interpret,
+        name='mx_sgd_mom_step',
     )(hyper, w2, g2, m2)
     return ow.reshape(shape), omom.reshape(shape)

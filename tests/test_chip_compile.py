@@ -1,0 +1,145 @@
+"""The kernels of the chip_smoke.py train path, compiled for a described
+TPU v5e at BERT-base shapes (bert_12_768_12, batch 32 x sequence 128).
+
+The TPU's compiler is installed where the tests run; it compiles for a
+chip that is described and not attached. Interpret mode cannot show what
+it refuses (a block whose rows are no multiple of 8, too much VMEM), so
+these compiles guard the main path at no chip time. A compile that
+passes is not a chip run.
+
+Only this file describes a topology, and only from inside the
+module-scoped fixture: the process that does so loads the TPU library
+and keeps it until it exits.
+"""
+
+import importlib
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+# the package re-exports functions under the modules' names
+flash_mod = importlib.import_module('mxnet_tpu.ops.pallas.flash_attention')
+norms_mod = importlib.import_module('mxnet_tpu.ops.pallas.fused_norms')
+opt_mod = importlib.import_module('mxnet_tpu.ops.pallas.fused_optimizer')
+
+BATCH, SEQ, UNITS, HEADS, HIDDEN, VOCAB = 32, 128, 768, 12, 3072, 30522
+
+# every distinct parameter shape of the smoke's classifier that the fused
+# optimizer's gate admits (test_shapes_cover_bert_base pins the list)
+ADAM_SHAPES = [
+    (UNITS,), (3 * UNITS,), (HIDDEN,),              # biases, LN gamma/beta
+    (2, UNITS),                                     # token types, head
+    (512, UNITS),                                   # positions
+    (UNITS, UNITS), (3 * UNITS, UNITS),             # proj / pooler, qkv
+    (HIDDEN, UNITS), (UNITS, HIDDEN),               # ffn1, ffn2
+    (VOCAB, UNITS),                                 # word embedding
+]
+
+
+@pytest.fixture(scope='module')
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:      # noqa: BLE001 - any failure means no compiler
+        pytest.skip(f'no v5e:2x2 topology can be described here: {e}')
+    # a compile for a described device is written to the persistent
+    # cache but cannot be read back without a chip
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update('jax_enable_compilation_cache', was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope='module')
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The dispatch gates ask jax.devices(), which is the CPU here: steer
+    them onto their Pallas branch, as the chip would."""
+    for mod in (flash_mod, norms_mod, opt_mod):
+        monkeypatch.setattr(mod, '_on_tpu', lambda: True)
+
+
+def _compile(fn, *shapes):
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+def test_shapes_cover_bert_base():
+    """ADAM_SHAPES is what the model has: one encoder layer carries every
+    distinct shape of twelve."""
+    import numpy as np
+    import mxnet_tpu as mx
+    from chip_smoke import Config, build
+    net, _, _, _ = build(Config(layers=1, batch=2, seq=8), mx.cpu(0))
+    shapes = {tuple(p.shape) for p in net.collect_params().values()}
+    admitted = {s for s in shapes if np.prod(s) % 128 == 0}
+    assert admitted == set(ADAM_SHAPES)
+    assert shapes - admitted == {(2,)}          # the head's bias: XLA
+
+
+@pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16])
+def test_flash_attention_fwd_bwd_compiles(one_chip, on_tpu, dtype):
+    qkv = jax.ShapeDtypeStruct((BATCH, HEADS, SEQ, UNITS // HEADS), dtype,
+                               sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_mod.flash_attention(q, k, v)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    assert _compile(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv) == 1
+
+
+@pytest.mark.parametrize('rows', [BATCH * SEQ, BATCH, 100])
+@pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16])
+def test_layer_norm_fwd_bwd_compiles(one_chip, on_tpu, dtype, rows):
+    # 4096 rows: the encoder; 32: a pooled vector; 100: no multiple of 8
+    x = jax.ShapeDtypeStruct((rows, UNITS), dtype, sharding=one_chip)
+    g = jax.ShapeDtypeStruct((UNITS,), jnp.float32, sharding=one_chip)
+
+    def loss(x, gamma, beta):
+        out = norms_mod.fused_layer_norm(x, gamma, beta, 1e-12)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    assert _compile(jax.grad(loss, argnums=(0, 1, 2)), x, g, g) == 1
+
+
+def _opt_shapes(shape, one_chip):
+    w = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    assert opt_mod._tileable(w), 'the gate sends this shape to XLA'
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    t = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    return w, lr, t
+
+
+@pytest.mark.parametrize('shape', ADAM_SHAPES, ids=str)
+def test_adam_step_compiles(one_chip, shape):
+    w, lr, t = _opt_shapes(shape, one_chip)
+
+    def step(w, g, m, v, lr, wd, t):
+        return opt_mod.adam_step(w, g, m, v, lr, wd, t, beta1=0.9,
+                                 beta2=0.999, epsilon=1e-8)
+
+    assert _compile(step, w, w, w, w, lr, lr, t) == 1
+
+
+@pytest.mark.parametrize('shape', [(UNITS,), (2, UNITS), (UNITS, HIDDEN),
+                                   (VOCAB, UNITS)], ids=str)
+def test_sgd_mom_step_compiles(one_chip, shape):
+    # the three shapes the old block rule had refused, and one it took
+    w, lr, _ = _opt_shapes(shape, one_chip)
+
+    def step(w, g, mom, lr, wd):
+        return opt_mod.sgd_mom_step(w, g, mom, lr, wd, momentum=0.9)
+
+    assert _compile(step, w, w, w, lr, lr) == 1

@@ -20,6 +20,61 @@ def test_context():
     assert d[mx.cpu(0)] == 1
 
 
+def test_tpu_context_needs_a_real_chip():
+    """No CPU stand-in for mx.tpu(), and no wrap of a chip index that
+    does not exist onto one that does."""
+    import jax
+    assert jax.default_backend() == 'cpu'
+    with pytest.raises(RuntimeError, match='no accelerator'):
+        mx.tpu(0).to_jax()
+    with pytest.raises(RuntimeError, match='no accelerator'):
+        mx.gpu(3).to_jax()
+
+    class _Chip:
+        platform = 'tpu'
+
+    chips = [_Chip(), _Chip()]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, 'local_devices', lambda *a, **k: chips)
+        assert mx.tpu(1).to_jax() is chips[1]
+        for bad in (2, 5, -1):
+            with pytest.raises(ValueError, match='out of range'):
+                mx.tpu(bad).to_jax()
+
+
+@pytest.mark.parametrize('env_dir', [None, 'from_env'])
+def test_compile_cache_placed_from_outside(env_dir, monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR set: the program sets nothing. Unset:
+    the one fixed path under the checkout."""
+    import os
+    import jax
+    from mxnet_tpu import _compile_cache
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert _compile_cache.CHECKOUT_CACHE == os.path.join(repo, '.jax_cache')
+    before = jax.config.jax_compilation_cache_dir
+    updates = []
+    real_update = jax.config.update
+
+    def spy(name, val):
+        updates.append((name, val))
+        real_update(name, val)
+
+    monkeypatch.setattr(jax.config, 'update', spy)
+    try:
+        if env_dir is None:
+            monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+            assert _compile_cache.place() == _compile_cache.CHECKOUT_CACHE
+            assert updates == [('jax_compilation_cache_dir',
+                                _compile_cache.CHECKOUT_CACHE)]
+        else:
+            monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR',
+                               str(tmp_path / env_dir))
+            assert _compile_cache.place() == before
+            assert updates == []
+    finally:
+        real_update('jax_compilation_cache_dir', before)
+
+
 def test_naive_engine_switch():
     with mx.engine.naive_engine():
         x = mx.np.ones((2, 2)) * 3
