@@ -116,7 +116,10 @@ def build(cfg, ctx):
     s = mx.np.array(np.zeros_like(toks), ctx=ctx)
     y = mx.np.array(labels, ctx=ctx)
 
-    net(x[:1], s[:1])                 # deferred shapes resolve eagerly
+    # deferred shapes resolve eagerly; wait for it, or the bulking engine
+    # carries these ops into the first step's segment and the second
+    # step's segment is then a new one to compile
+    net(x[:1], s[:1]).wait_to_read()
     net.hybridize(static_alloc=True)
     trainer = gluon.Trainer(net.collect_params(), 'adam',
                             {'learning_rate': cfg.lr})
