@@ -255,7 +255,7 @@ def train_phase(cfg, ctx, compiles, on_chip=True):
     return losses
 
 
-def mesh_phase(cfg, ctx, compiles):
+def mesh_phase(cfg, ctx, compiles, on_chip=True):
     """Four chips: the step under mx.sharding.mesh(dp=4), and the same
     step (same seed, same batch) on one chip as what it is compared with.
     """
@@ -264,7 +264,10 @@ def mesh_phase(cfg, ctx, compiles):
     import jax
     import mxnet_tpu as mx
 
+    check(jax.device_count() == 4, f'{jax.device_count()} device(s)')
+
     def run(scope):
+        shutil.rmtree(IR_DIR, ignore_errors=True)   # this run's programs
         net, trainer, loss_fn, batch = build(cfg, ctx)
         losses, times = [], []
         c0, s0 = compiles.count, compiles.seconds
@@ -274,13 +277,15 @@ def mesh_phase(cfg, ctx, compiles):
                 losses.append(float(raw))
                 times.append(dt)
         return dict(trainer=trainer, out=out, losses=losses, times=times,
+                    kernels=dumped_kernels(),
                     compiles=compiles.count - c0,
                     compile_seconds=compiles.seconds - s0)
 
     one = run(contextlib.nullcontext())
     emit(phase='mesh', event='one_chip', losses=one['losses'],
          step_seconds=one['times'][1:], warmup_seconds=one['times'][0],
-         compile_seconds=one['compile_seconds'])
+         compile_seconds=one['compile_seconds'],
+         tpu_custom_call=one['kernels'])
     del one['trainer'], one['out']
 
     four = run(mx.sharding.mesh(dp=4))
@@ -325,7 +330,16 @@ def mesh_phase(cfg, ctx, compiles):
     check(l4[-1] < l4[0], f'mesh loss did not fall: {l4}')
     check(not trainer._fused_fallback_taken,
           'Trainer fell back to per-parameter updates')
+    # GSPMD cannot partition a pallas_call: under the mesh every kernel
+    # gate takes its XLA branch (ops/pallas/flash_attention._under_mesh),
+    # on one chip none does
+    if on_chip:
+        check(all(one['kernels'].values()),
+              f'one chip, kernels missing: {one["kernels"]}')
+    check(not any(four['kernels'].values()),
+          f'pallas_call under the mesh: {four["kernels"]}')
     emit(phase='mesh', event='four_chips', losses=l4,
+         tpu_custom_call=four['kernels'],
          step_seconds=four['times'][1:], warmup_seconds=four['times'][0],
          compile_seconds=four['compile_seconds'],
          first_loss_abs_diff=abs(l4[0] - l1[0]), tolerance=tol,

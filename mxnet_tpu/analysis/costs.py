@@ -69,7 +69,7 @@ real_to_complex sharding_constraint optimization_barrier
 """.split())
 
 COLLECTIVE_PRIMS = frozenset("""
-psum psum2 psum_scatter all_gather all_to_all ppermute pbroadcast
+psum psum_invariant psum_scatter all_gather all_to_all ppermute pbroadcast
 reduce_scatter allreduce pmax pmin
 """.split())
 

@@ -455,20 +455,11 @@ class Trainer:
                          else [self._states[i]])
                         if isinstance(e, NDArray)] or None
 
-            # under a mesh the update math must stay partitionable by
-            # GSPMD (ZeRO-1 owned tiles, FSDP shards): an opaque
-            # pallas_call would force a gather, so the fused optimizer
-            # ops take their XLA path there (same fused region, same
-            # numbers) and the Pallas path stays a single-chip win
-            import contextlib as _contextlib
-            from ..ops.pallas import fused_optimizer as _fused_opt
-            _pallas_gate = (_fused_opt.pallas_disabled if _ctx is not None
-                            else _contextlib.nullcontext)
-
+            # traced inside the mesh context when there is one: the
+            # fused optimizer ops then take their XLA path, which GSPMD
+            # can partition (ops/pallas/fused_optimizer.py use_pallas)
             def fused(praws_, graws_, sraws_, lrs_, wds_, ts_):
                 prev = _tape.set_recording(False)
-                _gate = _pallas_gate()
-                _gate.__enter__()
                 try:
                     new_ws, new_ss = [], []
                     for j, (w, g) in enumerate(zip(praws_, graws_)):
@@ -508,7 +499,6 @@ class Trainer:
                         new_ss.append(ns_list)
                     return new_ws, new_ss
                 finally:
-                    _gate.__exit__(None, None, None)
                     _tape.set_recording(prev)
 
             n = len(live)

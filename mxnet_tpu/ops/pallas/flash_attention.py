@@ -30,6 +30,18 @@ def _on_tpu():
     return jax.devices()[0].platform == 'tpu'
 
 
+def _under_mesh():
+    """True inside an ``mx.sharding`` mesh context, the Trainer's own test
+    for switching its Pallas update off. GSPMD cannot partition an opaque
+    ``pallas_call`` (Mosaic refuses at lowering: "Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map"), so
+    the dispatch gates of this package take their XLA branch there.
+    Kernels called from inside a ``shard_map`` (ring attention) are per
+    device already and ask only :func:`_on_tpu`."""
+    from ...sharding.context import current
+    return current() is not None
+
+
 # ------------------------------------------------------------------ kernel
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, m_ref=None, l_ref=None, *,
@@ -296,15 +308,13 @@ def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=128,
         out = _flash_fwd(qr, kr, vr, sm_scale, causal, bq, bk,
                          interpret=True)
         return out.reshape(q_shape)
-    if _on_tpu():
+    bq = bk = 0                 # 0: the XLA path (off-TPU, under a mesh)
+    if _on_tpu() and not _under_mesh():
         bq = block_q if t % block_q == 0 else _choose_block(t, block_q)
         bk = block_k if s % block_k == 0 else _choose_block(s, block_k)
         if bq < 32 or bk < 32:
             # awkward sequence lengths (prime factors < MXU tile) would
             # degrade to scalar-ish tiles; XLA's fused attention is faster
-            out = _flash3(qr, kr, vr, sm_scale, causal, 0, 0)
-        else:
-            out = _flash3(qr, kr, vr, sm_scale, causal, bq, bk)
-    else:
-        out = _flash3(qr, kr, vr, sm_scale, causal, block_q, block_k)
+            bq = bk = 0
+    out = _flash3(qr, kr, vr, sm_scale, causal, bq, bk)
     return out.reshape(q_shape)

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .flash_attention import _on_tpu
+from .flash_attention import _on_tpu, _under_mesh
 
 _VMEM_BUDGET = 2 * 1024 * 1024   # bytes of fp32 workspace per block
 
@@ -153,17 +153,19 @@ def _fused_norm_bwd(eps, rms, use_pallas, res, g):
 _fused_norm.defvjp(_fused_norm_fwd, _fused_norm_bwd)
 
 
+def _use_pallas(d):
+    return _on_tpu() and not _under_mesh() and d > 0 and d % 128 == 0
+
+
 def fused_layer_norm(x, gamma, beta, eps=1e-5):
     """Single-HBM-pass LayerNorm over the last axis. Pallas on TPU when
-    the feature dim tiles (multiple of 128 lanes); XLA elsewhere —
-    numerics identical (fp32 statistics)."""
-    d = x.shape[-1]
-    use_pallas = _on_tpu() and d > 0 and d % 128 == 0
-    return _fused_norm(x, gamma, beta, float(eps), False, use_pallas)
+    the feature dim tiles (multiple of 128 lanes) and no mesh context is
+    active; XLA elsewhere — numerics identical (fp32 statistics)."""
+    return _fused_norm(x, gamma, beta, float(eps), False,
+                       _use_pallas(x.shape[-1]))
 
 
 def fused_rms_norm(x, gamma, eps=1e-6):
     """Single-pass RMSNorm (Llama-family); same dispatch rule."""
-    d = x.shape[-1]
-    use_pallas = _on_tpu() and d > 0 and d % 128 == 0
-    return _fused_norm(x, gamma, None, float(eps), True, use_pallas)
+    return _fused_norm(x, gamma, None, float(eps), True,
+                       _use_pallas(x.shape[-1]))

@@ -23,33 +23,16 @@ are bit-exact against the eager path — the parity contract tier-1 tests
 pin (tests/test_pallas_kernels.py).
 """
 
-import contextlib
 import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .flash_attention import _on_tpu
+from .flash_attention import _on_tpu, _under_mesh
 
 _VMEM_BUDGET = 2 * 1024 * 1024   # fp32 workspace bytes per block
 _LANES = 128
-
-# Trainer flips this off while tracing sharded placements: GSPMD cannot
-# partition an opaque pallas_call, so a sharded fused update must take
-# the XLA path (still one fused HLO region) instead of forcing an
-# all-gather of every shard onto one core.
-_pallas_enabled = [True]
-
-
-@contextlib.contextmanager
-def pallas_disabled():
-    """Force the XLA fallback inside the with-block (trace-time gate)."""
-    _pallas_enabled.append(False)
-    try:
-        yield
-    finally:
-        _pallas_enabled.pop()
 
 
 def _block_rows(n, arrays):
@@ -74,7 +57,10 @@ def _tileable(*arrs):
 
 
 def use_pallas(*arrs):
-    return _on_tpu() and _pallas_enabled[-1] and _tileable(*arrs)
+    """The Trainer traces its fused update inside the mesh context, so a
+    sharded update (ZeRO-1 owned tiles, FSDP shards) takes the XLA path,
+    still one fused HLO region, which GSPMD can partition."""
+    return _on_tpu() and not _under_mesh() and _tileable(*arrs)
 
 
 def _prep_grad(g, w, wd, rescale_grad, clip_gradient):

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _choose_block, _on_tpu
+from .flash_attention import _choose_block, _on_tpu, _under_mesh
 
 # MXU-native int8 tile is (32, 128); fp32 epilogue tiles are (8, 128)
 _SUBLANE, _LANES = 32, 128
@@ -93,12 +93,13 @@ def int8_matmul(x, w, scale, bias, out_dtype, interpret=False,
 
 
 def use_pallas(x, w):
-    """TPU with MXU-tileable int8 operands; anything ragged takes the
-    XLA fallback region in ops/quantization_ops.py."""
+    """TPU, no mesh context, and MXU-tileable int8 operands; anything
+    else takes the XLA fallback region in ops/quantization_ops.py."""
     kdim = x.shape[-1]
     m = 1
     for d in x.shape[:-1]:
         m *= d
-    return (_on_tpu() and x.dtype == jnp.int8 and w.dtype == jnp.int8
+    return (_on_tpu() and not _under_mesh()
+            and x.dtype == jnp.int8 and w.dtype == jnp.int8
             and m % _SUBLANE == 0 and w.shape[0] % _LANES == 0
             and kdim % _LANES == 0)

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .flash_attention import _on_tpu
+from .flash_attention import _on_tpu, _under_mesh
 
 _NEG_INF = -1e30
 
@@ -127,7 +127,8 @@ def paged_attention_decode_pallas(q, k_pool, v_pool, pages, offset,
 
 
 def use_pallas(q, k_pool):
-    """TPU with a lane-tileable head dim; everything else takes the
-    gather fallback in ops/contrib.py."""
+    """TPU, no mesh context, and a lane-tileable head dim; everything
+    else takes the gather fallback in ops/contrib.py."""
     dh = q.shape[-1]
-    return _on_tpu() and dh % 128 == 0 and k_pool.dtype == q.dtype
+    return (_on_tpu() and not _under_mesh() and dh % 128 == 0
+            and k_pool.dtype == q.dtype)

@@ -363,13 +363,29 @@ def test_kernel_bench_smoke_fused_wins():
     assert kernel_bench.main(['--smoke', '--reps', '5']) == 0
 
 
-def test_trainer_mesh_gate_context():
-    """The trainer disables the Pallas path while tracing sharded
-    placements; the context must nest and restore."""
-    assert fused_optimizer._pallas_enabled[-1]
-    with fused_optimizer.pallas_disabled():
-        assert not fused_optimizer._pallas_enabled[-1]
-        with fused_optimizer.pallas_disabled():
-            assert not fused_optimizer._pallas_enabled[-1]
-        assert not fused_optimizer._pallas_enabled[-1]
-    assert fused_optimizer._pallas_enabled[-1]
+def test_kernel_gates_take_xla_under_a_mesh(monkeypatch):
+    """GSPMD cannot partition an opaque pallas_call, so inside an
+    mx.sharding mesh context every dispatch gate answers no, on a TPU
+    too; outside it the gates answer by device and shape as before."""
+    import importlib
+    import mxnet_tpu as mx
+    mods = [importlib.import_module('mxnet_tpu.ops.pallas.' + m) for m in
+            ('flash_attention', 'fused_norms', 'fused_optimizer',
+             'paged_attention', 'int8_matmul')]
+    for mod in mods:
+        monkeypatch.setattr(mod, '_on_tpu', lambda: True)
+    flash, norms, opt, paged, int8 = mods
+    w = jnp.zeros((8, 128), jnp.float32)
+    q = jnp.zeros((2, 4, 1, 128), jnp.float32)
+    xi, wi = jnp.zeros((32, 128), jnp.int8), jnp.zeros((128, 128), jnp.int8)
+
+    def answers():
+        return (norms._use_pallas(768), opt.use_pallas(w),
+                paged.use_pallas(q, q), int8.use_pallas(xi, wi))
+
+    assert not flash._under_mesh() and all(answers())
+    with mx.sharding.mesh(dp=2):
+        assert flash._under_mesh() and not any(answers())
+        jaxpr = jax.make_jaxpr(flash.flash_attention)(q, q, q)
+        assert 'pallas_call' not in str(jaxpr)
+    assert not flash._under_mesh() and all(answers())
