@@ -234,17 +234,22 @@ def test_mesh_env_overrides(monkeypatch):
         assert sharding.current() is None
 
 
-def test_eager_loss_composes_with_sharded_forward():
+@pytest.mark.parametrize('bulk', [False, True])
+def test_eager_loss_composes_with_sharded_forward(bulk):
     """Eager loss/metric math mixes sharded graph outputs with fresh
     host arrays — the dispatch layer lifts the single-device operands
-    onto the mesh (ops.registry -> sharding.lift_raws)."""
+    onto the mesh (ops.registry -> sharding.lift_raws). With the bulking
+    engine on, as it is on an accelerator, ops inside the mesh context
+    are not recorded into a segment: its flush lifts nothing."""
+    from mxnet_tpu import _bulk
     net = _mlp(seed=13)
     x = nd.rand(16, 64)
-    with sharding.mesh(dp=8):
+    with _bulk.force(bulk), sharding.mesh(dp=8):
         out = net(x)
         label = nd.rand(16, 16)         # fresh single-device array
-        loss = ((out - label) ** 2).mean()
-        val = float(loss.asnumpy())
+        diff = out - label
+        assert diff._lazy is None
+        val = float((diff ** 2).mean().asnumpy())
     assert np.isfinite(val)
 
 
