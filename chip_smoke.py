@@ -185,8 +185,9 @@ def eager_phase(ctx):
 
 
 def train_phase(cfg, ctx, compiles, on_chip=True):
-    """Warm-up + timed steps. ``on_chip=False`` is for the CPU rehearsal
-    in the tests: it keeps every check that does not need the device."""
+    """Warm-up + timed steps. ``on_chip=False`` is for a CPU rehearsal
+    (.claude/skills/verify/SKILL.md): it keeps every check that does not
+    need the device."""
     import numpy as np
     import jax
     from mxnet_tpu.ops.pallas import fused_optimizer
@@ -270,7 +271,7 @@ def mesh_phase(cfg, ctx, compiles, on_chip=True):
         shutil.rmtree(IR_DIR, ignore_errors=True)   # this run's programs
         net, trainer, loss_fn, batch = build(cfg, ctx)
         losses, times = [], []
-        c0, s0 = compiles.count, compiles.seconds
+        s0 = compiles.seconds
         with scope:
             for _ in range(1 + cfg.steps):
                 raw, out, dt = one_step(net, trainer, loss_fn, batch)
@@ -278,7 +279,6 @@ def mesh_phase(cfg, ctx, compiles, on_chip=True):
                 times.append(dt)
         return dict(trainer=trainer, out=out, losses=losses, times=times,
                     kernels=dumped_kernels(),
-                    compiles=compiles.count - c0,
                     compile_seconds=compiles.seconds - s0)
 
     one = run(contextlib.nullcontext())
@@ -354,7 +354,6 @@ def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
     ap.add_argument('--chips', type=int, default=1, choices=(1, 4))
     ap.add_argument('--seed', type=int, default=0)
-    ap.add_argument('--steps', type=int, default=Config.steps)
     args = ap.parse_args(argv)
 
     import jax
@@ -380,7 +379,7 @@ def main(argv=None):
          cache_dir_from_env=bool(
              os.environ.get('JAX_COMPILATION_CACHE_DIR')))
 
-    cfg = Config(seed=args.seed, steps=args.steps)
+    cfg = Config(seed=args.seed)
     t0 = time.perf_counter()
     ctx = mx.tpu(0)
     if args.chips == 4:
@@ -396,10 +395,10 @@ def main(argv=None):
 
 
 def _libtpu_version():
+    from importlib import metadata
     try:
-        from importlib import metadata
         return metadata.version('libtpu')
-    except Exception:           # noqa: BLE001 - a label, not a check
+    except metadata.PackageNotFoundError:       # a label, not a check
         return None
 
 

@@ -58,6 +58,7 @@ class Context:
                 # default-backend list has no CPU devices on TPU hosts
                 devs = jax.local_devices(backend='cpu') \
                     if _has_platform('cpu') else jax.local_devices()
+                index = self.device_id % len(devs)
             else:
                 # tpu (or gpu alias): any non-cpu accelerator backend.
                 # No CPU stand-in and no wrap-around: a chip that is not
@@ -72,9 +73,8 @@ class Context:
                     raise ValueError(
                         f'{self!r}: device_id out of range, '
                         f'{len(devs)} accelerator device(s) present')
-                self._jax_device = devs[self.device_id]
-                return self._jax_device
-            self._jax_device = devs[self.device_id % len(devs)]
+                index = self.device_id
+            self._jax_device = devs[index]
         return self._jax_device
 
     def __hash__(self):
