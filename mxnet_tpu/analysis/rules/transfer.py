@@ -9,9 +9,10 @@ transfer-guard work catches dynamically (PAPERS.md).
 
 Flagged:
 
-* ``pure_callback`` / ``io_callback`` / ``debug_callback`` (from
-  ``jax.debug.print``) — error for pure/io (semantic host dependence),
-  warning for debug prints (usually leftover instrumentation);
+* ``pure_callback`` / ``io_callback`` / ``debug_print`` and
+  ``debug_callback`` (from ``jax.debug.print`` / ``jax.debug.callback``)
+  — error for pure/io (semantic host dependence), warning for the debug
+  pair (usually leftover instrumentation);
 * ``infeed`` / ``outfeed`` — warning (legitimate but rare, and never
   something a model-zoo forward should contain);
 * ``device_put`` eqns with an explicit device/memory-kind target —
@@ -33,6 +34,7 @@ CALLBACK_SEVERITY = {
     'io_callback': 'error',
     'callback': 'error',
     'debug_callback': 'warning',
+    'debug_print': 'warning',
     'infeed': 'warning',
     'outfeed': 'warning',
 }
