@@ -116,10 +116,7 @@ def build(cfg, ctx):
     s = mx.np.array(np.zeros_like(toks), ctx=ctx)
     y = mx.np.array(labels, ctx=ctx)
 
-    # deferred shapes resolve eagerly; wait for it, or the bulking engine
-    # carries these ops into the first step's segment and the second
-    # step's segment is then a new one to compile
-    net(x[:1], s[:1]).wait_to_read()
+    net(x[:1], s[:1])                 # deferred shapes resolve eagerly
     net.hybridize(static_alloc=True)
     trainer = gluon.Trainer(net.collect_params(), 'adam',
                             {'learning_rate': cfg.lr})
@@ -184,10 +181,10 @@ def eager_phase(ctx):
          bulked=True, device=str(dev))
 
 
-def train_phase(cfg, ctx, compiles, on_chip=True):
-    """Warm-up + timed steps. ``on_chip=False`` is for a CPU rehearsal
-    (.claude/skills/verify/SKILL.md): it keeps every check that does not
-    need the device."""
+def train_phase(cfg, ctx, compiles):
+    """Warm-up + timed steps. On a ``ctx`` that is no TPU (a rehearsal,
+    .claude/skills/verify/SKILL.md) the checks that read the device are
+    left out; main() gives it none."""
     import numpy as np
     import jax
     from mxnet_tpu.ops.pallas import fused_optimizer
@@ -224,7 +221,7 @@ def train_phase(cfg, ctx, compiles, on_chip=True):
     check(not trainer._fused_fallback_taken,
           'Trainer fell back to per-parameter updates')
 
-    if on_chip:
+    if ctx.device_type == 'tpu':
         for name, p in params.items():
             for d in p.data()._data.devices():
                 check(d.platform == 'tpu', f'{name} lives on {d}')
@@ -256,7 +253,7 @@ def train_phase(cfg, ctx, compiles, on_chip=True):
     return losses
 
 
-def mesh_phase(cfg, ctx, compiles, on_chip=True):
+def mesh_phase(cfg, ctx, compiles):
     """Four chips: the step under mx.sharding.mesh(dp=4), and the same
     step (same seed, same batch) on one chip as what it is compared with.
     """
@@ -333,7 +330,7 @@ def mesh_phase(cfg, ctx, compiles, on_chip=True):
     # GSPMD cannot partition a pallas_call: under the mesh every kernel
     # gate takes its XLA branch (ops/pallas/flash_attention._under_mesh),
     # on one chip none does
-    if on_chip:
+    if ctx.device_type == 'tpu':
         check(all(one['kernels'].values()),
               f'one chip, kernels missing: {one["kernels"]}')
     check(not any(four['kernels'].values()),

@@ -20,10 +20,11 @@ How it works
   grad-activity, input wiring): a training loop's second iteration walks the
   same trie path and reuses the recorded output avals — no re-abstract-eval,
   no retracing, no per-op device dispatch.
-* A **flush** (sync point: ``_data`` touch, ``backward()``, segment-size
-  cap, explicit ``engine.bulk`` exit) compiles — once per (trie node, live
-  output set) — a jitted replay of the whole segment and executes it as one
-  device program. Subsequent identical segments are a dict hit + one call.
+* A **flush** (sync point: ``_data`` touch, ``backward()``, a hybridized
+  block's compiled call, segment-size cap, explicit ``engine.bulk`` exit)
+  compiles — once per (trie node, live output set) — a jitted replay of
+  the whole segment and executes it as one device program. Subsequent
+  identical segments are a dict hit + one call.
 * Autograd: per-op tape nodes are *not* created inside a segment. Instead
   the flush populates ONE :class:`_tape.TapeNode` covering the segment,
   whose vjp re-linearizes the jitted replay (rematerialized backward — the

@@ -34,7 +34,7 @@ from ..base import MXNetError
 from ..context import Context, current_context
 from ..ndarray.ndarray import NDArray, array
 from .parameter import Constant, DeferredInitializationError, Parameter
-from .. import _rng, _tape
+from .. import _bulk, _rng, _tape
 
 _BLOCK_TRACE = threading.local()
 
@@ -519,6 +519,13 @@ class _CachedGraph:
                 cb(self.block, out)
             return out
 
+        # a compiled graph is a launch of its own and a sync point of the
+        # bulking engine, like backward(): eager ops still pending (the
+        # shape-resolving forward before hybridize(), whose result nobody
+        # reads) are dispatched first, in program order. Left pending they
+        # ride into the first step's segment, and the second step's
+        # segment is then a new one to compile.
+        _bulk.flush_current()
         leaves, treedef = jax.tree.flatten(
             args, is_leaf=lambda x: isinstance(x, NDArray))
         in_nds = [x if isinstance(x, NDArray) else array(x) for x in leaves]

@@ -40,6 +40,10 @@ def _bind_sharding():
     _lift_raws = lift_raws
 
 
+def _on_mesh():
+    return _sharding_current is not None and _sharding_current() is not None
+
+
 class Op:
     """One registered operator.
 
@@ -211,16 +215,14 @@ def apply_op(op, arrays, fn, n_out=None, name=None, _from_invoke=False,
 
     recording = _tape.is_recording() and _tape._needs_grad(arrays)
     profiling = _prof._is_profiling_ops()
-    on_mesh = _sharding_current is not None \
-        and _sharding_current() is not None
 
     # ---- bulked (lazy) dispatch: record into the segment instead of
     # executing; the flush runs the whole segment as one XLA program.
     # Not under a mesh context: a segment's boundary may mix arrays
     # committed to the mesh with single-device ones, and only the eager
     # path below reconciles them.
-    if (bulk_key is not None and arrays and not profiling and not on_mesh
-            and not _dc.is_deferred_compute()):
+    if (bulk_key is not None and arrays and not profiling
+            and not _dc.is_deferred_compute() and not _on_mesh()):
         grad_active = recording and op.differentiable
         rec = _bulk.try_record(op, arrays, fn, bulk_key, grad_active)
         if rec is not None:
@@ -233,7 +235,7 @@ def apply_op(op, arrays, fn, n_out=None, name=None, _from_invoke=False,
             return tuple(wrapped) if multi else wrapped[0]
 
     raws = [a._data for a in arrays]
-    if lift and on_mesh:
+    if lift and _on_mesh():
         # mesh context active: reconcile committed device sets (sharded
         # graph outputs vs host-fresh labels) before dispatch. The
         # _CachedGraph dispatch opts out (lift=False): its pjit entry

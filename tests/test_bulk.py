@@ -158,6 +158,32 @@ def test_second_iteration_no_retrace():
     assert s2['misses'] == s['misses'], 'iteration 2 missed the trie'
 
 
+def test_pending_forward_does_not_ride_into_hybridized_loop():
+    """examples/bert_finetune.py's shape: an eager forward nobody reads
+    resolves the shapes, then hybridize() and the loop. The compiled graph
+    is a sync point, so the pending forward is its own segment and the
+    loop's loss segment is the same one from the first step on."""
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(8, activation='relu'), gluon.nn.Dense(2))
+    net.initialize()
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    x = mx.np.array(onp.ones((4, 3), 'f'))
+    y = mx.np.array(onp.array([0, 1, 0, 1], 'f'))
+    seen = []
+    with engine.bulk(4096):
+        net(x[:1])                          # result unread: stays pending
+        net.hybridize(static_alloc=True)
+        trainer = gluon.Trainer(net.collect_params(), 'adam')
+        for _ in range(3):
+            with autograd.record():
+                loss = loss_fn(net(x), y)
+            loss.backward()
+            trainer.step(4)
+            seen.append(_bulk.stats())
+    assert seen[1]['compiles'] == seen[0]['compiles'], 'step 2 recompiled'
+    assert seen[2]['misses'] == seen[0]['misses'], 'step 2 missed the trie'
+
+
 def test_dead_intermediates_not_materialized():
     _bulk.reset()       # pristine trie (earlier tests mark positions)
     with engine.bulk(100):

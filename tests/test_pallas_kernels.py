@@ -61,8 +61,14 @@ def _adam_ref(w, g, m, v, lr, wd, t):
 _OPT_SHAPES = [(8, 384), (768,), (1031, 128)]
 
 
-@pytest.mark.parametrize('shape', _OPT_SHAPES)
-def test_adam_kernel_slot_updates_bit_exact(shape):
+# weight tolerance per shape. 1e-6 is the old limit and holds for the old
+# shape; the 132k-element draw has one element (row 207, in the first, full
+# block; a (512,128) draw has it too, so it is the data and not the ragged
+# block) where |v| is tiny, the step is large and the kernel sits 1.31e-6
+# from the reference
+@pytest.mark.parametrize('shape,w_atol', list(zip(_OPT_SHAPES,
+                                                  (1e-6, 1e-6, 2e-6))))
+def test_adam_kernel_slot_updates_bit_exact(shape, w_atol):
     w, g = _rand(0, shape), _rand(1, shape)
     m, v = _rand(2, shape, 0.1), jnp.abs(_rand(3, shape, 0.01))
     t, lr, wd = 5, 0.01, 0.001
@@ -72,9 +78,8 @@ def test_adam_kernel_slot_updates_bit_exact(shape):
     assert bool((om == mr).all()), 'adam mean slot must be bit-exact'
     assert bool((ov == vr).all()), 'adam var slot must be bit-exact'
     # weight: ulp-level — the traced lr operand vs the folded constant
-    # changes one contraction in the final fma (2e-6: the worst of
-    # 132k elements where 1e-6 covered 3k)
-    assert bool(jnp.allclose(ow, wr, rtol=1e-6, atol=2e-6))
+    # changes one contraction in the final fma
+    assert bool(jnp.allclose(ow, wr, rtol=1e-6, atol=w_atol))
 
 
 def test_adam_kernel_traced_hyper_no_recompile():
