@@ -56,6 +56,7 @@ import jax
 from jax import lax
 
 from . import _tape
+from .telemetry import trace as _trace
 from .analysis import race as _race
 from .analysis.race import guarded_by as _guarded_by
 
@@ -310,48 +311,9 @@ class _Segment:
                 _race.handoff_release(self)
                 return
             self.state.flushes += 1
-
-            live_keys = []
-            live_refs = []
-            for ei, e in enumerate(self.entries):
-                for oi, w in enumerate(e.out_refs):
-                    ref = w()
-                    if ref is not None:
-                        live_keys.append((ei, oi))
-                        live_refs.append(ref)
-            out_keys = tuple(live_keys)
-
-            plan = self.trie_pos.plans.get(out_keys)
-            if plan is None:
-                replay = _build_replay(self.entries)
-
-                def fwd(*boundary):
-                    env = replay(*boundary)
-                    return tuple(env[ei][oi] for ei, oi in out_keys)
-
-                plan = _Plan(jax.jit(fwd), fwd, replay, out_keys)
-                self.trie_pos.plans[out_keys] = plan
-                self.state.compiles += 1
-
-            outs = plan.jfwd(*self.boundary)
-
-            for i, ref in enumerate(live_refs):
-                ref.value = outs[i]
-                ref.seg = None
-
-            if self.tape_node is not None:
-                pos = {k: i for i, k in enumerate(out_keys)}
-                node = self.tape_node
-                node.fn = plan.fwd_raw
-                node.in_vals = list(self.boundary)
-                node.parents = list(self.boundary_ags)
-                node.n_out = len(out_keys)
-                node.out_avals = [r.aval for r in live_refs]
-                node.vjp_fn = _SegVjp(plan, tuple(self.boundary))
-                for key, agw in self.agrefs:
-                    ag = agw()
-                    if ag is not None and key in pos:
-                        ag.index = pos[key]
+            # plan lookup, the segment's jitted call, publishing the refs
+            with _trace.child_span('mx.bulk.flush') as span:
+                self._launch(span)
             # release recording state (tape node keeps what it needs)
             self.entries = []
             self.agrefs = []
@@ -359,6 +321,54 @@ class _Segment:
             # happens-before edge: values are published; the recording
             # thread's next access to them is a handoff, not a race
             _race.handoff_release(self)
+
+    def _launch(self, span):
+        """Run the recorded entries as one program and publish the
+        values (under the segment lock, from flush)."""
+        live_keys = []
+        live_refs = []
+        for ei, e in enumerate(self.entries):
+            for oi, w in enumerate(e.out_refs):
+                ref = w()
+                if ref is not None:
+                    live_keys.append((ei, oi))
+                    live_refs.append(ref)
+        out_keys = tuple(live_keys)
+
+        plan = self.trie_pos.plans.get(out_keys)
+        if span.live:
+            span.set(n_ops=len(self.entries), n_out=len(out_keys),
+                     compiled=int(plan is None))
+        if plan is None:
+            replay = _build_replay(self.entries)
+
+            def fwd(*boundary):
+                env = replay(*boundary)
+                return tuple(env[ei][oi] for ei, oi in out_keys)
+
+            plan = _Plan(jax.jit(fwd), fwd, replay, out_keys)
+            self.trie_pos.plans[out_keys] = plan
+            self.state.compiles += 1
+
+        outs = plan.jfwd(*self.boundary)
+
+        for i, ref in enumerate(live_refs):
+            ref.value = outs[i]
+            ref.seg = None
+
+        if self.tape_node is not None:
+            pos = {k: i for i, k in enumerate(out_keys)}
+            node = self.tape_node
+            node.fn = plan.fwd_raw
+            node.in_vals = list(self.boundary)
+            node.parents = list(self.boundary_ags)
+            node.n_out = len(out_keys)
+            node.out_avals = [r.aval for r in live_refs]
+            node.vjp_fn = _SegVjp(plan, tuple(self.boundary))
+            for key, agw in self.agrefs:
+                ag = agw()
+                if ag is not None and key in pos:
+                    ag.index = pos[key]
 
 
 def _build_replay(entries):
@@ -396,6 +406,7 @@ class _State(threading.local):
         self.misses = 0
         self.flushes = 0
         self.compiles = 0
+        self.unbulked = 0           # eager ops the engine did not take
 
 
 _st = _State()
@@ -455,7 +466,18 @@ def current_size():
 
 def stats():
     return {'hits': _st.hits, 'misses': _st.misses,
-            'flushes': _st.flushes, 'compiles': _st.compiles}
+            'flushes': _st.flushes, 'compiles': _st.compiles,
+            'unbulked': _st.unbulked}
+
+
+def note_unbulked(raws):
+    """An op that was the engine's to take went eager, a launch of its
+    own (bulking off, a mesh context, a position that keeps changing).
+    Inside a ``jit`` trace the op launches nothing and is not counted."""
+    for r in raws:
+        if isinstance(r, jax.core.Tracer):
+            return
+    _st.unbulked += 1
 
 
 def reset():

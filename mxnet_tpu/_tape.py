@@ -25,6 +25,8 @@ import threading
 import jax
 import jax.numpy as jnp
 
+from .telemetry import trace as _trace
+
 _state = threading.local()
 
 
@@ -222,9 +224,44 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True,
     are differentiable — higher-order autograd, the role of the
     reference's create_graph handling in MXGradient.
     """
+    with _trace.child_span('mx.tape.backward') as span:
+        return _backward(heads, head_grads, retain_graph, train_mode,
+                         variables, create_graph, span)
+
+
+def _node_vjp(node, present, indexed):
+    """The input cotangents of one node from the cotangents ``present``
+    of its outputs."""
+    if indexed is not None:
+        # segment node: zero cotangents are synthesized inside
+        # the jitted vjp (symbolic zeros) instead of N host ops
+        return indexed({
+            i: (c.dense() if isinstance(c, RowSparseCot) else c)
+            for i, c in present.items()})
+    out_cots = [
+        present.get(i) if present.get(i) is not None
+        else jnp.zeros(node.out_avals[i].shape,
+                       dtype=node.out_avals[i].dtype)
+        for i in range(node.n_out)]
+    if node.vjp_fn is not None:
+        vjp_fn = node.vjp_fn
+    elif node.vjp_lock is not None:
+        # predict-record deferral: the re-trace re-enters
+        # _CachedOp's pure_fn Parameter-payload swap, which
+        # must not race lock-free inference snapshots
+        with node.vjp_lock:
+            _, vjp_fn = jax.vjp(node.fn, *node.in_vals)
+    else:
+        _, vjp_fn = jax.vjp(node.fn, *node.in_vals)
+    return vjp_fn(tuple(out_cots) if node.multi else out_cots[0])
+
+
+def _backward(heads, head_grads, retain_graph, train_mode, variables,
+              create_graph, span):
     from .ndarray.ndarray import NDArray  # local import to avoid cycle
     from . import _bulk
-    _bulk.flush_current()   # segment tape nodes must be complete
+    with _trace.child_span('mx.tape.flush'):
+        _bulk.flush_current()   # segment tape nodes must be complete
 
     head_infos = []
     for h in heads:
@@ -268,6 +305,8 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True,
 
     order = _toposort(head_infos)
     node_index = {id(n): n for n in order}
+    if span.live:
+        span.set(n_nodes=len(order))
 
     prev_train = set_training(train_mode)
     try:
@@ -280,36 +319,25 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True,
             if not present:
                 continue
             indexed = getattr(node.vjp_fn, 'indexed', None)
-            if indexed is not None:
-                # segment node: zero cotangents are synthesized inside
-                # the jitted vjp (symbolic zeros) instead of N host ops
-                in_cots = indexed({
-                    i: (c.dense() if isinstance(c, RowSparseCot) else c)
-                    for i, c in present.items()})
+            if indexed is not None or node.vjp_lock is not None:
+                # the node is a compiled program (a bulk segment's
+                # indexed vjp, a compiled graph's call, which alone
+                # brings a lock): its vjp launches the backward
+                # programs. Single eager ops get no span of their own.
+                with _trace.child_span('mx.tape.vjp') as launch:
+                    in_cots = _node_vjp(node, present, indexed)
+                    if launch.live:
+                        launch.set(n_out=len(in_cots))
             else:
-                out_cots = [
-                    present.get(i) if present.get(i) is not None
-                    else jnp.zeros(node.out_avals[i].shape,
-                                   dtype=node.out_avals[i].dtype)
-                    for i in range(node.n_out)]
-                if node.vjp_fn is not None:
-                    vjp_fn = node.vjp_fn
-                elif node.vjp_lock is not None:
-                    # predict-record deferral: the re-trace re-enters
-                    # _CachedOp's pure_fn Parameter-payload swap, which
-                    # must not race lock-free inference snapshots
-                    with node.vjp_lock:
-                        _, vjp_fn = jax.vjp(node.fn, *node.in_vals)
-                else:
-                    _, vjp_fn = jax.vjp(node.fn, *node.in_vals)
-                in_cots = vjp_fn(tuple(out_cots) if node.multi
-                                 else out_cots[0])
+                in_cots = _node_vjp(node, present, indexed)
             for parent, cot in zip(node.parents, in_cots):
                 _push(parent, cot)
             if not retain_graph:
                 node.vjp_fn = None
     finally:
         set_training(prev_train)
+    if span.live:
+        span.set(n_vars=len(var_grads))
 
     if variables is not None:
         out = []

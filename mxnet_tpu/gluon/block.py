@@ -35,6 +35,7 @@ from ..context import Context, current_context
 from ..ndarray.ndarray import NDArray, array
 from .parameter import Constant, DeferredInitializationError, Parameter
 from .. import _bulk, _rng, _tape
+from ..telemetry import trace as _trace
 
 _BLOCK_TRACE = threading.local()
 
@@ -511,25 +512,40 @@ class _CachedGraph:
         return pure_fn
 
     def __call__(self, args):
-        import jax
-
         if self._dynamic:
             out = self.block.forward(*args)
             for cb in self._monitor_callbacks:
                 cb(self.block, out)
             return out
 
-        # a compiled graph is a launch of its own and a sync point of the
-        # bulking engine, like backward(): eager ops still pending (the
-        # shape-resolving forward before hybridize(), whose result nobody
-        # reads) are dispatched first, in program order. Left pending they
-        # ride into the first step's segment, and the second step's
-        # segment is then a new one to compile.
-        _bulk.flush_current()
+        # spans of the train path (docs/observability.md): what is left
+        # of mx.graph.call after flush and launch is this layer's own
+        # host time
+        with _trace.child_span('mx.graph.call') as span:
+            built = self.compiles
+            # a compiled graph is a launch of its own and a sync point of
+            # the bulking engine, like backward(): eager ops still pending
+            # (the shape-resolving forward before hybridize(), whose
+            # result nobody reads) are dispatched first, in program
+            # order. Left pending they ride into the first step's
+            # segment, and the second step's segment is then a new one to
+            # compile.
+            with _trace.child_span('mx.graph.flush'):
+                _bulk.flush_current()
+            out = self._call_static(args, span)
+            if span.live:
+                span.set(compiled=self.compiles - built)
+            return out
+
+    def _call_static(self, args, span):
+        import jax
+
         leaves, treedef = jax.tree.flatten(
             args, is_leaf=lambda x: isinstance(x, NDArray))
         in_nds = [x if isinstance(x, NDArray) else array(x) for x in leaves]
         main, aux = self._params()
+        if span.live:
+            span.set(n_in=len(in_nds), n_params=len(main) + len(aux))
         # the train flag alone decides the traced branch/behavior
         # (dropout, BN stats, detector training heads): record() turns
         # it on by default, autograd.train_mode() turns it on without
@@ -669,8 +685,14 @@ class _CachedGraph:
         # payload swap and must hold this graph's lock (ADVICE r4)
         op.vjp_lock = self._lock
         try:
-            res = apply_op(op, in_nds + main_nds, fn, name='_CachedOp',
-                           lift=False)
+            # the jitted call down to PjRt (under record() jax.vjp's
+            # forward, residuals and all)
+            with _trace.child_span('mx.graph.launch') as launch:
+                res = apply_op(op, in_nds + main_nds, fn,
+                               name='_CachedOp', lift=False)
+                if launch.live:
+                    launch.set(
+                        n_out=len(res) if isinstance(res, tuple) else 1)
         except DynamicShapeError:
             # a dynamic-output-shape op inside the graph (boolean_mask,
             # unique, ...): permanently switch this block to eager

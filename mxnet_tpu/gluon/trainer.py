@@ -17,6 +17,7 @@ from ..kvstore.base import KVStoreBase
 from .. import optimizer as opt
 from .parameter import Parameter
 from ..ndarray.ndarray import NDArray
+from ..telemetry import trace as _trace
 
 
 class _FusedUnsupported(Exception):
@@ -156,14 +157,19 @@ class Trainer:
     # ------------------------------------------------------------------ step
     def step(self, batch_size, ignore_stale_grad=False):
         """Reference trainer.py:334."""
-        rescale_grad = self._scale / batch_size
-        self._check_and_rescale_grad(rescale_grad)
-        if not self._kv_initialized:
-            self._init_kvstore()
-        if self._params_to_init:
-            self._init_params()
-        self._allreduce_grads()
-        self._update(ignore_stale_grad)
+        # what is left of this span after place, hyper and launch is the
+        # update's own host time: the loops over the parameters
+        with _trace.child_span('mx.trainer.step') as span:
+            if span.live:
+                span.set(n_params=len(self._params))
+            rescale_grad = self._scale / batch_size
+            self._check_and_rescale_grad(rescale_grad)
+            if not self._kv_initialized:
+                self._init_kvstore()
+            if self._params_to_init:
+                self._init_params()
+            self._allreduce_grads()
+            self._update(ignore_stale_grad)
 
     def _check_and_rescale_grad(self, scale):
         if self._update_on_kvstore and self._kv_initialized and \
@@ -415,7 +421,8 @@ class Trainer:
         from .. import sharding as _sharding
         _ctx = _sharding.current()
         if _ctx is not None:
-            self._mesh_place(live, _ctx)
+            with _trace.child_span('mx.trainer.place'):
+                self._mesh_place(live, _ctx)
 
         praws = [p.list_data()[0]._data for _, p in live]
         graws = [p.list_grad()[0]._data for _, p in live]
@@ -517,23 +524,32 @@ class Trainer:
         elif fn is _FUSED_SENTINEL:
             raise _FusedUnsupported('previously failed')
 
-        for i, _ in live:
-            opt._update_count(i)
-        # constant hyperparameter vectors are cached device-side; the
-        # update counts change every step and are uploaded (one small
-        # transfer, and no program beyond the fused update to compile)
-        lr_vals = tuple(opt._get_lr(i) for i, _ in live)
-        wd_vals = tuple(opt._get_wd(i) for i, _ in live)
-        cached = getattr(self, '_hyper_cache', None)
-        if cached is not None and cached[0] == (lr_vals, wd_vals):
-            lrs, wds = cached[1], cached[2]
-        else:
-            lrs = jnp.asarray(_onp.asarray(lr_vals, _onp.float32))
-            wds = jnp.asarray(_onp.asarray(wd_vals, _onp.float32))
-            self._hyper_cache = ((lr_vals, wd_vals), lrs, wds)
-        ts = jnp.asarray(_onp.asarray(
-            [opt._index_update_count[i] for i, _ in live], _onp.int32))
-        new_ws, new_ss = fn(praws, graws, sraws, lrs, wds, ts)
+        with _trace.child_span('mx.trainer.hyper') as hyper:
+            for i, _ in live:
+                opt._update_count(i)
+            # constant hyperparameter vectors are cached device-side; the
+            # update counts change every step and are uploaded (one small
+            # transfer, and no program beyond the fused update to compile)
+            lr_vals = tuple(opt._get_lr(i) for i, _ in live)
+            wd_vals = tuple(opt._get_wd(i) for i, _ in live)
+            cached = getattr(self, '_hyper_cache', None)
+            fresh = cached is None or cached[0] != (lr_vals, wd_vals)
+            if fresh:
+                lrs = jnp.asarray(_onp.asarray(lr_vals, _onp.float32))
+                wds = jnp.asarray(_onp.asarray(wd_vals, _onp.float32))
+                self._hyper_cache = ((lr_vals, wd_vals), lrs, wds)
+            else:
+                lrs, wds = cached[1], cached[2]
+            ts = jnp.asarray(_onp.asarray(
+                [opt._index_update_count[i] for i, _ in live], _onp.int32))
+            if hyper.live:
+                hyper.set(uploaded=3 if fresh else 1)
+        with _trace.child_span('mx.trainer.launch') as launch:
+            if launch.live:
+                n_state = sum(map(len, sraws))
+                launch.set(n_in=2 * len(live) + n_state + 3,
+                           n_out=len(live) + n_state)
+            new_ws, new_ss = fn(praws, graws, sraws, lrs, wds, ts)
         for (i, param), nw, ns in zip(live, new_ws, new_ss):
             datas = param.list_data()
             datas[0]._rebind(nw)

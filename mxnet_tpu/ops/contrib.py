@@ -171,6 +171,13 @@ def multi_head_attention(q, k, v, num_heads, mask=None, dropout_p=0.0,
     replacement for the interleaved-matmul pipeline. Unmasked/causal cases
     take the Pallas flash path (ops/pallas/flash_attention.py); explicit
     masks use jax.nn.dot_product_attention, which XLA fuses."""
+    # one scope round every branch: a device operation of a profile is
+    # put down to attention whichever implementation ran
+    with jax.named_scope('mx.attention'):
+        return _attention(q, k, v, num_heads, mask, dropout_p, causal, key)
+
+
+def _attention(q, k, v, num_heads, mask, dropout_p, causal, key):
     b, sq, e = q.shape
     hd = e // num_heads
     qh = q.reshape(b, sq, num_heads, hd)

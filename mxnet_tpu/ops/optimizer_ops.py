@@ -18,6 +18,7 @@ semantics (rescale_grad, clip_gradient, wd applied to raw weight) follow the
 reference's optimizer_op-inl.h structs.
 """
 
+import jax
 import jax.numpy as jnp
 
 from .registry import register
@@ -57,6 +58,9 @@ def _elementwise_pallas_cost(flops_per_elem):
 # denom/update(6) — the closed form BENCH rows divide achieved time by
 _ADAM_FLOPS_PER_ELEM = 18
 _SGD_MOM_FLOPS_PER_ELEM = 7
+# round both sides of the gate: a profile's device operations are put
+# down to the optimizer step whether the kernel or XLA ran it
+_STEP_SCOPE = 'mx.optimizer_step'
 
 
 @register('fused_adam_step', n_out=3, fused_kernel=True,
@@ -68,23 +72,24 @@ def fused_adam_step(weight, grad, mean, var, lr=0.001, wd=0.0, t=1,
     """One Adam step, (w, g, m, v) -> (w', m', v'). ``lr``/``wd``/``t``
     may be traced scalars (LR schedules never recompile)."""
     from .pallas import fused_optimizer as _fo
-    if _fo.use_pallas(weight, grad, mean, var):
-        return _fo.adam_step(
-            weight, grad, mean, var, lr, wd, t, beta1=beta1, beta2=beta2,
-            epsilon=epsilon, rescale_grad=rescale_grad,
-            clip_gradient=clip_gradient, correct_bias=correct_bias)
-    g = grad * rescale_grad
-    if clip_gradient is not None:
-        g = jnp.clip(g, -clip_gradient, clip_gradient)
-    g = g + wd * weight
-    m = beta1 * mean + (1 - beta1) * g
-    v = beta2 * var + (1 - beta2) * g * g
-    if correct_bias:
-        mhat = m / (1 - beta1 ** t)
-        vhat = v / (1 - beta2 ** t)
-    else:
-        mhat, vhat = m, v
-    return weight - lr * mhat / (jnp.sqrt(vhat) + epsilon), m, v
+    with jax.named_scope(_STEP_SCOPE):
+        if _fo.use_pallas(weight, grad, mean, var):
+            return _fo.adam_step(
+                weight, grad, mean, var, lr, wd, t, beta1=beta1,
+                beta2=beta2, epsilon=epsilon, rescale_grad=rescale_grad,
+                clip_gradient=clip_gradient, correct_bias=correct_bias)
+        g = grad * rescale_grad
+        if clip_gradient is not None:
+            g = jnp.clip(g, -clip_gradient, clip_gradient)
+        g = g + wd * weight
+        m = beta1 * mean + (1 - beta1) * g
+        v = beta2 * var + (1 - beta2) * g * g
+        if correct_bias:
+            mhat = m / (1 - beta1 ** t)
+            vhat = v / (1 - beta2 ** t)
+        else:
+            mhat, vhat = m, v
+        return weight - lr * mhat / (jnp.sqrt(vhat) + epsilon), m, v
 
 
 @register('fused_sgd_mom_step', n_out=2, fused_kernel=True,
@@ -93,16 +98,17 @@ def fused_sgd_mom_step(weight, grad, mom, lr=0.01, wd=0.0, momentum=0.0,
                        rescale_grad=1.0, clip_gradient=None):
     """One SGD-momentum step, (w, g, mom) -> (w', mom')."""
     from .pallas import fused_optimizer as _fo
-    if _fo.use_pallas(weight, grad, mom):
-        return _fo.sgd_mom_step(
-            weight, grad, mom, lr, wd, momentum=momentum,
-            rescale_grad=rescale_grad, clip_gradient=clip_gradient)
-    g = grad * rescale_grad
-    if clip_gradient is not None:
-        g = jnp.clip(g, -clip_gradient, clip_gradient)
-    g = g + wd * weight
-    new_mom = momentum * mom - lr * g
-    return weight + new_mom, new_mom
+    with jax.named_scope(_STEP_SCOPE):
+        if _fo.use_pallas(weight, grad, mom):
+            return _fo.sgd_mom_step(
+                weight, grad, mom, lr, wd, momentum=momentum,
+                rescale_grad=rescale_grad, clip_gradient=clip_gradient)
+        g = grad * rescale_grad
+        if clip_gradient is not None:
+            g = jnp.clip(g, -clip_gradient, clip_gradient)
+        g = g + wd * weight
+        new_mom = momentum * mom - lr * g
+        return weight + new_mom, new_mom
 
 
 # ------------------------------------------------------------------ sgd family

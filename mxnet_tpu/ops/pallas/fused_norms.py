@@ -153,6 +153,11 @@ def _fused_norm_bwd(eps, rms, use_pallas, res, g):
 _fused_norm.defvjp(_fused_norm_fwd, _fused_norm_bwd)
 
 
+# round the call, so that the Pallas kernel and the XLA path both carry
+# it and a profile's device operations can be put down to the norm
+SCOPE = 'mx.layer_norm'
+
+
 def _use_pallas(d):
     return _on_tpu() and not _under_mesh() and d > 0 and d % 128 == 0
 
@@ -161,11 +166,13 @@ def fused_layer_norm(x, gamma, beta, eps=1e-5):
     """Single-HBM-pass LayerNorm over the last axis. Pallas on TPU when
     the feature dim tiles (multiple of 128 lanes) and no mesh context is
     active; XLA elsewhere — numerics identical (fp32 statistics)."""
-    return _fused_norm(x, gamma, beta, float(eps), False,
-                       _use_pallas(x.shape[-1]))
+    with jax.named_scope(SCOPE):
+        return _fused_norm(x, gamma, beta, float(eps), False,
+                           _use_pallas(x.shape[-1]))
 
 
 def fused_rms_norm(x, gamma, eps=1e-6):
     """Single-pass RMSNorm (Llama-family); same dispatch rule."""
-    return _fused_norm(x, gamma, None, float(eps), True,
-                       _use_pallas(x.shape[-1]))
+    with jax.named_scope(SCOPE):
+        return _fused_norm(x, gamma, None, float(eps), True,
+                           _use_pallas(x.shape[-1]))

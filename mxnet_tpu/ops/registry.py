@@ -221,8 +221,9 @@ def apply_op(op, arrays, fn, n_out=None, name=None, _from_invoke=False,
     # Not under a mesh context: a segment's boundary may mix arrays
     # committed to the mesh with single-device ones, and only the eager
     # path below reconciles them.
-    if (bulk_key is not None and arrays and not profiling
-            and not _dc.is_deferred_compute() and not _on_mesh()):
+    offered = (bulk_key is not None and arrays and not profiling
+               and not _dc.is_deferred_compute())
+    if offered and not _on_mesh():
         grad_active = recording and op.differentiable
         rec = _bulk.try_record(op, arrays, fn, bulk_key, grad_active)
         if rec is not None:
@@ -235,6 +236,8 @@ def apply_op(op, arrays, fn, n_out=None, name=None, _from_invoke=False,
             return tuple(wrapped) if multi else wrapped[0]
 
     raws = [a._data for a in arrays]
+    if offered:
+        _bulk.note_unbulked(raws)       # a launch of its own
     if lift and _on_mesh():
         # mesh context active: reconcile committed device sets (sharded
         # graph outputs vs host-fresh labels) before dispatch. The

@@ -12,6 +12,7 @@ import time
 
 import jax
 
+from .telemetry import trace as _trace
 from .telemetry.metrics import Histogram as _Histogram
 
 _config = {'profile_all': False, 'filename': '/tmp/mxnet_tpu_profile',
@@ -327,12 +328,13 @@ def _record(name, dt):
 
 @contextlib.contextmanager
 def scope(name='<unk>:'):
-    """Reference profiler.scope — also emits a jax named annotation so the
-    region shows up in the device trace."""
-    t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation(name):
+    """Reference profiler.scope — also an event of a running
+    ``jax.profiler`` trace, beside the device's operations, through the
+    span primitive ``mx.telemetry`` uses; the tally takes that span's
+    one duration."""
+    with _trace.profiler_span(name) as s:
         yield
-    _record(name, time.perf_counter() - t0)
+    _record(name, s.seconds)
 
 
 class Task:

@@ -11,7 +11,13 @@ dist_async training, elastic checkpoints):
   adopted by ``RpcServer``), and land in a bounded per-process ring
   buffer (the flight recorder). One user request through the router =
   one connected trace: routing → retry/failover attempts → replica
-  admission → queue wait → prefill chunks → per-step decode.
+  admission → queue wait → prefill chunks → per-step decode. The
+  recorder's clock is the wall clock; every span is also a
+  ``jax.profiler.TraceAnnotation`` for its body, so a running
+  ``jax.profiler`` trace holds it on the clock of the device's
+  operations. The Gluon train path opens ``mx.graph.*``,
+  ``mx.tape.*``, ``mx.bulk.flush`` and ``mx.trainer.*`` child spans
+  (docs/observability.md, "Tracing a training loop").
 * **Metrics registry** (:mod:`.metrics`): Counter / Gauge / Histogram
   with fixed mergeable log-scale buckets; the serving/RPC/training
   ``stats()`` surfaces register into it, the router aggregates
@@ -21,8 +27,10 @@ dist_async training, elastic checkpoints):
   cross-process clock normalization off RPC ping timestamps, plus the
   span-tree formatter behind ``tools/trace_dump.py``.
 
-Env knobs: ``MXNET_TELEMETRY`` (default on; ``0`` disables tracing —
-the disabled path is a near-no-op), ``MXNET_TELEMETRY_BUFFER`` (ring
+Env knobs: ``MXNET_TELEMETRY`` (default on; ``0`` disables tracing,
+both sinks — the disabled path is a near-no-op, and so is a
+``child_span`` with no caller's context and no profile being taken),
+``MXNET_TELEMETRY_BUFFER`` (ring
 capacity, default 4096 events), ``MXNET_TELEMETRY_SAMPLE`` (root-span
 sampling fraction, default 1.0). See docs/observability.md.
 """

@@ -19,14 +19,30 @@ Dapper-style distributed tracing with zero dependencies:
   events. :func:`snapshot_buffer` serializes it for the RPC
   ``telemetry`` verb and the Chrome-trace exporter.
 
-Timestamps are wall-clock (``time.time()``) so buffers from different
-processes land on one axis; per-peer clock offsets measured off RPC
-ping replies (:func:`note_clock`) let the exporter normalize them.
+Two sinks, two clocks. The flight recorder's timestamps are
+wall-clock (``time.time()``) so buffers from different processes land
+on one axis; per-peer clock offsets measured off RPC ping replies
+(:func:`note_clock`) let the exporter normalize them. Every span opened
+as a context manager also enters a ``jax.profiler.TraceAnnotation`` for
+its body (:class:`_ProfilerSpan`, the one place that knows how a span
+reaches the profiler): while a ``jax.profiler`` trace is being taken
+the span is an event of the ``/host:CPU`` plane, on the clock of the
+device's ``XLA Ops``, with its int and short-string attributes as the
+event's stats. :func:`emit` is retroactive and reaches the recorder
+alone. :func:`child_span` outside any context gives the profiler sink
+alone: the train path (``gluon/block.py``, ``_tape.py``, ``_bulk.py``,
+``gluon/trainer.py``) is instrumented with it, so a profile of a loop
+that opens no ``train.step`` span still says where a step's host time
+goes (docs/observability.md, "Tracing a training loop").
 
-``MXNET_TELEMETRY=0`` disables tracing: :func:`span` returns a shared
-no-op context manager, :func:`current_tc` returns ``None`` after a
-single flag check — the disabled path is a near-no-op, machine-checked
-by the overhead guard in ``tests/test_telemetry.py``.
+``MXNET_TELEMETRY=0`` disables tracing, both sinks: :func:`span` and
+:func:`child_span` return a shared no-op context manager,
+:func:`current_tc` returns ``None`` after a single flag check (the
+overhead guard in ``tests/test_telemetry.py`` checks that path). With
+tracing enabled, a :func:`child_span` that nobody listens to (no current
+context, no profile being taken) is that same no-op after two more
+checks; a recorded span pays an inactive ``TraceAnnotation`` (well under
+a microsecond) beside its record.
 ``MXNET_TELEMETRY_SAMPLE`` (default 1.0) samples ROOT spans: an
 unsampled root records nothing and propagates nothing, while children
 of a live context always record (a trace is all-or-nothing).
@@ -41,6 +57,16 @@ import os
 import random
 import threading
 import time
+
+try:
+    from jax.profiler import TraceAnnotation as _Annotation
+    _profiling = _Annotation.is_enabled     # is a profile being taken?
+except ImportError:     # tools/trace_dump.py where jax is not installed
+    def _Annotation(name, **attrs):
+        return _NOOP
+
+    def _profiling():
+        return False
 
 __all__ = ['span', 'child_span', 'attach', 'emit', 'current_tc',
            'enabled', 'configure', 'events', 'clear', 'snapshot_buffer',
@@ -158,9 +184,12 @@ def _record(name, trace_id, span_id, parent_id, t0, t1, attrs):
 
 
 class _NoopSpan:
-    """Shared do-nothing span: the entire disabled/unsampled path."""
+    """Shared do-nothing span: the entire disabled/unsampled path.
+    ``live`` is false, so a hot path computes a span's attributes only
+    ``if span.live``."""
 
     __slots__ = ()
+    live = False
 
     def __enter__(self):
         return self
@@ -171,32 +200,73 @@ class _NoopSpan:
     def set(self, **attrs):
         pass
 
+    set_metadata = set
+
 
 _NOOP = _NoopSpan()
 
+_ATTR_CHARS = 32        # longest string attribute a profile event carries
 
-class _Span:
-    __slots__ = ('name', 'trace_id', 'span_id', 'parent_id', 'attrs',
-                 't0', '_prev')
 
-    def __init__(self, name, trace_id, parent_id, attrs):
+def _plain(attrs):
+    """The attributes a profile event can carry as stats: ints and
+    short strings."""
+    return {k: v for k, v in attrs.items()
+            if isinstance(v, int)
+            or (isinstance(v, str) and len(v) <= _ATTR_CHARS)}
+
+
+class _ProfilerSpan:
+    """A span in the profiler sink alone: a ``TraceAnnotation`` round
+    the body, its duration (``seconds``) on the monotonic clock. No id,
+    no lock, nothing in the ring."""
+
+    __slots__ = ('name', 'attrs', 'seconds', '_ann', '_p0')
+    live = True
+
+    def __init__(self, name, attrs):
         self.name = name
-        self.trace_id = trace_id
-        self.parent_id = parent_id
         self.attrs = attrs
 
     def set(self, **attrs):
         self.attrs.update(attrs)
+        self._ann.set_metadata(**_plain(attrs))
+
+    def __enter__(self):
+        self._ann = _Annotation(self.name, **_plain(self.attrs))
+        self._ann.__enter__()
+        self._p0 = time.perf_counter()
+        return self
+
+    def __exit__(self, etype, exc, tb):
+        self.seconds = time.perf_counter() - self._p0
+        self._ann.__exit__(etype, exc, tb)
+        return False
+
+
+class _Span(_ProfilerSpan):
+    """Both sinks: the profiler's annotation and a record in the ring,
+    stamped on the wall clock (``t0``/``t1``) as the ring's records
+    are."""
+
+    __slots__ = ('trace_id', 'span_id', 'parent_id', 't0', '_prev')
+
+    def __init__(self, name, trace_id, parent_id, attrs):
+        _ProfilerSpan.__init__(self, name, attrs)
+        self.trace_id = trace_id
+        self.parent_id = parent_id
 
     def __enter__(self):
         self.span_id = _new_id()
         self._prev = getattr(_tls, 'ctx', None)
         _tls.ctx = (self.trace_id, self.span_id)
+        _ProfilerSpan.__enter__(self)
         self.t0 = walltime()
         return self
 
     def __exit__(self, etype, exc, tb):
         t1 = walltime()
+        _ProfilerSpan.__exit__(self, etype, exc, tb)
         _tls.ctx = self._prev
         if etype is not None:
             self.attrs['error'] = f'{etype.__name__}: {exc}'
@@ -225,15 +295,26 @@ def span(name, parent=None, **attrs):
 
 
 def child_span(name, **attrs):
-    """Like :func:`span` but a no-op when there is no current context:
-    instrumentation for hot library paths (kvstore push/pull) that
-    should only trace inside a caller-opened trace, never start one."""
+    """Like :func:`span` but never the root of a trace: instrumentation
+    for hot library paths (kvstore push/pull, the Gluon train path).
+    Inside a caller-opened context it is a child span in both sinks;
+    with no current context it records nothing in the flight recorder
+    and is an event of a running ``jax.profiler`` trace alone, or, with
+    no profile being taken either, the shared no-op: nobody listens, and
+    a hot path allocates nothing."""
     if not _enabled:
         return _NOOP
     cur = getattr(_tls, 'ctx', None)
     if cur is None:
-        return _NOOP
+        return _ProfilerSpan(name, attrs) if _profiling() else _NOOP
     return _Span(name, cur[0], cur[1], attrs)
+
+
+def profiler_span(name, **attrs):
+    """The profiler sink alone whatever ``MXNET_TELEMETRY`` says: what
+    ``mx.profiler.scope`` opens, since a user who writes a scope has
+    asked for it by name. Its ``seconds`` are set on exit."""
+    return _ProfilerSpan(name, attrs)
 
 
 class _Attach:
