@@ -25,6 +25,7 @@ import threading
 import jax
 import jax.numpy as jnp
 
+from .base import MXNetError
 from .telemetry import trace as _trace
 
 _state = threading.local()
@@ -229,9 +230,30 @@ def backward(heads, head_grads=None, retain_graph=False, train_mode=True,
                          variables, create_graph, span)
 
 
+def _check_not_updated_in_place(node):
+    """``backward()`` through a graph whose weights ``Trainer.step`` has
+    updated since it was recorded: the update donates the buffers the
+    graph holds in ``in_vals``, and what the vjp kept of them may be a
+    transposed copy that still reads, at the old value. The reference,
+    which updates in place, would differentiate at the new weights;
+    neither a deleted-array error from inside JAX nor a silent gradient
+    at the old ones will do."""
+    for v in node.in_vals:
+        if getattr(v, 'is_deleted', bool)():
+            raise MXNetError(
+                f'backward() through a retained graph (node '
+                f'{node.name!r}) whose weights were updated in place '
+                'since it was recorded: Trainer.step() writes each new '
+                'weight over the buffer the graph still holds. Call '
+                'backward() as often as needed before step() '
+                '(grad_req=\'add\' accumulates), or record the forward '
+                'again after it.')
+
+
 def _node_vjp(node, present, indexed):
     """The input cotangents of one node from the cotangents ``present``
     of its outputs."""
+    _check_not_updated_in_place(node)
     if indexed is not None:
         # segment node: zero cotangents are synthesized inside
         # the jitted vjp (symbolic zeros) instead of N host ops
@@ -463,6 +485,7 @@ def _backward_recorded(heads, head_infos, head_grads, variables,
             op = Op(f'_backward_{node.name}', bwd_fn)
             arrays = list(out_cots) + in_nds
             raws = [a._data for a in arrays]
+            _check_not_updated_in_place(node)
             res = apply_op(op, arrays,
                            lambda *r, _b=bwd_fn: _b(*r), name=op.name)
             in_cots = res if isinstance(res, tuple) else (res,)

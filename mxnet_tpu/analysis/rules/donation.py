@@ -45,7 +45,7 @@ def parse_input_output_aliases(hlo_text):
         return aliases
     i = hlo_text.index('{', start)
     depth, j = 0, i
-    for j in range(i, min(len(hlo_text), i + 10000)):
+    for j in range(i, len(hlo_text)):
         if hlo_text[j] == '{':
             depth += 1
         elif hlo_text[j] == '}':

@@ -609,9 +609,12 @@ class _CachedGraph:
                         raise
                     # a recorded-train step donated the aux buffers this
                     # thread snapshotted between the lock release and
-                    # dispatch; fall through to the serialized path,
-                    # which re-snapshots the rebound (post-donation)
-                    # state under the lock and executes while holding it
+                    # dispatch, or another thread's Trainer.step donated
+                    # a weight after _execute read it out of main_nds
+                    # (NDArrays, which the step rebinds); fall through
+                    # to the serialized path, which re-snapshots the
+                    # rebound (post-donation) state under the lock and
+                    # executes while holding it
         with self._lock:
             if self._race is not None:
                 self._race.write()

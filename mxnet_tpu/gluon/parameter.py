@@ -217,7 +217,13 @@ class Parameter:
         return list(self._data)
 
     def set_data(self, data):
-        """Set value on all contexts (reference parameter.py:set_data)."""
+        """Set value on all contexts (reference parameter.py:set_data).
+
+        The parameter takes a copy, as the reference's does: the
+        caller's array stays the caller's. ``Trainer.step`` donates a
+        parameter's buffer to the update, which would delete an adopted
+        array under its owner and, given to two parameters, be one
+        buffer donated twice."""
         self.shape = data.shape
         if self._data is None:
             if self._deferred_init is not None:
@@ -227,8 +233,8 @@ class Parameter:
                               else current_context(): None}
         src = data if isinstance(data, NDArray) else array(data)
         for c in list(self._data):
-            self._data[c] = src.as_in_context(c).astype(self.dtype,
-                                                        copy=False)
+            nd = src.as_in_context(c).astype(self.dtype, copy=False)
+            self._data[c] = nd.copy() if nd is data else nd
         if self._sharding_spec is not None and \
                 self._sharding_mesh is not None:
             # sticky sharded placement: a restored checkpoint value goes

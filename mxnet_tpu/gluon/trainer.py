@@ -8,6 +8,7 @@ priority scheduling (priority=-i for comm/compute overlap) is a no-op —
 XLA's async collectives already overlap — but the argument is accepted.
 """
 
+import collections
 import logging
 
 import jax
@@ -34,6 +35,14 @@ _UNTRACEABLE = (NotImplementedError, jax.errors.ConcretizationTypeError,
 
 
 _FUSED_SENTINEL = object()
+
+
+def _zero_hypers(n):
+    """Stand-ins for the fused update's lr, wd and step-count vectors,
+    for tracing and lowering it without a step."""
+    import jax.numpy as jnp
+    return (jnp.zeros(n, jnp.float32), jnp.zeros(n, jnp.float32),
+            jnp.zeros(n, jnp.int32))
 
 
 class Trainer:
@@ -248,6 +257,13 @@ class Trainer:
         multi_sgd/preloaded_multi_*): per-param eager dispatch of hundreds
         of tiny update ops would dominate step time on TPU. Falls back to
         the per-param loop if fused tracing fails for a custom optimizer.
+
+        The jitted call donates the weights and slots it replaces
+        (:meth:`_fused_program`): after it the arrays they held are
+        deleted, and a handle that shared one (``detach()``, a view, a
+        retained autograd graph) raises on use. The per-param loop, the
+        row-sparse path and ``update_on_kvstore`` rebind fresh arrays
+        and delete nothing.
         """
         if self._update_on_kvstore:
             return  # server-side update already applied by pushpull
@@ -256,19 +272,7 @@ class Trainer:
             # entirely (no wd/momentum mutation on zeroed grads)
             self._amp_skip_update = False
             return
-        live = []
-        sparse_live = []
-        for i, param in enumerate(self._params):
-            if param.grad_req == 'null' or param._data is None:
-                continue
-            if i not in self._states:
-                self._states[i] = self._zero1_place(
-                    param, self._optimizer.create_state_multi_precision(
-                        i, param.data()))
-            if param._grad_stype == 'row_sparse':
-                sparse_live.append((i, param))
-            else:
-                live.append((i, param))
+        live, sparse_live = self._live_params()
         if sparse_live:
             from ..ndarray import sparse as _sp
             opt = self._optimizer
@@ -309,6 +313,25 @@ class Trainer:
                 for d in datas[1:]:
                     d._rebind(datas[0]._data)
                 self._restore_placement(param)
+
+    def _live_params(self):
+        """``(dense, row_sparse)``: the ``(index, parameter)`` pairs an
+        update takes, by the storage of their gradients, each with its
+        optimizer state created."""
+        live = []
+        sparse_live = []
+        for i, param in enumerate(self._params):
+            if param.grad_req == 'null' or param._data is None:
+                continue
+            if i not in self._states:
+                self._states[i] = self._zero1_place(
+                    param, self._optimizer.create_state_multi_precision(
+                        i, param.data()))
+            if param._grad_stype == 'row_sparse':
+                sparse_live.append((i, param))
+            else:
+                live.append((i, param))
+        return live, sparse_live
 
     # ------------------------------------------------------- sharded slots
     def _zero1_place(self, param, state):
@@ -403,13 +426,46 @@ class Trainer:
                 nd._rebind(jax.device_put(nd._data, sh))
 
     # -------------------------------------------------------- fused update
-    def _fused_update(self, live):
-        import numpy as _onp
+    def _fused_program(self, live):
+        """The jitted update for ``live`` and its operands, as the next
+        launch takes them: ``(fn, donated, kept, graws)``.
+
+        ``fn(donated, kept, graws, lrs, wds, ts)`` donates its first
+        argument, ``(weights, slots)``: XLA aliases each new weight and
+        slot onto the buffer it replaces, and the arrays the parameters
+        and states held before the step are deleted by it. ``kept`` is
+        ``None``, or the same two lists holding the leaves that are
+        passed undonated, each ``None`` in the one where it is set in
+        the other. Those are what the code can see would not alias, or
+        must not be donated:
+
+        * a buffer that appears twice among the operands (two
+          Parameters on one array, ``create_state`` handing back the
+          weight, a view of it): XLA refuses a buffer that is donated
+          and used again in one call, so no occurrence is donated;
+        * a leaf whose output has another shape, dtype or layout than
+          it has (a weight whose recorded mesh layout differs from the
+          one it arrives in): it would cost a copy and a warning.
+
+        Gradients, hyperparameters and step counts are never donated.
+        This is the one donating call. The parameter-by-parameter
+        fallback, ``update_on_kvstore`` and the row-sparse path rebind
+        fresh arrays and delete nothing. ``PipelineTrainer`` and the
+        contrib ``Estimator`` step a ``gluon.Trainer`` of their own and
+        so come through here; both read the parameters anew each step
+        and hold no array across one."""
         import jax
-        import jax.numpy as jnp
-        from .. import _tape
+        from .. import _bulk, _tape
 
         opt = self._optimizer
+        # a write sync point, like backward() and a hybridized call: a
+        # pending bulk segment holds the raw arrays of its concrete
+        # inputs and launches with them only at its flush, so an eager
+        # read of a weight or slot that is still pending has to run
+        # before the update deletes what it reads. It then yields the
+        # pre-step value, the reference's read-before-write order.
+        # Nothing is pending after backward(), and this costs nothing
+        _bulk.flush_current()
 
         def flat_state(s):
             if s is None:
@@ -438,14 +494,16 @@ class Trainer:
                tuple((r.shape, str(r.dtype)) for r in praws),
                _ctx.fingerprint() if _ctx is not None else None,
                place_key)
-        fn = self._fused_cache.get(key)
-        if fn is None:
+        entry = self._fused_cache.get(key)
+        if entry is None:
             state_templates = [self._states[i] for i, _ in live]
             # under a mesh context, pin the updated weights and slots to
             # the layouts the compiled forward / ZeRO-1 plan expect:
             # GSPMD would otherwise let a replicated param inherit its
             # gradient's data-parallel sharding and break the pjit
-            # entry's declared in_shardings on the next step
+            # entry's declared in_shardings on the next step. A weight
+            # with no recorded layout keeps the one it came in with, so
+            # that its donated buffer can be written in place
             w_shard = [None] * len(live)
             s_shard = [None] * len(live)
             if _ctx is not None:
@@ -455,17 +513,21 @@ class Trainer:
                     if sp is not None and \
                             getattr(p, '_sharding_mesh', None) == _ctx.mesh:
                         w_shard[j] = NamedSharding(_ctx.mesh, sp)
-                    s_shard[j] = [
-                        e._data.sharding for e in
-                        (self._states[i] if isinstance(
-                            self._states[i], (list, tuple))
-                         else [self._states[i]])
-                        if isinstance(e, NDArray)] or None
+                    else:
+                        w_shard[j] = praws[j].sharding
+                    s_shard[j] = [e.sharding for e in sraws[j]] or None
 
             # traced inside the mesh context when there is one: the
             # fused optimizer ops then take their XLA path, which GSPMD
             # can partition (ops/pallas/fused_optimizer.py use_pallas)
-            def fused(praws_, graws_, sraws_, lrs_, wds_, ts_):
+            def fused(donated_, kept_, graws_, lrs_, wds_, ts_):
+                praws_, sraws_ = donated_
+                if kept_ is not None:
+                    praws_ = [k if d is None else d
+                              for d, k in zip(praws_, kept_[0])]
+                    sraws_ = [[k if d is None else d
+                               for d, k in zip(ds, ks)]
+                              for ds, ks in zip(sraws_, kept_[1])]
                 prev = _tape.set_recording(False)
                 try:
                     new_ws, new_ss = [], []
@@ -509,20 +571,77 @@ class Trainer:
                     _tape.set_recording(prev)
 
             n = len(live)
-            zeros = (jnp.zeros(n, jnp.float32), jnp.zeros(n, jnp.float32),
-                     jnp.zeros(n, jnp.int32))
             try:
-                fn = jax.jit(fused)
+                fn = jax.jit(fused, donate_argnums=(0,))
                 # trace-check BEFORE advancing update counts so a failed
                 # optimizer falls back without double-counting
-                jax.eval_shape(fn, praws, graws, sraws, *zeros)
+                out_ws, out_ss = jax.eval_shape(
+                    fn, (praws, sraws), None, graws, *_zero_hypers(n))
             except _UNTRACEABLE as e:
                 self._fused_cache[key] = _FUSED_SENTINEL
                 raise _FusedUnsupported(
                     f'{type(e).__name__}: {e}'.splitlines()[0])
-            self._fused_cache[key] = fn
-        elif fn is _FUSED_SENTINEL:
+
+            def aliases(raw, out, pinned):
+                return (raw.shape, raw.dtype) == (out.shape, out.dtype) \
+                    and (pinned is None or
+                         raw.sharding.is_equivalent_to(pinned, raw.ndim))
+
+            # (j, -1) a weight, (j, k) a slot: leaves no output can
+            # take the place of
+            fixed = frozenset(
+                [(j, -1) for j in range(n)
+                 if not aliases(praws[j], out_ws[j], w_shard[j])] +
+                [(j, k) for j in range(n) for k, e in enumerate(sraws[j])
+                 if k >= len(out_ss[j]) or
+                 not aliases(e, out_ss[j][k], None)])
+            entry = self._fused_cache[key] = (fn, fixed)
+        elif entry is _FUSED_SENTINEL:
             raise _FusedUnsupported('previously failed')
+        fn, fixed = entry
+
+        ids = [id(e) for raw, slots in zip(praws, sraws)
+               for e in (raw, *slots)]
+        read = set(map(id, graws))
+        if not fixed and len(read.union(ids)) == len(read) + len(ids):
+            return fn, (praws, sraws), None, graws
+        times = collections.Counter(ids)
+
+        def split(j, k, e):
+            keep = (j, k) in fixed or times[id(e)] > 1 or id(e) in read
+            return (None, e) if keep else (e, None)
+
+        w = [split(j, -1, raw) for j, raw in enumerate(praws)]
+        s = [[split(j, k, e) for k, e in enumerate(slots)]
+             for j, slots in enumerate(sraws)]
+        donated = ([d for d, _ in w], [[d for d, _ in l] for l in s])
+        kept = ([k for _, k in w], [[k for _, k in l] for l in s])
+        return fn, donated, kept, graws
+
+    def audit_donation(self):
+        """Compile the fused update as the next ``step`` would launch it
+        and read ``input_output_alias`` from its HLO (the parser of
+        ``mx.analysis``' donation-audit rule): ``{'donated_args': the
+        operand buffers passed as donated, 'aliased_args': those of
+        them an output is written over}``. Equal when the donation is
+        real. Steps nothing and deletes nothing."""
+        from ..analysis.rules.donation import parse_input_output_aliases
+        live, _ = self._live_params()
+        fn, donated, kept, graws = self._fused_program(live)
+        hlo = fn.lower(donated, kept, graws,
+                       *_zero_hypers(len(live))).compile().as_text()
+        aliased = parse_input_output_aliases(hlo)
+        # the donated leaves are the program's first parameters
+        n_donated = len(jax.tree.leaves(donated))
+        return {'donated_args': n_donated,
+                'aliased_args': sum(1 for i in aliased if i < n_donated)}
+
+    def _fused_update(self, live):
+        import numpy as _onp
+        import jax.numpy as jnp
+
+        opt = self._optimizer
+        fn, donated, kept, graws = self._fused_program(live)
 
         with _trace.child_span('mx.trainer.hyper') as hyper:
             for i, _ in live:
@@ -546,10 +665,13 @@ class Trainer:
                 hyper.set(uploaded=3 if fresh else 1)
         with _trace.child_span('mx.trainer.launch') as launch:
             if launch.live:
-                n_state = sum(map(len, sraws))
+                n_state = sum(map(len, donated[1]))
+                n_kept = 0 if kept is None else sum(
+                    e is not None for l in (kept[0], *kept[1]) for e in l)
                 launch.set(n_in=2 * len(live) + n_state + 3,
-                           n_out=len(live) + n_state)
-            new_ws, new_ss = fn(praws, graws, sraws, lrs, wds, ts)
+                           n_out=len(live) + n_state,
+                           donated=len(live) + n_state - n_kept)
+            new_ws, new_ss = fn(donated, kept, graws, lrs, wds, ts)
         for (i, param), nw, ns in zip(live, new_ws, new_ss):
             datas = param.list_data()
             datas[0]._rebind(nw)

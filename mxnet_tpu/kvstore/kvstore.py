@@ -61,7 +61,11 @@ class KVStoreLocal(KVStoreBase):
             if isinstance(v, _sp.BaseSparseNDArray):
                 self._store[k] = v.copy()   # keep sparse storage
             else:
-                self._store[k] = NDArray(v._data, ctx=v._ctx)
+                # the store's own copy, in the caller's layout
+                # (reference: init copies in, pull copies out): the
+                # caller's array may be a weight, whose buffer
+                # Trainer.step donates to the update
+                self._store[k] = NDArray(jnp.copy(v._data), ctx=v._ctx)
 
     def push(self, key, value, priority=0):
         for k, vals in _group(key, value):
@@ -77,7 +81,7 @@ class KVStoreLocal(KVStoreBase):
         for k, outs in _group(key, out):
             src = self._store[k]
             for o in outs:
-                o._rebind(src._data)
+                o._rebind(jnp.copy(src._data))
 
     def pushpull(self, key, value, out=None, priority=0):
         """Fused push+pull (reference PushPullDefault kvstore_dist.h:578).
@@ -106,8 +110,16 @@ class KVStoreLocal(KVStoreBase):
                     v._rebind(result)
 
     def broadcast(self, key, value, out, priority=0):
+        """``init`` then ``pull``. In one process the value is already
+        what every out has to hold, so an out that is the value itself
+        keeps its array: a weight stays on the buffer its recorded
+        graphs and the caller's handles know."""
         self.init(key, value)
-        self.pull(key, out=out, priority=priority)
+        sources = {id(vals[0]) for _, vals in _group(key, value)}
+        for k, outs in _group(key, out):
+            rest = [o for o in outs if id(o) not in sources]
+            if rest:
+                self.pull(k, out=rest, priority=priority)
 
     # ---------------------------------------------------------- fused path
     def fused_pushpull(self, keys, values, outs=None, priorities=None):

@@ -308,11 +308,10 @@ class KVStoreTPUSync(KVStoreLocal):
 
     def broadcast(self, key, value, out, priority=0):
         """Rank-0's value wins (reference KVStoreDist::Init semantics)."""
-        if self._nproc > 1:
-            for k, vals in _group(key, value):
-                self._store[k] = NDArray(self._bcast0(vals[0]._data))
-        else:
-            self.init(key, value)
+        if self._nproc == 1:
+            return super().broadcast(key, value, out, priority)
+        for k, vals in _group(key, value):
+            self._store[k] = NDArray(self._bcast0(vals[0]._data))
         self.pull(key, out=out, priority=priority)
 
     @property

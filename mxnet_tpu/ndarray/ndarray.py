@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from .. import _tape
+from ..base import MXNetError
 from ..context import Context, current_context
 
 __all__ = ['NDArray', 'array', 'concatenate_dtypes', '_wrap_out',
@@ -159,7 +160,18 @@ class NDArray:
 
     def asnumpy(self):
         """Copy to a host numpy array — THE sync point (ndarray.py:2574)."""
-        return _np.asarray(jax.device_get(self._data))
+        raw = self._data
+        if getattr(raw, 'is_deleted', bool)():
+            raise MXNetError(
+                'this array\'s buffer was donated to a compiled call and '
+                'written over, e.g. by Trainer.step, which updates '
+                'weights and optimizer states in place (a detach(), a '
+                'same-shape view or the raw array of Parameter.data() '
+                'shares the weight\'s buffer), or by '
+                'hybridize(donate_inputs=True): read the parameter '
+                'again, or keep copy() / asnumpy() of a value the call '
+                'should not touch')
+        return _np.asarray(jax.device_get(raw))
 
     def item(self):
         return self.asnumpy().item()
@@ -246,7 +258,11 @@ class NDArray:
         shapes must match)."""
         if isinstance(other, Context):
             dev = other.to_jax()
-            raw = self._data if _is_tracer(self._data) else jax.device_put(self._data, dev)
+            # may_alias=False: on the array's own device device_put
+            # hands back the same buffer under a new name, and a buffer
+            # that Trainer.step donates takes every holder with it
+            raw = self._data if _is_tracer(self._data) else \
+                jax.device_put(self._data, dev, may_alias=False)
             return NDArray(raw, ctx=other)
         if isinstance(other, NDArray):
             if other.shape != self.shape:
@@ -255,7 +271,10 @@ class NDArray:
                     f'{other.shape}')
             raw = self._data.astype(other.dtype) \
                 if other.dtype != self.dtype else self._data
-            other._rebind(jax.device_put(raw, other.context.to_jax()))
+            # may_alias=False as above: the destination, which may be
+            # a weight, gets a buffer of its own
+            other._rebind(jax.device_put(raw, other.context.to_jax(),
+                                         may_alias=False))
             return other
         raise TypeError(f'copyto does not support type {type(other)}')
 

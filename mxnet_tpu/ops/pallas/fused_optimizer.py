@@ -8,7 +8,9 @@ the surrounding program imposes (donation copies, sharding constraints,
 multi-output fusions split by the scheduler). One pallas_call pins the
 whole update — read param/grad/slots once, write param'/slots' once — and
 aliases param and slot buffers in place (``input_output_aliases``), which
-is the kernel-level form of the donation the Trainer preserves end to end.
+is the kernel-level form of the donation ``Trainer._fused_program`` makes
+of the same operands: the compiled update then writes each new weight and
+slot over the buffer it replaces (``Trainer.audit_donation()``).
 
 Step-varying hyperparameters (lr, wd, the bias-correction denominators
 that depend on ``t``) arrive as a tiny fp32 vector operand rather than

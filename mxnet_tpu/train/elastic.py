@@ -224,14 +224,18 @@ class ElasticTrainer:
         device stays a DEVICE array — gathering a pod-sharded FSDP
         param to host on-step would serialize the whole model through
         one host; orbax writes each shard from where it lives instead.
-        Safe to hold across steps: optimizer updates rebind the
-        parameter to NEW buffers (no donation of params), so the
-        snapshot's reference stays valid while the daemon serializes."""
+        It is a copy (shard by shard, the layout kept): the Trainer's
+        fused update donates the parameter's own buffers to the next
+        step, so the array the parameter holds now is deleted while
+        the daemon still serializes. The optimizer slots reach the
+        ``meta`` blob through ``Trainer.state_dict()``, which copies
+        them to the host on the step."""
         nd = p.data()
         raw = getattr(nd, '_data', None)
         sh = getattr(raw, 'sharding', None)
         if sh is not None and len(getattr(sh, 'device_set', ())) > 1:
-            return raw
+            import jax.numpy as jnp
+            return jnp.copy(raw)
         return nd.asnumpy()
 
     def snapshot(self, step):

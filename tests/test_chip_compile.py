@@ -133,6 +133,28 @@ def test_adam_step_compiles(one_chip, shape):
     assert _compile(step, w, w, w, w, lr, lr, t) == 1
 
 
+@pytest.mark.parametrize('shape', [(UNITS,), (UNITS, HIDDEN),
+                                   (VOCAB, UNITS)], ids=str)
+def test_adam_step_writes_over_its_donated_operands(one_chip, shape):
+    """What Trainer._fused_program donates: with w, m and v donated the
+    chip's compiler aliases each output onto its operand, through the
+    kernel's own input_output_aliases and the reshapes round it."""
+    from mxnet_tpu.analysis.rules.donation import \
+        parse_input_output_aliases
+    w, lr, t = _opt_shapes(shape, one_chip)
+
+    def step(w, m, v, g, lr, wd, t):
+        return opt_mod.adam_step(w, g, m, v, lr, wd, t, beta1=0.9,
+                                 beta2=0.999, epsilon=1e-8)
+
+    compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+        w, w, w, w, lr, lr, t).compile()
+    assert parse_input_output_aliases(compiled.as_text()) == \
+        {0: 0, 1: 1, 2: 2}
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        3 * 4 * w.size
+
+
 @pytest.mark.parametrize('shape', [(UNITS,), (2, UNITS), (UNITS, HIDDEN),
                                    (VOCAB, UNITS)], ids=str)
 def test_sgd_mom_step_compiles(one_chip, shape):

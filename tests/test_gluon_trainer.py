@@ -210,3 +210,329 @@ def test_bf16_cast_net_keeps_dtype_across_steps():
     g = mx.np.ones(net2.weight.shape, dtype='bfloat16')
     opt.update(0, net2.weight.data(), g, state)
     assert str(net2.weight.data().dtype) == 'bfloat16'
+
+
+# ------------------------------------------------- the donating update
+def _steps(net, trainer, n, x=None, batch=4):
+    x = mx.np.ones((batch, 2)) if x is None else x
+    for _ in range(n):
+        with autograd.record():
+            loss = (net(x) ** 2).sum()
+        loss.backward()
+        trainer.step(batch)
+
+
+def _state_leaves(trainer):
+    out = []
+    for s in trainer._states.values():
+        for e in (s if isinstance(s, (list, tuple)) else [s]):
+            if e is not None:
+                out.append(e)
+    return out
+
+
+@pytest.mark.parametrize('name,kwargs,slots', [
+    ('sgd', {'learning_rate': 0.1}, 0),
+    ('sgd', {'learning_rate': 0.1, 'momentum': 0.9}, 1),
+    ('adam', {'learning_rate': 0.01}, 2),
+])
+def test_step_donates_the_weights_and_slots_it_replaces(name, kwargs,
+                                                        slots):
+    """After step() the arrays a parameter and its slots held before it
+    are deleted (their buffers are the new values'), and the values are
+    the parameter-by-parameter update's."""
+    net, ref = _make_net(), _make_net()
+    for p, q in zip(net.collect_params().values(),
+                    ref.collect_params().values()):
+        q.set_data(p.data())
+    trainer = gluon.Trainer(net.collect_params(), name, dict(kwargs))
+    opt = mx.optimizer.create(name, **kwargs)
+    ref_params = list(ref.collect_params().values())
+    ref_states = [opt.create_state_multi_precision(i, p.data())
+                  for i, p in enumerate(ref_params)]
+    x = mx.np.array(np.random.randn(4, 2).astype('float32'))
+    for step in range(3):
+        for n_, o_ in ((net, None), (ref, opt)):
+            with autograd.record():
+                loss = (n_(x) ** 2).sum()
+            loss.backward()
+        held = [p.data()._data for p in net.collect_params().values()] \
+            + [e._data for e in _state_leaves(trainer)]
+        trainer.step(4)
+        opt.rescale_grad = 1.0 / 4
+        for i, p in enumerate(ref_params):
+            opt.update_multi_precision(i, p.data(), p.grad(),
+                                       ref_states[i])
+        assert len(held) == (2 if step == 0 else 2 * (1 + slots))
+        assert all(a.is_deleted() for a in held), step
+    assert len(_state_leaves(trainer)) == 2 * slots
+    assert not trainer._fused_fallback_taken
+    for p, q in zip(net.collect_params().values(), ref_params):
+        # a few float32 ulp: XLA fuses the jitted update's arithmetic
+        assert_almost_equal(p.data().asnumpy(), q.data().asnumpy(),
+                            rtol=1e-5, atol=1e-6)
+
+
+def _mlp():
+    net = nn.HybridSequential()
+    net.add(nn.Dense(16, in_units=8, activation='relu'),
+            nn.Dense(8, in_units=16))
+    net.initialize()
+    net.hybridize()
+    return net
+
+
+@pytest.mark.parametrize('dp', [None, 4])
+def test_compiled_update_aliases_every_donated_operand(dp):
+    """The claim machine-checked: every operand the fused update donates
+    is in the compiled program's input_output_alias table, on one device
+    and under a mesh once the layouts have settled."""
+    import contextlib
+    import warnings
+    scope = mx.sharding.mesh(dp=dp) if dp else contextlib.nullcontext()
+    with scope, warnings.catch_warnings():
+        warnings.simplefilter('error')      # 'donated buffers not usable'
+        net = _mlp()
+        trainer = gluon.Trainer(net.collect_params(), 'adam',
+                                {'learning_rate': 0.01})
+        _steps(net, trainer, 3, x=mx.np.ones((8, 8)), batch=8)
+        audit = trainer.audit_donation()
+        if dp:
+            shardings = {len(p.data()._data.sharding.device_set)
+                         for p in net.collect_params().values()}
+            assert shardings == {dp}
+    n_params = len(net.collect_params())
+    assert audit == {'donated_args': 3 * n_params,
+                     'aliased_args': 3 * n_params}
+    # the audit stepped nothing and deleted nothing
+    assert trainer.optimizer.num_update == 3
+    for p in net.collect_params().values():
+        assert np.isfinite(p.data().asnumpy()).all()
+
+
+class _PrevWeightSGD(mx.optimizer.Optimizer):
+    """A state that is the weight's own array, as DCASGD's is."""
+
+    def create_state(self, index, weight):
+        return mx.nd.NDArray(weight._data)
+
+    def step(self, w, g, state, lr, wd, t):
+        new_w = w - lr * (self._prep(g) + 0.5 * (w - state._data))
+        return new_w, new_w
+
+
+def test_a_buffer_met_twice_is_not_donated():
+    """Two parameters on one raw array, and a state that is the weight
+    itself: XLA refuses a buffer that is donated and used again in one
+    call, so the update passes those undonated and still steps."""
+    from mxnet_tpu import telemetry
+    net = _make_net()
+    twin = _make_net()
+    twin.weight.data()._rebind(net.weight.data()._data)
+    params = list(net.collect_params().values()) \
+        + list(twin.collect_params().values())
+    trainer = gluon.Trainer(params, 'adam', {'learning_rate': 0.01})
+    x = mx.np.ones((4, 2))
+    telemetry.configure(enabled=True, sample=1.0)
+    with telemetry.span('train.step'):
+        with autograd.record():
+            loss = (net(x) ** 2).sum() + (twin(x) ** 2).sum()
+        loss.backward()
+        shared = net.weight.data()._data
+        trainer.step(4)
+    launch = [e for e in telemetry.events()
+              if e['name'] == 'mx.trainer.launch'][-1]['attrs']
+    # four weights and eight slots; the shared weight's two occurrences
+    # are passed as they are
+    assert launch['n_out'] == 12 and launch['donated'] == 10
+    assert not shared.is_deleted()
+    assert_almost_equal(net.weight.data().asnumpy(),
+                        twin.weight.data().asnumpy())
+    assert net.weight.data()._data is not twin.weight.data()._data
+
+    net = _make_net()
+    trainer = gluon.Trainer(net.collect_params(), _PrevWeightSGD(
+        learning_rate=0.1))
+    w0 = net.weight.data().asnumpy()
+    _steps(net, trainer, 2)
+    assert not trainer._fused_fallback_taken
+    assert not np.allclose(net.weight.data().asnumpy(), w0)
+
+
+def test_backward_through_a_graph_whose_weights_were_stepped():
+    """retain_graph, step, backward: the graph's weights were updated in
+    place, and the error says so instead of 'Array has been deleted'."""
+    net = _make_net()
+    trainer = gluon.Trainer(net.collect_params(), 'sgd',
+                            {'learning_rate': 0.1})
+    x = mx.np.ones((4, 2))
+    with autograd.record():
+        loss = (net(x) ** 2).sum()
+    loss.backward(retain_graph=True)
+    trainer.step(4)
+    with pytest.raises(mx.MXNetError, match='updated in place'):
+        loss.backward()
+
+
+@pytest.mark.parametrize('hybridize', [False, True])
+def test_two_backwards_before_one_step_accumulate(hybridize):
+    """Nothing is donated until the update: gradient accumulation over a
+    retained graph works as before."""
+    net = _make_net()
+    if hybridize:
+        net.hybridize()
+    for p in net.collect_params().values():
+        p.grad_req = 'add'
+    trainer = gluon.Trainer(net.collect_params(), 'sgd',
+                            {'learning_rate': 0.1})
+    x = mx.np.array(np.random.randn(4, 2).astype('float32'))
+    _steps(net, trainer, 1, x=x)     # past the first step's set-up
+    for p in net.collect_params().values():
+        p.zero_grad()
+    with autograd.record():
+        loss = (net(x) ** 2).sum()
+    loss.backward(retain_graph=True)
+    once = net.weight.grad().asnumpy().copy()
+    loss.backward()
+    assert_almost_equal(net.weight.grad().asnumpy(), 2 * once)
+    w0 = net.weight.data().asnumpy()
+    trainer.step(4)
+    assert_almost_equal(net.weight.data().asnumpy(),
+                        w0 - 0.1 * 2 * once / 4, rtol=1e-5, atol=1e-6)
+
+
+def test_copyto_a_weight_gives_it_a_buffer_of_its_own():
+    """x.copyto(w.data()) copies (the reference's does): on the same
+    device device_put would hand the weight x's own buffer, which the
+    step then deletes under its owner, and two weights filled from one
+    x would share a buffer and lose donation."""
+    net, other = _make_net(), _make_net()
+    x = mx.np.array([[0.5, -0.25]])
+    x.copyto(net.weight.data())
+    x.copyto(other.weight.data())
+    where = [a._data.unsafe_buffer_pointer()
+             for a in (x, net.weight.data(), other.weight.data())]
+    assert len(set(where)) == 3
+    trainer = gluon.Trainer(net.collect_params(), 'sgd',
+                            {'learning_rate': 0.1})
+    _steps(net, trainer, 2)
+    assert_almost_equal(x.asnumpy(), np.array([[0.5, -0.25]], 'float32'))
+    assert_almost_equal(other.weight.data().asnumpy(), x.asnumpy())
+
+
+def test_set_data_leaves_the_callers_array_alone():
+    """set_data copies (the reference's does): a step neither deletes
+    nor rebinds the caller's array, and one array given to two
+    parameters makes two buffers."""
+    net, other = _make_net(), _make_net()
+    x = mx.np.array([[0.5, -0.25]])
+    net.weight.set_data(x)
+    other.weight.set_data(x)
+    assert net.weight.data() is not x
+    assert net.weight.data()._data is not other.weight.data()._data
+    trainer = gluon.Trainer(
+        list(net.collect_params().values())
+        + list(other.collect_params().values()), 'sgd',
+        {'learning_rate': 0.1})
+    for _ in range(2):
+        with autograd.record():
+            loss = (net(mx.np.ones((4, 2))) ** 2).sum() \
+                + (other(mx.np.ones((4, 2))) ** 2).sum()
+        loss.backward()
+        trainer.step(4)
+    assert_almost_equal(x.asnumpy(), np.array([[0.5, -0.25]], 'float32'))
+    assert not np.allclose(net.weight.data().asnumpy(), x.asnumpy())
+
+
+@pytest.mark.parametrize('handle', ['detach', 'copy', 'asnumpy'])
+def test_a_held_handle_to_a_weight_after_a_step(handle):
+    """copy() and asnumpy() are the caller's own and keep the value;
+    detach() shares the weight's buffer, which the step wrote over: a
+    clear error on use, never a silently stale value."""
+    net = _make_net()
+    trainer = gluon.Trainer(net.collect_params(), 'sgd',
+                            {'learning_rate': 0.1})
+    _steps(net, trainer, 1)
+    before = net.weight.data().asnumpy().copy()
+    held = getattr(net.weight.data(), handle)()
+    _steps(net, trainer, 1)
+    if handle == 'detach':
+        with pytest.raises(mx.MXNetError, match='buffer was donated'):
+            held.asnumpy()
+    else:
+        assert_almost_equal(np.asarray(held), before)
+
+
+@pytest.mark.parametrize('pending', ['weight', 'slot', 'forward'])
+def test_a_lazy_read_pending_in_the_bulking_engine_sees_the_old_value(
+        pending):
+    """The bulking engine (on by default on the TPU, forced here) keeps
+    a pending segment's concrete inputs by their raw arrays and launches
+    with them only at its flush. The donating update is a write sync
+    point: a read of a weight or slot that is still lazy when step()
+    runs yields the pre-step value (the reference's read-before-write
+    order), not JAX's 'Array has been deleted' at a later flush."""
+    from mxnet_tpu import engine
+    net = _make_net()
+    trainer = gluon.Trainer(net.collect_params(), 'sgd',
+                            {'learning_rate': 0.1, 'momentum': 0.9})
+    _steps(net, trainer, 1)
+    w0 = net.weight.data().asnumpy().copy()
+    m0 = trainer._states[0].asnumpy().copy()
+    x = mx.np.ones((4, 2))
+    with engine.bulk(100):
+        if pending == 'forward':
+            # an eager forward with no backward() before update(): the
+            # gradients are those of the step before
+            lazy = net(x)
+            want = x.asnumpy() @ w0.T + net.bias.data().asnumpy()
+            trainer.update(4)
+        else:
+            with autograd.record():
+                loss = (net(x) ** 2).sum()
+            loss.backward()
+            if pending == 'weight':
+                lazy, want = (net.weight.data() ** 2).sum(), (w0 ** 2).sum()
+            else:
+                lazy, want = trainer._states[0] * 2, m0 * 2
+            assert lazy._lazy is not None and lazy._lazy.value is None
+            trainer.step(4)
+        got = lazy.asnumpy()
+    assert_almost_equal(got, want, rtol=1e-5, atol=1e-6)
+    assert not np.allclose(net.weight.data().asnumpy(), w0)
+
+
+@pytest.mark.parametrize('async_save', [False, True])
+def test_elastic_snapshot_of_a_sharded_param_outlives_the_step(
+        tmp_path, async_save):
+    """ElasticTrainer keeps a parameter that is sharded over several
+    devices on the device for the checkpoint writer: a copy, since the
+    next step donates the parameter's own buffers."""
+    from mxnet_tpu.parallel.checkpoint import SharedCheckpointManager
+    from mxnet_tpu.train import ElasticTrainer
+    with mx.sharding.mesh(dp=4):
+        net = _mlp()
+        params = dict(net.collect_params())
+        trainer = gluon.Trainer(params, 'adam', {'learning_rate': 0.01})
+        x = mx.np.ones((8, 8))
+        _steps(net, trainer, 2, x=x, batch=8)
+        name, p = next((n, p) for n, p in params.items()
+                       if len(p.data()._data.sharding.device_set) > 1)
+        et = ElasticTrainer(params, trainer,
+                            SharedCheckpointManager(str(tmp_path)),
+                            name=f'donate{int(async_save)}',
+                            async_save=async_save)
+        saved = {n: q.data().asnumpy().copy() for n, q in params.items()}
+        tree = et.snapshot(2)
+        live = p.data()._data
+        assert tree['params'][name] is not live
+        _steps(net, trainer, 1, x=x, batch=8)
+        assert live.is_deleted()
+        et._manager.save(2, tree) if not async_save else (
+            et._daemon.submit(2, tree), et.flush(timeout=60))
+        assert not np.allclose(p.data().asnumpy(), saved[name])
+        assert et.restore() == 2
+        for n, q in params.items():
+            np.testing.assert_array_equal(saved[n], q.data().asnumpy())
+        assert trainer.optimizer.num_update == 2
+        et.close()

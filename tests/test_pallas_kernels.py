@@ -172,8 +172,10 @@ def test_trainer_fused_path_still_bit_stable():
         y = net(x)
         loss = (y * y).sum()
     loss.backward()
-    w0 = jnp.asarray(net.weight.data()._data)
-    gw = jnp.asarray(net.weight.grad()._data)
+    # host copies: jnp.asarray of a jax array is that array, and the
+    # step donates the weight's buffer to the update
+    w0 = jnp.asarray(onp.asarray(net.weight.data()._data))
+    gw = jnp.asarray(onp.asarray(net.weight.grad()._data))
     tr = Trainer(net.collect_params(), 'adam',
                  {'learning_rate': 0.01, 'wd': 0.0})
     tr.step(1)

@@ -30,7 +30,7 @@ ATTRS = {
     'mx.bulk.flush': {'n_ops', 'n_out', 'compiled'},
     'mx.trainer.step': {'n_params'},
     'mx.trainer.hyper': {'uploaded'},
-    'mx.trainer.launch': {'n_in', 'n_out'},
+    'mx.trainer.launch': {'n_in', 'n_out', 'donated'},
 }
 
 
@@ -150,9 +150,11 @@ def test_the_spans_carry_their_counts(bulked_steps):
     assert one['mx.graph.launch'] == {'n_out': 1}
     assert one['mx.trainer.step'] == {'n_params': n_params}
     assert one['mx.trainer.hyper'] == {'uploaded': 1}
-    # w, g and Adam's two slots in; w and the slots out
+    # w, g and Adam's two slots in; w and the slots out, each written
+    # over the distinct buffer it replaces
     assert one['mx.trainer.launch'] == {'n_in': 4 * n_params + 3,
-                                        'n_out': 3 * n_params}
+                                        'n_out': 3 * n_params,
+                                        'donated': 3 * n_params}
     assert one['mx.tape.backward']['n_vars'] == n_params
     # the two compiled nodes of the tape: the loss segment, the net
     vjps = sorted(e['attrs']['n_out'] for e in events
