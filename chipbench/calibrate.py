@@ -14,12 +14,13 @@ line a seed. Refuses without the cell's chips, as ``run.py`` does.
 
 import argparse
 import gc
-import importlib
 import json
 import statistics
 import sys
 
 from run import first_steps, load_cell, place_compile_cache
+
+from chipbench import check
 
 
 def cut(batches, rows):
@@ -36,6 +37,12 @@ def main(argv=None):
     ap.add_argument('--faults', action='store_true')
     args = ap.parse_args(argv)
     cell, cfg = load_cell(args.workload)
+    if args.faults:
+        try:
+            kept = check.fault_rows(cell)
+        except ValueError as e:
+            print(f'{args.workload}: {e}', file=sys.stderr)
+            return 2
 
     import jax
     devs = jax.devices()
@@ -44,9 +51,9 @@ def main(argv=None):
               f'{devs[0].platform}', file=sys.stderr)
         return 1
     import mxnet_tpu as mx
-    from chipbench import check
+    from chipbench import families
     place_compile_cache()
-    family = importlib.import_module(f'chipbench.families.{cfg["family"]}')
+    family = families.load(cfg['family'])
     for seed in (int(s) for s in args.seeds.split(',')):
         job = family.Job(cfg, cell, seed, mx.tpu(0))
         with job.scope():
@@ -68,13 +75,9 @@ def main(argv=None):
             line['control_bfloat16'] = check.compare(
                 job.follow_reference(pool, dtype='bfloat16'), want)[0]
         if args.faults:
-            rows = cell['batch']
-            line['fault_half_batch'] = check.compare(
-                job.follow_reference(cut(pool, rows // 2)), want)[0]
-            if cell['chips'] > 1:
-                line['fault_no_exchange'] = check.compare(
-                    job.follow_reference(cut(pool, rows // cell['chips'])),
-                    want)[0]
+            for fault, rows in kept.items():
+                line['fault_' + fault] = check.compare(
+                    job.follow_reference(cut(pool, rows)), want)[0]
         print(json.dumps(line), flush=True)
         del job, got, want
         gc.collect()

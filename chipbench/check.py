@@ -28,6 +28,22 @@ STEPS = 3
 DEAD_LEAF = 1e-3       # of the median leaf's gradient norm
 
 
+def fault_rows(cell):
+    """{fault: the rows of a batch it keeps}. A cell whose batch leaves
+    a fault no row is refused: the reference on an empty batch is a NaN,
+    not a reading, and the fault cannot be told from a sound run."""
+    rows = {'half_batch': cell['batch'] // 2}
+    if cell['chips'] > 1:
+        rows['no_exchange'] = cell['batch'] // cell['chips']
+    for fault, kept in rows.items():
+        if kept < 1:
+            raise ValueError(
+                f'a batch of {cell["batch"]} row(s) on {cell["chips"]} '
+                f'chip(s) leaves the fault {fault} no row to keep: the '
+                'cell needs at least two rows, and one a chip')
+    return rows
+
+
 def norms_of(raws, minus=None, scale=1.0, parts=None):
     """{name: raw array} -> {name: float norm}, in one jitted call; of
     ``raws[name] - minus[name]`` where ``minus`` is given. A leaf named in
@@ -56,6 +72,19 @@ def norms_of(raws, minus=None, scale=1.0, parts=None):
     keys = [n if k == 1 else f'{n}[{j}]'
             for n, k in zip(names, split) for j in range(k)]
     return dict(zip(keys, got.tolist()))
+
+
+def named_parts(norms):
+    """{name: a norm, or a vector of them, one a part} -> {name: float}
+    under the names :func:`norms_of` gives: ``name`` or ``name[j]``."""
+    out = {}
+    for name, a in norms.items():
+        a = np.asarray(a)
+        if a.ndim == 0:
+            out[name] = float(a)
+        else:
+            out.update({f'{name}[{j}]': float(x) for j, x in enumerate(a)})
+    return out
 
 
 def worst_leaf(got, want, leaves):

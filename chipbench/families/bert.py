@@ -9,14 +9,18 @@ picks the head and the loss: ``classify`` is the loop of
 
 This file is also what knows the program's surface: the names the zoo
 gives the parameters that ``reference/bert.py`` makes, the Trainer's Adam
-slots, and the XLA module name of the fused update.
+slots, and the XLA module name of the fused update. ``__init__.py`` says
+what a family owns.
 """
 
 import contextlib
+import copy
 import importlib
 import time
 
 import numpy as np
+
+from .. import check
 
 UPDATE_PROGRAM = 'jit_fused'    # gluon/trainer.py: jax.jit(fused)
 ADAM_BETA1 = 0.9                # the program's default, stated per config
@@ -64,20 +68,31 @@ def by_program_name(tree):
 
 def norms_by_program_name(norms):
     """The reference's leaf norms under the names ``check.norms_of``
-    gives the program's: ``name`` or, for a fused leaf, ``name[j]``."""
-    out = {}
-    for name, a in by_program_name(norms).items():
-        a = np.asarray(a)
-        if a.ndim == 0:
-            out[name] = float(a)
-        else:
-            out.update({f'{name}[{j}]': float(x) for j, x in enumerate(a)})
-    return out
+    gives the program's: ``name`` or, for a leaf read in parts,
+    ``name[j]``."""
+    return check.named_parts(by_program_name(norms))
 
 
 def _sibling(kind):
     return importlib.import_module(
         f'{__package__.rsplit(".", 1)[0]}.{kind}.bert')
+
+
+def tiny(cell, cfg):
+    """(cell, config) at a size a test on the CPU can hold: every width
+    of the configuration and every length of the cell shrunk, nothing
+    else changed."""
+    cell, cfg = copy.deepcopy(cell), copy.deepcopy(cfg)
+    cfg.update(hidden_size=64, intermediate_size=128, num_attention_heads=2,
+               num_hidden_layers=2, vocab_size=2000,
+               max_position_embeddings=32)
+    cell.update(batch=8, positions=32 if cell['lengths'] is None else 16,
+                pool=4, reference_block_rows=4)
+    if cell.get('lengths'):
+        cell['lengths'].update(median=8, min=3, max=16)
+    if cell.get('mlm_predicted'):
+        cell['mlm_predicted'] = 5
+    return cell, cfg
 
 
 class Job:
@@ -229,6 +244,10 @@ class Job:
             self.cfg, self.cell['job'], batch['lengths'],
             self.cell.get('mlm_predicted', 0))
 
+    def part_flops(self, batch):
+        return {'attention': self.flops.attention_flops(
+            self.cfg, batch['lengths'])}
+
     def update_bytes(self):
         return self.flops.update_bytes(self.cfg, self.cell['job'])
 
@@ -290,3 +309,36 @@ class Job:
     def free(self):
         """Drop the program's state so the reference has the chip."""
         self.net = self.trainer = self.loss_fn = None
+
+    # ------------------- the reference's side of the agreement tests
+    def _reference_batch(self, batch):
+        import jax.numpy as jnp
+        return {k: jnp.asarray(v) for k, v in
+                self.reference_batches([batch])[0].items()}
+
+    def reference_forward(self, batch):
+        """What the reference gives for each output of ``forward``: the
+        classifier's logits, or (None, the next-sentence logits): the
+        reference makes the MLM logits at the predicted positions only."""
+        import jax
+        import jax.numpy as jnp
+        ref = self.reference
+        p = ref.init_params(self.cfg, self.cell['job'], self.seed)
+        b = self._reference_batch(batch)
+        with jax.default_matmul_precision('highest'):
+            seq = ref.encode(p, self.cfg, b['tokens'], b['types'],
+                             b.get('valid_length'))
+            pooled = jnp.tanh(ref.linear(seq[:, 0], p['pooler_w'],
+                                         p['pooler_b']))
+            if self.kind == 'classify':
+                return [ref.linear(pooled, p['head_w'], p['head_b'])]
+            return [None, ref.linear(pooled, p['nsp_w'], p['nsp_b'])]
+
+    def reference_loss_and_gradients(self, batch):
+        import jax
+        ref = self.reference
+        p = ref.init_params(self.cfg, self.cell['job'], self.seed)
+        with jax.default_matmul_precision('highest'):
+            loss, grad = jax.value_and_grad(ref.loss_fn)(
+                p, self.cfg, self.cell['job'], self._reference_batch(batch))
+        return loss, by_program_name(grad)

@@ -40,6 +40,17 @@ def step_flops(cfg, job, lengths, predicted=0):
     return 3 * forward_flops(cfg, job, lengths, predicted)
 
 
+def attention_flops(cfg, lengths):
+    """The part of :func:`step_flops` that is attention's own: in every
+    layer the scores and the context of the real tokens (2 n^2 U each, all
+    heads together), forward and the two gradients of each. The
+    projections round them are not attention's, and a backward pass that
+    computes the scores again earns nothing for it."""
+    per_row = sum(2 * 2 * int(n) * int(n) * cfg['hidden_size']
+                  for n in lengths)
+    return 3 * cfg['num_hidden_layers'] * per_row
+
+
 def param_count(cfg, job):
     u, h = cfg['hidden_size'], cfg['intermediate_size']
     n = (cfg['vocab_size'] + cfg['type_vocab_size']

@@ -6,4 +6,4 @@ from .. import program_trace
 
 def read(run):
     return program_trace.span_ms_per_step(
-        program_trace.of_run(), 'mx.tape.vjp')
+        program_trace.of_run(run), 'mx.tape.vjp')

@@ -7,4 +7,4 @@ from .. import program_trace
 
 def read(run):
     return program_trace.span_ms_per_step(
-        program_trace.of_run(), 'mx.graph.call', self_time=True)
+        program_trace.of_run(run), 'mx.graph.call', self_time=True)

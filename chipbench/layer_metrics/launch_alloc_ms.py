@@ -6,4 +6,4 @@ from .. import program_trace
 
 
 def read(run):
-    return program_trace.launch_alloc_ms_per_step(program_trace.of_run())
+    return program_trace.launch_alloc_ms_per_step(program_trace.of_run(run))

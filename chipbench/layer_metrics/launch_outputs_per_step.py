@@ -7,4 +7,4 @@ from .. import program_trace
 
 
 def read(run):
-    return program_trace.launch_outputs_per_step(program_trace.of_run())
+    return program_trace.launch_outputs_per_step(program_trace.of_run(run))

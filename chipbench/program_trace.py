@@ -22,11 +22,14 @@ the one ``.xplane.pb`` of a ``--trace 1`` run into, inside
 The program's ``jax.named_scope``s (``mx.attention``, ``mx.layer_norm``,
 ``mx.optimizer_step``) reach a v5e profile as a stat of each operation's
 event *metadata* (its ``op_name``), which ``jax.profiler.ProfileData``
-does not yield (PERF.md section 3). The print-out alone reads them, by
-the schema TensorFlow ships (:func:`device_seconds_by_scope`); no reader
-of a metric does.
+does not yield (PERF.md section 3). ``trace_reduce.load`` reads them by
+the schema TensorFlow ships and keeps each operation's scope; the
+print-out sums them (:func:`device_seconds_by_scope`), and a per-layer
+reader takes ``scope_s`` of ``trace_reduce.reduce``.
 
-The per-layer readers under ``layer_metrics/`` take :func:`of_run`;
+The file is parsed once, by ``trace_reduce.load_dir``, for both. The
+per-layer readers under ``layer_metrics/`` take :func:`of_run`, which
+finds the trace directory in the ``run`` the runner hands them;
 
     python3 chipbench/program_trace.py <trace_dir>
 
@@ -36,10 +39,7 @@ the spans (an older commit) reads as no span at all and nothing raises.
 
 import bisect
 import functools
-import glob
-import importlib.util
 import os
-import re
 import sys
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -47,11 +47,10 @@ if os.path.dirname(HERE) not in sys.path:
     sys.path.insert(0, os.path.dirname(HERE))
 
 from chipbench import trace_reduce
-from chipbench.trace_reduce import clipped, gaps, merged, minus, total
+from chipbench.trace_reduce import (ALLOCATION, BENCH, PROGRAM, clipped,
+                                    innermost, merged, minus, profile_under,
+                                    total)
 
-TRACE_DIR = os.path.join(HERE, '.trace')    # where a --trace 1 run writes
-PROGRAM = 'mx.'                 # prefix of the program's spans
-BENCH = 'chipbench.'            # prefix of the benchmark's own spans
 WINDOW = BENCH + 'window'
 STEP = BENCH + 'update'         # one a step
 PHASES = tuple(BENCH + p for p in trace_reduce.PHASES)
@@ -59,65 +58,17 @@ PHASES = tuple(BENCH + p for p in trace_reduce.PHASES)
 # number of buffers the call hands back
 LAUNCHES = ('mx.graph.launch', 'mx.tape.vjp', 'mx.trainer.launch',
             'mx.bulk.flush')
-# PjRt's own host events (PERF.md section 3): the allocation of a
-# program's output buffers, one AllocateRawBuffer a buffer
-ALLOCATION = re.compile(r'^Allocate')
-ONE_BUFFER = 'AllocateRawBuffer'
+ONE_BUFFER = 'AllocateRawBuffer'     # one of PjRt's events a buffer
 
 
-_SCOPE = re.compile(r'\bmx\.[a-z_]+')
-
-
-def scope_of(op_name):
-    """The ``mx.`` scope in an operation's ``op_name``, None where there
-    is none. Forward and backward read alike: the tape takes ``jax.vjp``
-    of the jitted forward, and the backward program it launches names
-    its operations ``jit(pure_fn)/mx.attention/...`` as the forward's."""
-    m = _SCOPE.search(op_name)
-    return m.group(0) if m else None
-
-
-def profile_under(trace_dir):
-    """The one .xplane.pb the profiler wrote under ``trace_dir``."""
-    paths = glob.glob(os.path.join(trace_dir, 'plugins', 'profile', '*',
-                                   '*.xplane.pb'))
-    if len(paths) != 1:
-        raise FileNotFoundError(
-            f'want one .xplane.pb under {trace_dir}, found {len(paths)}')
-    return paths[0]
-
-
-def load(path):
-    """An .xplane.pb as plain data: ``{'host': [(name, start, end, line,
-    attrs)], 'devices': {n: [(start, end)]}}``, in nanoseconds. Of the
-    host's events the program's, the benchmark's and PjRt's allocations
-    are kept; of a device's, the ``XLA Ops``."""
-    import jax
-    data = jax.profiler.ProfileData.from_file(path)
-    host, devices = [], {}
-    for plane in data.planes:
-        m = trace_reduce.DEVICE_PLANE.match(plane.name)
-        if m:
-            ops = devices.setdefault(int(m.group(1)), [])
-            for line in plane.lines:
-                if line.name == trace_reduce.OP_LINE:
-                    ops += [(ev.start_ns, ev.start_ns + ev.duration_ns)
-                            for ev in line.events]
-        elif plane.name == trace_reduce.HOST_PLANE:
-            for line in plane.lines:
-                for ev in line.events:
-                    name = ev.name
-                    if name.startswith(PROGRAM):
-                        attrs = {k: v for k, v in ev.stats
-                                 if isinstance(v, int)}
-                    elif name.startswith(BENCH) or ALLOCATION.match(name):
-                        attrs = {}
-                    else:
-                        continue
-                    host.append((name, ev.start_ns,
-                                 ev.start_ns + ev.duration_ns, line.name,
-                                 attrs))
-    return {'host': host, 'devices': devices}
+def of_loaded(trace):
+    """``trace_reduce.load``'s data as :func:`analyse` takes it:
+    ``{'host': [(name, start, end, line, attrs)], 'devices': {n: [(start,
+    end)]}}``, in nanoseconds: the host events it kept, and of a device
+    its ``XLA Ops``."""
+    return {'host': trace['host'],
+            'devices': {n: [(s, e) for _, s, e, *_ in dev['ops']]
+                        for n, dev in trace['devices'].items()}}
 
 
 def _nest(spans):
@@ -139,31 +90,6 @@ def _holds(intervals, t):
     """Whether one of the merged ``intervals`` holds ``t``."""
     i = bisect.bisect_right(intervals, (t, float('inf'))) - 1
     return i >= 0 and t < intervals[i][1]
-
-
-def _innermost(spans):
-    """``at(t)``: the name of the innermost of ``spans`` (one thread
-    line's, properly nested) that holds ``t``, else None."""
-    edges = []          # (time, name in force from then on), in order
-    stack = []
-
-    def close():
-        _, end = stack.pop()
-        edges.append((end, stack[-1][0] if stack else None))
-
-    for name, s, e, *_ in sorted(spans, key=lambda sp: (sp[1], -sp[2])):
-        while stack and stack[-1][1] <= s:
-            close()
-        stack.append((name, e))
-        edges.append((s, name))
-    while stack:
-        close()
-    times = [t for t, _ in edges]
-
-    def at(t):
-        i = bisect.bisect_right(times, t) - 1
-        return edges[i][1] if i >= 0 else None
-    return at
 
 
 def analyse(trace):
@@ -234,71 +160,35 @@ def idle_by_program_span(trace, inside, lo, hi):
     if not trace['devices']:
         return {}
     main = next(sp[3] for sp in inside if sp[0] == WINDOW)
-    at = _innermost([sp for sp in inside if sp[3] == main
-                     and (sp[0].startswith(PROGRAM) or sp[0] in PHASES)])
-    out = {}
-    for ops in trace['devices'].values():
-        busy = merged(clipped(ops, lo, hi))
-        for s, e in gaps(busy, lo, hi):
-            name = at(s) or 'between'
-            name = name[len(BENCH):] if name.startswith(BENCH) else name
-            out[name] = out.get(name, 0.0) + \
-                (e - s) * 1e-9 / len(trace['devices'])
-    return out
+    at = innermost([sp for sp in inside if sp[3] == main
+                    and (sp[0].startswith(PROGRAM) or sp[0] in PHASES)])
+
+    def doing(t):
+        name = at(t)
+        return name[len(BENCH):] if name in PHASES else name
+
+    return trace_reduce.idle_by(
+        doing, [merged(clipped(ops, lo, hi))
+                for ops in trace['devices'].values()], lo, hi)
 
 
-def _schema():
-    """The schema of an .xplane.pb (tsl/profiler/protobuf/xplane.proto)
-    as TensorFlow ships it, loaded by its file: importing the package
-    would bring TensorFlow's runtime in and take ten seconds. None where
-    it is not installed."""
-    found = importlib.util.find_spec('tensorflow')
-    if found is None or not found.origin:
-        return None
-    path = os.path.join(os.path.dirname(found.origin), 'tsl', 'profiler',
-                        'protobuf', 'xplane_pb2.py')
-    if not os.path.exists(path):
-        return None
-    spec = importlib.util.spec_from_file_location('xplane_pb2', path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-def device_seconds_by_scope(path, lo, hi):
-    """Device seconds of the operations inside [lo, hi] (nanoseconds) by
+def device_seconds_by_scope(trace, lo, hi):
+    """Device seconds of the operations of ``trace`` (as
+    ``trace_reduce.load`` gives it) inside [lo, hi] (nanoseconds) by
     their ``mx.`` scope, ``other`` where an operation has none; mean over
-    the devices; None without a schema to read the file by. The scope is
-    a string stat of the operation's event metadata, which ProfileData
-    does not yield. An executable read from a compilation cache that an
+    the devices; None where the scopes could not be read (no schema
+    installed). An executable read from a compilation cache that an
     older tree filled carries that tree's names: every operation then
     reads ``other``."""
-    schema = _schema()
-    if schema is None:
+    if not trace['scopes_read']:
         return None
-    space = schema.XSpace()
-    with open(path, 'rb') as f:
-        space.ParseFromString(f.read())
     per_device = []
-    for plane in space.planes:
-        if not trace_reduce.DEVICE_PLANE.match(plane.name):
-            continue
-        scopes = {}
-        for key, metadata in plane.event_metadata.items():
-            for stat in metadata.stats:
-                found = scope_of(stat.str_value)
-                if found:
-                    scopes[key] = found
+    for dev in trace['devices'].values():
         seconds = {}
-        for line in plane.lines:
-            if line.name != trace_reduce.OP_LINE:
-                continue
-            for ev in line.events:
-                s = line.timestamp_ns + ev.offset_ps * 1e-3
-                e = s + ev.duration_ps * 1e-3
-                for cs, ce in clipped([(s, e)], lo, hi):
-                    key = scopes.get(ev.metadata_id, 'other')
-                    seconds[key] = seconds.get(key, 0.0) + (ce - cs) * 1e-9
+        for _, s, e, _, scope in dev['ops']:
+            for cs, ce in clipped([(s, e)], lo, hi):
+                key = scope or 'other'
+                seconds[key] = seconds.get(key, 0.0) + (ce - cs) * 1e-9
         per_device.append(seconds)
     return {k: sum(d.get(k, 0.0) for d in per_device) / len(per_device)
             for d in per_device for k in d}
@@ -306,14 +196,20 @@ def device_seconds_by_scope(path, lo, hi):
 
 @functools.lru_cache(maxsize=1)
 def _analysed(path, _mtime_ns):
-    return analyse(load(path))
+    return analyse(of_loaded(trace_reduce.load_once(path)))
 
 
-def of_run(trace_dir=TRACE_DIR):
-    """The analysis of the profile the runner has just written, made once
-    for all the readers of a run."""
+def of_dir(trace_dir):
+    """The analysis of the profile under ``trace_dir``, made once."""
     path = profile_under(trace_dir)
     return _analysed(path, os.stat(path).st_mtime_ns)
+
+
+def of_run(run):
+    """The analysis of the profile the runner has just written (it says
+    where in ``run['trace_dir']``), made once for all the readers of a
+    run."""
+    return of_dir(run['trace_dir'])
 
 
 # ----------------------------------------------------- what the readers take
@@ -386,9 +282,9 @@ def main(argv):
     if len(argv) != 1:
         print('usage: program_trace.py <trace_dir>', file=sys.stderr)
         return 2
-    got = of_run(argv[0])
+    got = of_dir(argv[0])
     print('\n'.join(report(got)))
-    by_scope = device_seconds_by_scope(profile_under(argv[0]),
+    by_scope = device_seconds_by_scope(trace_reduce.load_dir(argv[0]),
                                        *got['window_ns'])
     if by_scope is None:
         print('\nno xplane_pb2 installed: device seconds by mx. scope not '

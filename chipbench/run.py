@@ -11,8 +11,9 @@ with another number of chips than the cell states, it refuses: exit code
 Nothing here names a cell, a family or a metric. ``BENCHMARK.json`` names
 the cell and the metrics; the cell's file (``workloads/<cell>.json``)
 names its configuration (``configs/<config>.json``), that names its
-family (``families/<family>.py``), and every metric is read by the file
-of its name under ``end_to_end/`` or ``layer_metrics/``.
+family (``families/<family>.py``; ``families/__init__.py`` says what a
+family owns), and every metric is read by the file of its name under
+``end_to_end/`` or ``layer_metrics/``.
 """
 
 import time
@@ -145,10 +146,13 @@ def window(job, seconds, first_batch, trace_dir=None):
     stop is not the window's."""
     import jax
     import numpy as np
-    # what each batch of the pool is worth: real tokens, required FLOPs
-    worth = [(job.tokens(b), job.step_flops(b)) for b in job.pool]
+    # what each batch of the pool is worth: real tokens, required FLOPs,
+    # and those of the step's named parts that a kernel's roofline needs
+    worth = [(job.tokens(b), job.step_flops(b), job.part_flops(b))
+             for b in job.pool]
     pending = collections.deque()
     stamps, tokens, flops = [], 0, 0
+    part_flops = collections.Counter()
     attempted = failed = 0
     paused = 0.0
     traced = None
@@ -180,7 +184,8 @@ def window(job, seconds, first_batch, trace_dir=None):
             whole.__exit__(None, None, None)
             t_stop = time.perf_counter()
             jax.profiler.stop_trace()
-            traced = {'steps': attempted, 'seconds': t_stop - t0}
+            traced = {'steps': attempted, 'seconds': t_stop - t0,
+                      'part_flops': dict(part_flops)}
             paused += time.perf_counter() - t_stop
             continue
         if elapsed >= seconds:
@@ -190,6 +195,7 @@ def window(job, seconds, first_batch, trace_dir=None):
             pending.append(one_step(job, job.pool[k]))
             tokens += worth[k][0]
             flops += worth[k][1]
+            part_flops.update(worth[k][2])
         except Exception as e:           # a step that raises has failed;
             failed += 1                  # the run goes on and reports it
             print(f'step {attempted} raised {type(e).__name__}: {e}',
@@ -203,7 +209,8 @@ def window(job, seconds, first_batch, trace_dir=None):
     if trace_dir and traced is None:
         whole.__exit__(None, None, None)
         jax.profiler.stop_trace()
-        traced = {'steps': attempted, 'seconds': t_end - t0}
+        traced = {'steps': attempted, 'seconds': t_end - t0,
+                  'part_flops': dict(part_flops)}
     return {'seconds': t_end - t0 - paused, 'stamps': stamps,
             'tokens': tokens, 'flops': flops, 'attempted': attempted,
             'failed': failed, 'traced': traced}
@@ -230,11 +237,11 @@ def run_cell(cell, cfg, metrics, seed, seconds, trace, ctx, peaks):
     ``{'end_to_end': [...], 'per_layer': [...]}``: the entries of
     BENCHMARK.json that this cell reports. Returns the result object."""
     import jax
-    from chipbench import check, trace_reduce
+    from chipbench import check, families, trace_reduce
 
     marks = {'start': time.perf_counter() - T_PROCESS}
     compiles = CompileLog()
-    family = importlib.import_module(f'chipbench.families.{cfg["family"]}')
+    family = families.load(cfg['family'])
     job = family.Job(cfg, cell, seed, ctx)
     marks['built'] = time.perf_counter() - T_PROCESS
 
@@ -257,6 +264,8 @@ def run_cell(cell, cfg, metrics, seed, seconds, trace, ctx, peaks):
         'update_program': family.UPDATE_PROGRAM,
         'compile': {'count': compiles.count, 'seconds': compiles.seconds,
                     'cache_hits': compiles.cache_hits},
+        # the readers find the profile there; it is parsed once
+        'trace_dir': trace_dir,
         'trace': trace_reduce.reduce_dir(trace_dir, SPAN) if trace else None,
     }
 
@@ -272,10 +281,12 @@ def run_cell(cell, cfg, metrics, seed, seconds, trace, ctx, peaks):
         and not after['fused_fallback']
 
     group = 'layer_metrics' if trace else 'end_to_end'
-    out = {}
+    out, nothing_to_read = {}, []
     for m in metrics['per_layer' if trace else 'end_to_end']:
         value = reader(group, m['name'])(run)
-        if value is not None:
+        if value is None:
+            nothing_to_read.append(m['name'])
+        else:
             out[m['name']] = {'value': value, 'unit': m['unit']}
     dev = jax.devices()[0]
     device = {'platform': dev.platform, 'kind': dev.device_kind,
@@ -290,7 +301,8 @@ def run_cell(cell, cfg, metrics, seed, seconds, trace, ctx, peaks):
     # for whoever reads a run by hand; the driver ignores it
     where['left_out'] = len(where['left_out'])
     result['notes'] = {
-        **notes_on(win, run['trace']), 'compile': run['compile'],
+        **notes_on(win, run['trace']), 'nothing_to_read': nothing_to_read,
+        'compile': run['compile'],
         'reference_s': time.perf_counter() - t_ref, 'worst_leaf': where,
         'first_losses': got['losses'],
         'marks_to_window': {**marks, 'build_parts': job.timing}}
@@ -307,9 +319,14 @@ def notes_on(win, trace):
         'step_ms_p10_p50_p90_p99_max': [
             float(np.percentile(gaps_ms, q)) for q in (10, 50, 90, 99, 100)]
         if len(gaps_ms) else None,
+        # scoped false: no operation of the window carried a scope of
+        # the program, so a reader keyed on one finds nothing to read
         'traced': win['traced'] and {
             **win['traced'], 'longest_gaps': trace['longest_gaps'],
-            'host_span_s': trace['host_span_s']}}
+            'host_span_s': trace['host_span_s'],
+            'scoped': trace['scoped'],
+            'device0_scope_s': trace['devices'][0]['scope_s'],
+            'device0_kernel_s': trace['devices'][0]['kernel_s']}}
 
 
 def place_compile_cache():

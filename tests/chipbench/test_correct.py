@@ -1,6 +1,7 @@
 """``correct`` has to come out false when it should. Each case drives a
 whole run (all but the look for a chip) at a tiny size on the CPU, under
-the cell's own limits:
+the cell's own limits, for every cell of BENCHMARK.json and the toy
+family's (chipbench_tiny.py); the family is found from the cell:
 
 * the control: the reference in bfloat16, put in the program's place;
 * the timed path broken underneath: a step that leaves the parameters
@@ -17,12 +18,12 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import gluon
 from chipbench import check, run
-from chipbench.families import bert as family
 
-from chipbench_tiny import CELLS, PEAKS, load_bench, tiny
+from chipbench_tiny import (ALL_CELLS, PEAKS, load_bench, load_cell, tiny,
+                            tiny_job)
 
 BENCH = load_bench()
-MESH_CELLS = [c for c in CELLS if run.load_cell(c)[0].get('mesh')]
+MESH_CELLS = [c for c in ALL_CELLS if load_cell(c)[0].get('mesh')]
 
 
 def run_tiny(name, seed=9):
@@ -46,13 +47,13 @@ def keep_rows(monkeypatch, share):
                         forward)
 
 
-@pytest.mark.parametrize('name', CELLS)
+@pytest.mark.parametrize('name', ALL_CELLS)
 def test_sound_run_is_correct(name):
     r = run_tiny(name)
     assert r['correct'], r['check']
 
 
-@pytest.mark.parametrize('name', CELLS)
+@pytest.mark.parametrize('name', ALL_CELLS)
 def test_state_left_unchanged_is_not_correct(name, monkeypatch):
     real = gluon.Trainer.step
 
@@ -66,7 +67,7 @@ def test_state_left_unchanged_is_not_correct(name, monkeypatch):
     assert r['check']['change_gap']['value'] == pytest.approx(1.0)
 
 
-@pytest.mark.parametrize('name', CELLS)
+@pytest.mark.parametrize('name', ALL_CELLS)
 def test_half_of_the_batch_left_out_is_not_correct(name, monkeypatch):
     keep_rows(monkeypatch, 0.5)
     r = run_tiny(name)
@@ -75,16 +76,16 @@ def test_half_of_the_batch_left_out_is_not_correct(name, monkeypatch):
 
 @pytest.mark.parametrize('name', MESH_CELLS)
 def test_exchange_between_chips_left_out_is_not_correct(name, monkeypatch):
-    chips = run.load_cell(name)[0]['chips']
+    chips = load_cell(name)[0]['chips']
     keep_rows(monkeypatch, 1.0 / chips)
     r = run_tiny(name)
     assert not r['correct'], r['check']
 
 
-@pytest.mark.parametrize('name', CELLS)
+@pytest.mark.parametrize('name', ALL_CELLS)
 def test_bfloat16_control_is_not_correct(name):
-    cell, cfg = tiny(name)
-    job = family.Job(cfg, cell, 4, mx.cpu(0))
+    cell, _ = tiny(name)
+    job = tiny_job(name, 4, mx.cpu(0))
     pool = job.pool[:check.STEPS]
     want = job.follow_reference(pool)
     control = job.follow_reference(pool, dtype='bfloat16')
@@ -94,6 +95,24 @@ def test_bfloat16_control_is_not_correct(name):
     # and the reference against itself is exact
     assert check.compare(want, want)[0] == {
         'loss_gap': 0.0, 'grad_gap': 0.0, 'change_gap': 0.0}
+
+
+@pytest.mark.parametrize('name', ALL_CELLS)
+def test_every_fault_keeps_a_row_of_the_cells_batch(name):
+    for cell in (load_cell(name)[0], tiny(name)[0]):
+        kept = check.fault_rows(cell)
+        assert set(kept) == {'half_batch'} | (
+            {'no_exchange'} if cell['chips'] > 1 else set())
+        assert all(1 <= rows < cell['batch'] for rows in kept.values())
+
+
+@pytest.mark.parametrize('batch, chips, fault', [
+    (1, 1, 'half_batch'), (2, 4, 'no_exchange')])
+def test_a_batch_that_leaves_a_fault_no_row_is_refused(batch, chips, fault):
+    """calibrate.py compares against the rows a fault keeps; none is a
+    NaN and no reading."""
+    with pytest.raises(ValueError, match=f'the fault {fault} no row'):
+        check.fault_rows({'batch': batch, 'chips': chips})
 
 
 def test_a_number_that_is_not_finite_is_not_correct():

@@ -1,20 +1,19 @@
-"""chipbench/flops/bert.py against closed forms, and
-chipbench/reference/bert.py against the zoo's model at a tiny size on
-the CPU: logits, both losses, gradients, one Adam step."""
+"""chipbench/flops/bert.py against closed forms (BERT's own, by name),
+and every family's reference against its program at a tiny size on the
+CPU: outputs, loss, gradients, one Adam step, the reference in blocks of
+rows. The agreement tests find the family from the cell and name none."""
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import autograd
 from chipbench import check, run
-from chipbench.families import bert as family
 from chipbench.flops import bert as flops
 from chipbench.reference import bert as ref
 
-from chipbench_tiny import CELLS, tiny
+from chipbench_tiny import (ALL_CELLS, CELLS, family_of, load_cell, tiny,
+                            tiny_job)
 
 BASE = run.load_json(run.HERE, 'configs', 'bert_base.json')
 LARGE = run.load_json(run.HERE, 'configs', 'bert_large.json')
@@ -71,58 +70,72 @@ def test_large_seed_makes_a_key():
 
 
 def _tiny_cfg_job():
-    cell, cfg = tiny(CELLS[0])
+    cell, cfg = tiny(next(c for c in CELLS
+                          if load_cell(c)[1]['family'] == 'bert'))
     return cfg, cell['job']
 
 
+def test_attention_is_its_part_of_the_step():
+    """Scores and context, 2 n^2 U each, forward and the two gradients of
+    each, in every layer; what a row's padding would cost is not in it."""
+    cfg = dict(hidden_size=4, intermediate_size=8, num_hidden_layers=1,
+               vocab_size=10)
+    assert flops.attention_flops(cfg, [3]) == 3 * (72 + 72)
+    assert flops.attention_flops(cfg, [3, 2]) == 3 * (72 + 72 + 32 + 32)
+    # the issue's arithmetic for the pre-train cell: 232 GFLOP a step
+    assert flops.attention_flops(BASE, [512] * 8) == \
+        12 * 8 * (4 * 512 ** 2 * 768) * 3
+    # a part of the step's count: what is left of a layer are its four
+    # projections and the two FFN products, 2 n U (4 U + 2 H) forward
+    rest = dict(BASE, num_hidden_layers=0)
+    layers = flops.step_flops(BASE, MLM, [512] * 8, 76) \
+        - flops.step_flops(rest, MLM, [512] * 8, 76)
+    assert layers - flops.attention_flops(BASE, [512] * 8) == \
+        3 * 12 * 8 * 2 * 512 * 768 * (4 * 768 + 2 * 3072)
+
+
+def _families_first(cells, key):
+    """The first of ``cells`` of each family whose file has ``key``."""
+    first = {}
+    for name in cells:
+        cell, cfg = load_cell(name)
+        if cell.get(key):
+            first.setdefault(cfg['family'], name)
+    return sorted(first.values())
+
+
 @pytest.fixture(scope='module', params=[
-    c for c in CELLS if not run.load_cell(c)[0].get('mesh')])
-def pair(request):
-    """A tiny job of the program and the reference's own weights."""
-    cell, cfg = tiny(request.param)
-    job = family.Job(cfg, cell, 7, mx.cpu(0))
-    return job, ref.init_params(cfg, cell['job'], 7)
+    c for c in ALL_CELLS if not load_cell(c)[0].get('mesh')])
+def job(request):
+    """A tiny job of the program; its family's reference makes its own
+    weights from the same seed."""
+    return tiny_job(request.param, 7, mx.cpu(0))
 
 
-def _ref_batch(job, i=0):
-    return {k: jnp.asarray(v)
-            for k, v in job.reference_batches(job.pool[i:i + 1])[0].items()}
-
-
-def test_logits_agree(pair):
-    job, p = pair
+def test_logits_agree(job):
     out = job.forward(job.upload(job.pool[0]))
-    b = _ref_batch(job)
-    with jax.default_matmul_precision('highest'):
-        seq = ref.encode(p, job.cfg, b['tokens'], b['types'],
-                         b.get('valid_length'))
-        pooled = jnp.tanh(ref.linear(seq[:, 0], p['pooler_w'],
-                                     p['pooler_b']))
-        if job.kind == 'classify':
-            want = [ref.linear(pooled, p['head_w'], p['head_b'])]
-            got = [out]
-        else:
-            want = [ref.linear(pooled, p['nsp_w'], p['nsp_b'])]
-            got = [out[1]]
+    got = list(out) if isinstance(out, (tuple, list)) else [out]
+    want = job.reference_forward(job.pool[0])
+    assert len(got) == len(want) and any(w is not None for w in want)
     for g, w in zip(got, want):
-        np.testing.assert_allclose(g.asnumpy(), np.asarray(w), atol=2e-5)
+        if w is not None:
+            np.testing.assert_allclose(g.asnumpy(), np.asarray(w),
+                                       atol=2e-5)
 
 
-def test_loss_gradients_and_one_adam_step_agree(pair):
-    job, p = pair
-    first = {k: jnp.array(v) for k, v in job.param_raws().items()}
+def test_loss_gradients_and_one_adam_step_agree(job):
+    first = {k: np.array(v) for k, v in job.param_raws().items()}
     dev = job.upload(job.pool[0])
     with autograd.record():
         loss = job.loss(job.forward(dev), dev)
     loss.backward()
-    b = _ref_batch(job)
-    with jax.default_matmul_precision('highest'):
-        want_loss, want_grad = jax.value_and_grad(ref.loss_fn)(
-            p, job.cfg, job.cell['job'], b)
+    want_loss, want_grad = job.reference_loss_and_gradients(job.pool[0])
     assert float(loss.asnumpy()) == pytest.approx(float(want_loss),
                                                   rel=1e-5)
-    want_grad = family.by_program_name(want_grad)
     params = job.net.collect_params()
+    held = {n for n, p in params.items() if p.grad_req == 'null'}
+    # the reference takes a gradient for every leaf the program does
+    assert set(want_grad) == set(params) - held
     for name, w in want_grad.items():
         np.testing.assert_allclose(params[name].grad().asnumpy(),
                                    np.asarray(w), atol=1e-5, err_msg=name)
@@ -131,18 +144,31 @@ def test_loss_gradients_and_one_adam_step_agree(pair):
     follow = job.follow_reference(job.pool[:1])
     moved = check.norms_of(job.param_raws(), minus=first,
                            parts=job.leaf_parts())
-    assert set(moved) == set(follow['change_norms'])
+    # a leaf the optimizer holds no slot for: in no gradient the family
+    # reads, in none of the reference's norms, and where it was
+    still = set(moved) - set(follow['change_norms'])
+    read = set(check.norms_of(*job.first_gradient_raws()[:1],
+                              parts=job.leaf_parts()))
+    assert read == set(follow['grad_norms']) == set(follow['change_norms'])
+    assert {n.split('[')[0] for n in still} == held
+    assert all(moved[n] == 0.0 for n in still)
     for name, w in follow['change_norms'].items():
         # abs: the key's bias moves by round-off alone
         assert moved[name] == pytest.approx(w, rel=1e-3, abs=1e-6), name
 
 
-def test_the_reference_in_blocks_of_rows_is_the_reference():
-    cell, cfg = tiny(CELLS[-1])
-    job = family.Job(cfg, cell, 3, mx.cpu(0))
-    pool = job.reference_batches(job.pool[:2])
-    whole = ref.follow(cfg, cell['job'], 3, pool, 1e-4, block_rows=8)
-    blocks = ref.follow(cfg, cell['job'], 3, pool, 1e-4, block_rows=2)
+@pytest.mark.parametrize('name', _families_first(ALL_CELLS,
+                                                 'reference_block_rows'))
+def test_the_reference_in_blocks_of_rows_is_the_reference(name):
+    """Once a family, on its first cell that states the blocks; a job
+    reads them from its cell when it is asked to follow."""
+    cell, cfg = tiny(name)
+    job = family_of(name).Job(cfg, cell, 3, mx.cpu(0))
+    pool = job.pool[:2]
+    cell['reference_block_rows'] = cell['batch']
+    whole = job.follow_reference(pool)
+    cell['reference_block_rows'] = cell['batch'] // 4
+    blocks = job.follow_reference(pool)
     assert blocks['losses'] == pytest.approx(whole['losses'], rel=1e-6)
     for k, v in whole['change_norms'].items():
         np.testing.assert_allclose(blocks['change_norms'][k], v, rtol=1e-3,

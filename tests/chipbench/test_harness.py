@@ -1,7 +1,8 @@
 """The harness end to end on the CPU at tiny sizes: every cell of
-BENCHMARK.json yields every metric it names, the command refuses without
-a TPU, and every name resolves to a file. Device numbers read here are
-thrown away: nothing from a CPU run is a measurement."""
+BENCHMARK.json, and the toy family's, yields every metric it names, the
+command refuses without a TPU, every name resolves to a file, and a
+family that lacks a piece of its contract is told which. Device numbers
+read here are thrown away: nothing from a CPU run is a measurement."""
 
 import importlib
 import json
@@ -11,10 +12,10 @@ import re
 import pytest
 
 import mxnet_tpu as mx
-from chipbench import run, trace_reduce
+from chipbench import families, run, trace_reduce
 
-from chipbench_tiny import (CELLS, PEAKS, ROOT, load_bench, small_trace,
-                            tiny)
+from chipbench_tiny import (ALL_CELLS, CELLS, PEAKS, ROOT, family_of,
+                            load_bench, load_cell, small_trace, tiny)
 
 NAME = re.compile(r'^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$')
 UNIT = re.compile(r'^[A-Za-z0-9_/%.\-]{1,16}$')
@@ -22,14 +23,19 @@ BENCH = load_bench()
 
 
 @pytest.fixture
-def hand_trace(monkeypatch):
-    """A CPU trace has no device plane; the reduction is given the
-    hand-built one, the profiler still runs round the window."""
+def hand_device(monkeypatch):
+    """A CPU trace has no device plane and the CPU no memory_stats(): the
+    reduction is given the hand-built trace (its operations carry kernels
+    and scopes) and the memory reading a hand-built one; the profiler
+    still runs round the window and the readers of the program's spans
+    read what it wrote."""
     monkeypatch.setattr(trace_reduce, 'reduce_dir',
                         lambda d, prefix: trace_reduce.reduce(small_trace()))
+    monkeypatch.setattr(run, 'memory', lambda devices: {
+        'peak_bytes': 3 * 2 ** 30, 'limit_bytes': 16 * 2 ** 30})
 
 
-@pytest.mark.parametrize('name', CELLS)
+@pytest.mark.parametrize('name', ALL_CELLS)
 def test_cell_yields_its_end_to_end_metrics(name):
     cell, cfg = tiny(name)
     want = run.entries_for(BENCH, name)
@@ -46,19 +52,59 @@ def test_cell_yields_its_end_to_end_metrics(name):
     json.dumps(r)
 
 
-@pytest.mark.parametrize('name', CELLS)
-def test_cell_yields_its_per_layer_metrics(name, hand_trace):
+@pytest.mark.parametrize('name', ALL_CELLS)
+def test_cell_yields_its_per_layer_metrics(name, hand_device):
     cell, cfg = tiny(name)
     want = run.entries_for(BENCH, name)
     r = run.run_cell(cell, cfg, want, 5, 0.5, True, mx.cpu(0), PEAKS)
-    # no memory_stats() on the CPU: that reader finds nothing and is
-    # left out, every other metric of the cell is there
-    names = {m['name'] for m in want['per_layer']} - {'peak_hbm_share'}
-    assert set(r['metrics']) == names
+    # every metric of the cell, none excused by name
+    assert set(r['metrics']) == {m['name'] for m in want['per_layer']}
+    assert r['notes']['nothing_to_read'] == []
+    assert all(v['value'] >= 0 for v in r['metrics'].values())
+    shares = [m['name'] for m in want['per_layer'] if m['unit'] == '%']
+    assert all(r['metrics'][n]['value'] > 0 for n in shares)
     assert r['device']['busy_s'] > 0 and r['device']['window_s'] > 0
     assert len(r['breakdown']['device_ops']) <= 10
     assert len(r['breakdown']['idle_gaps']) <= 10
     assert r['metrics']['compiles_in_window']['value'] == 0
+
+
+def _traced(trace):
+    """What a reader of the device seam takes of a run."""
+    return {'trace': trace_reduce.reduce(trace), 'chips': 1, 'peaks': PEAKS,
+            'window': {'traced': {'part_flops': {'attention': 1e6}}}}
+
+
+def _without_scopes(trace, keep=lambda op: False):
+    for dev in trace['devices'].values():
+        dev['ops'] = [op if keep(op) else (*op[:4], None)
+                      for op in dev['ops']]
+    return trace
+
+
+def test_attention_is_read_by_scope_and_by_kernel_where_names_are_stale():
+    device_ms = run.reader('layer_metrics', 'attention_device_ms')
+    roofline = run.reader('layer_metrics', 'attention_roofline')
+    # by scope: device 0's 5000 ns under mx.attention over two steps, and
+    # 1e6 FLOP at 1e12 FLOP/s are 1000 ns of them
+    scoped = _traced(small_trace())
+    assert device_ms(scoped) == pytest.approx(2.5e-3)
+    assert roofline(scoped) == pytest.approx(20.0)
+    # no operation carries a scope: the flash kernels by name, which is
+    # not all of attention, so no roofline
+    stale = _traced(_without_scopes(small_trace()))
+    assert device_ms(stale) == pytest.approx(1.5e-3)
+    assert roofline(stale) is None
+    # scopes are there and attention's is not: nothing, never 0.0
+    other = _traced(_without_scopes(
+        small_trace(), keep=lambda op: op[4] != 'mx.attention'))
+    assert other['trace']['scoped']
+    assert device_ms(other) is None and roofline(other) is None
+    # neither a scope nor a kernel of that name
+    bare = small_trace()
+    for dev in bare['devices'].values():
+        dev['ops'] = [(*op[:3], None, None) for op in dev['ops']]
+    assert device_ms(_traced(bare)) is None
 
 
 def test_without_a_tpu_the_command_refuses(capsys):
@@ -148,5 +194,22 @@ def test_per_layer_metrics_move_an_end_to_end_metric_of_their_cells():
 def test_run_py_names_no_cell_family_or_metric():
     with open(os.path.join(run.HERE, 'run.py')) as f:
         text = f.read()
-    names = [e['name'] for _, e in _named()] + ['bert']
+    # the families' names as the configurations' files give them
+    named = {load_cell(c)[1]['family'] for c in ALL_CELLS}
+    assert len(named) > 1
+    names = [e['name'] for _, e in _named()] + sorted(named)
     assert [n for n in names if n in text] == []
+
+
+@pytest.mark.parametrize('name', sorted(
+    {load_cell(c)[1]['family'] for c in ALL_CELLS}))
+@pytest.mark.parametrize('piece', families.MODULE + tuple(
+    f'Job.{k}' for k in families.JOB))
+def test_a_family_without_a_piece_of_the_contract_is_told_which(
+        name, piece, monkeypatch):
+    family = families.load(name)
+    owner, _, attr = piece.rpartition('.')
+    monkeypatch.delattr(family.Job if owner else family, attr)
+    with pytest.raises(NotImplementedError, match=(
+            rf'{name}\W+ lacks {re.escape(piece)}: .*families/__init__')):
+        families.load(name)

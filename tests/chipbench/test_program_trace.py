@@ -9,12 +9,14 @@ import os
 import pytest
 
 from chipbench import program_trace as pt
+from chipbench import trace_reduce as tr
 
 NS = 1e-9
 
 
 def small():
-    """program_trace_small.json as ``program_trace.load`` would give it."""
+    """program_trace_small.json as ``program_trace.of_loaded`` gives a
+    loaded profile."""
     with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            'program_trace_small.json')) as f:
         raw = json.load(f)
@@ -145,24 +147,24 @@ def test_no_window_is_an_error():
     ('jit(pure_fn)/jit(main)/dot_general', None),
 ])
 def test_scope_of_an_operation(text, scope):
-    assert pt.scope_of(text) == scope
+    assert tr.scope_of(text) == scope
 
 
 def test_device_seconds_by_scope_from_the_events_metadata(tmp_path):
     """A device plane as a v5e profile holds it (my chip run, PR 27): the
     op_name is a string stat (``tf_op``) of the operation's event
     metadata."""
-    schema = pt._schema()
-    space = schema.XSpace()
+    space = tr.schema().XSpace()
 
     def plane(dev, ops):
         pl = space.planes.add(id=dev, name=f'/device:TPU:{dev}')
-        for key, op_name in ((1, 'jit(f)/jvp(mx.attention)/dot_general'),
-                             (2, 'jit(f)/mx.layer_norm/mul:'),
-                             (3, 'jit(f)/jit(main)/add')):
+        for key, op_name, opcode in (
+                (1, 'jit(f)/jvp(mx.attention)/dot_general', 'custom-call'),
+                (2, 'jit(f)/mx.layer_norm/mul:', 'fusion'),
+                (3, 'jit(f)/jit(main)/add', 'fusion')):
             meta = pl.event_metadata[key]
             meta.id = key
-            meta.name = f'%fusion.{key} = f32[8]{{0}} fusion()'
+            meta.name = f'%k.{key} = f32[8]{{0}} {opcode}()'
             meta.stats.add(metadata_id=9, str_value='file.py:7')
             meta.stats.add(metadata_id=26, str_value=op_name)
         steps = pl.lines.add(name='Steps', timestamp_ns=5)
@@ -178,8 +180,16 @@ def test_device_seconds_by_scope_from_the_events_metadata(tmp_path):
     space.planes.add(name='/host:CPU')
     path = tmp_path / 'x.xplane.pb'
     path.write_bytes(space.SerializeToString())
+    loaded = tr.load(str(path))
+    assert loaded['scopes_read']
+    # every operation with its kernel (a custom-call's name) and scope
+    assert loaded['devices'][0]['ops'] == [
+        ('k.1 custom-call f32[8]', 1005, 1305, 'k', 'mx.attention'),
+        ('k.2 fusion f32[8]', 1305, 1655, None, 'mx.layer_norm'),
+        ('k.3 fusion f32[8]', 2005, 2505, None, None),
+        ('k.1 custom-call f32[8]', 9905, 10305, 'k', 'mx.attention')]
     # the line starts at 5 ns; the last attention op is cut by the window
-    got = pt.device_seconds_by_scope(str(path), 0, 10005)
+    got = pt.device_seconds_by_scope(loaded, 0, 10005)
     assert got == pytest.approx({
         'mx.attention': (300 + 100) / 2 * NS,
         'mx.layer_norm': 350 / 2 * NS,
@@ -200,13 +210,13 @@ def test_the_schema_reads_what_the_profiler_writes(tmp_path):
     finally:
         jax.profiler.stop_trace()
     path = pt.profile_under(str(tmp_path))
-    space = pt._schema().XSpace()
+    space = tr.schema().XSpace()
     with open(path, 'rb') as f:
         space.ParseFromString(f.read())
     data = jax.profiler.ProfileData.from_file(path)
     assert [p.name for p in space.planes] == [p.name for p in data.planes]
     # no device plane on the CPU: nothing to sum, nothing raised
-    assert pt.device_seconds_by_scope(path, 0, 2 ** 62) == {}
+    assert pt.device_seconds_by_scope(tr.load(path), 0, 2 ** 62) == {}
 
 
 def test_without_a_schema_the_print_out_says_so(tmp_path, monkeypatch,
@@ -218,11 +228,45 @@ def test_without_a_schema_the_print_out_says_so(tmp_path, monkeypatch,
             pass
     finally:
         jax.profiler.stop_trace()
-    monkeypatch.setattr(pt, '_schema', lambda: None)
-    assert pt.device_seconds_by_scope(
-        pt.profile_under(str(tmp_path)), 0, 1) is None
+    monkeypatch.setattr(tr, 'schema', lambda: None)
+    loaded = tr.load(pt.profile_under(str(tmp_path)))
+    assert not loaded['scopes_read']
+    assert pt.device_seconds_by_scope(loaded, 0, 1) is None
     assert pt.main([str(tmp_path)]) == 0
     assert 'no xplane_pb2' in capsys.readouterr().out
+
+
+def test_a_runs_profile_is_parsed_once(tmp_path, monkeypatch):
+    """The reduction and every reader of the program's spans share one
+    parse of the run's .xplane.pb, by each of the two parsers."""
+    import jax
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation(pt.WINDOW):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    parses = {'ProfileData': 0, 'schema': 0}
+    real_from_file = jax.profiler.ProfileData.from_file
+    real_op_scopes = tr.op_scopes
+
+    def from_file(path):
+        parses['ProfileData'] += 1
+        return real_from_file(path)
+
+    def op_scopes(path, schema):
+        parses['schema'] += 1
+        return real_op_scopes(path, schema)
+
+    monkeypatch.setattr(jax.profiler.ProfileData, 'from_file', from_file)
+    monkeypatch.setattr(tr, 'op_scopes', op_scopes)
+    run = {'trace_dir': str(tmp_path)}
+    with pytest.raises(ValueError, match='no device plane'):
+        tr.reduce_dir(run['trace_dir'])          # what run.py calls first
+    for _ in range(3):                           # then reader after reader
+        pt.of_run(run)
+    assert pt.main([str(tmp_path)]) == 0         # and the print-out
+    assert parses == {'ProfileData': 1, 'schema': 1}
 
 
 def test_the_print_out_names_every_span(got):
@@ -253,7 +297,8 @@ def test_a_real_cpu_profile_of_the_programs_spans(tmp_path):
                             launch.set(n_out=5)
     finally:
         jax.profiler.stop_trace()
-    got = pt.of_run(str(tmp_path))
+    got = pt.of_run({'trace_dir': str(tmp_path)})
+    assert got is pt.of_dir(str(tmp_path))      # analysed once
     assert got['steps'] == 3
     assert got['spans']['mx.trainer.step']['count'] == 3
     assert got['spans']['mx.trainer.step']['attrs'] == {'n_params': 21}

@@ -1,6 +1,11 @@
 """chipbench/trace_reduce.py on a hand-built trace (trace_small.json, in
-nanoseconds): busy union and idle share, device time by program,
-collective time and its exposed part, gaps by what the host was doing."""
+nanoseconds): busy union and idle share, device time by program, by
+kernel and by scope, collective time and its exposed part, gaps by what
+the host was doing; and every figure PR 28's reduce gave, unchanged."""
+
+import copy
+import json
+import os
 
 import pytest
 
@@ -92,6 +97,117 @@ def test_gaps_go_to_what_the_host_was_doing(reduced):
         reduced['window_s'] - reduced['busy_s'])
     most = reduced["breakdown"]["idle_gaps"][0]
     assert most[0] == "wait" and most[1] == pytest.approx(by["wait"])
+
+
+def test_gaps_go_to_the_innermost_program_span(reduced):
+    by = reduced['idle_by_span_s']
+    # device 0's (100, 500) began inside mx.graph.launch inside
+    # mx.graph.call inside forward; no span of the program held any
+    # other gap, the one on the line 'worker' (4400, 4600) is not the
+    # host's, and their phases keep them
+    assert by == pytest.approx({
+        'mx.graph.launch': 400e-9 / 2, 'feed': 1000e-9 / 2,
+        'wait': (1000 + 500 + 3000 + 2000) * 1e-9 / 2})
+    assert sum(by.values()) == pytest.approx(
+        sum(reduced['idle_by_phase_s'].values()))
+    assert dict(reduced['breakdown']['idle_gaps']) == pytest.approx(by)
+    assert [name for _, name in reduced['longest_gaps']] == \
+        ['wait', 'wait', 'wait', 'feed', 'wait']
+
+
+def test_a_trace_without_the_programs_spans_keeps_the_phases():
+    trace = small_trace()
+    trace['host'] = [sp for sp in trace['host']
+                     if not sp[0].startswith(tr.PROGRAM)]
+    got = tr.reduce(trace)
+    assert got['idle_by_span_s'] == pytest.approx(got['idle_by_phase_s'])
+    del trace['host']                   # as PR 28's loader gave it
+    assert tr.reduce(trace)['idle_by_span_s'] == pytest.approx(
+        got['idle_by_phase_s'])
+
+
+def test_device_time_by_scope_and_by_kernel(reduced):
+    d0, d1 = reduced['devices']
+    assert reduced['scoped'] is True
+    # device 0, a step: fusion.1 (1000) and the backward's flash kernel
+    # (1500) under mx.attention, fusion.2 (700) under mx.layer_norm, the
+    # Adam kernel (300) under mx.optimizer_step; the op that began before
+    # the window carries no scope, the collectives none
+    assert d0['scope_s'] == pytest.approx({
+        'mx.attention': 2 * 2500e-9, 'mx.layer_norm': 2 * 700e-9,
+        'mx.optimizer_step': 2 * 300e-9})
+    assert d0['kernel_s'] == pytest.approx({
+        'mx_flash_attention_bwd': 2 * 1500e-9, 'mx_adam_step': 2 * 300e-9})
+    assert d1['scope_s'] == pytest.approx({'mx.attention': 4000e-9})
+    assert d1['kernel_s'] == {}
+
+
+def test_an_operation_cut_by_the_window_counts_its_part_inside():
+    trace = small_trace()
+    ops = trace['devices'][0]['ops']
+    trace['devices'][0]['ops'] = [
+        (name, s, e, kernel, 'mx.layer_norm' if name == 'fusion.0' else scope)
+        for name, s, e, kernel, scope in ops]
+    got = tr.reduce(trace)['devices'][0]['scope_s']
+    assert got['mx.layer_norm'] == pytest.approx((100 + 2 * 700) * 1e-9)
+
+
+def test_names_that_are_stale_read_as_not_scoped():
+    """An executable from a compile cache that an older tree filled: no
+    operation carries a scope, and a reader can tell that from a window
+    in which the scoped work did not run."""
+    trace = small_trace()
+    for dev in trace['devices'].values():
+        dev['ops'] = [(*op[:4], None) for op in dev['ops']]
+    got = tr.reduce(trace)
+    assert got['scoped'] is False
+    assert all(d['scope_s'] == {} for d in got['devices'])
+    assert got['devices'][0]['kernel_s']['mx_adam_step'] == pytest.approx(
+        600e-9)
+
+
+@pytest.mark.parametrize('short, kernel', [
+    ('mx_adam_step.153 custom-call (f32[8], f32[8])', 'mx_adam_step'),
+    ('mx_flash_attention_fwd custom-call f32[8,128]',
+     'mx_flash_attention_fwd'),
+    ('fusion.12 fusion f32[8,128]', None),
+    ('custom-call.3 fusion f32[8]', None),     # a name is no opcode
+    ('all-gather.1', None),
+])
+def test_kernel_of_an_operation(short, kernel):
+    assert tr.kernel_of(short) == kernel
+
+
+def _same(old, new, path=''):
+    """Everything ``old`` holds, ``new`` holds with the same value."""
+    if isinstance(old, dict):
+        for k, v in old.items():
+            assert k in new, f'{path}/{k} is gone'
+            _same(v, new[k], f'{path}/{k}')
+    elif isinstance(old, list):
+        assert len(old) == len(new), path
+        for i, (a, b) in enumerate(zip(old, new)):
+            _same(a, b, f'{path}[{i}]')
+    elif isinstance(old, float):
+        assert new == pytest.approx(old, rel=1e-12), path
+    else:
+        assert new == old, path
+
+
+def test_every_figure_of_the_reduce_before_the_seam_is_unchanged(reduced):
+    """trace_small.reduced_pr28.json is what PR 28's reduce gave for the
+    same trace. What the seam changed on purpose: idle_gaps and the
+    names in longest_gaps go by the program's span where one held the
+    gap; their seconds, and idle_by_phase_s, are as they were."""
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           'trace_small.reduced_pr28.json')) as f:
+        old = json.load(f)['reduced']
+    new = copy.deepcopy(reduced)
+    assert dict(old['breakdown'].pop('idle_gaps')) == pytest.approx(
+        new['idle_by_phase_s'])
+    assert [sec for sec, _ in old.pop('longest_gaps')] == pytest.approx(
+        [sec for sec, _ in new['longest_gaps']])
+    _same(old, new)
 
 
 def test_breakdown_names_the_heaviest_ops(reduced):
