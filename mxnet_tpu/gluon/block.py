@@ -416,11 +416,39 @@ class _CachedGraph:
             # rule (mx.analysis) machine-checks the aliasing actually
             # happens.
             jit_kwargs['donate_argnums'] = tuple(donate)
+        held = self._aux_handed_back(pure_fn, in_nds) if 3 in donate \
+            else ()
         if self.remat:
             # recompute activations in backward instead of storing them
             # (reference backward mirroring, MXNET_BACKWARD_DO_MIRROR)
             pure_fn = jax.checkpoint(pure_fn)
-        jitted = jax.jit(pure_fn, **jit_kwargs)
+        if held:
+            # a grad_req='null' leaf the forward only reads (a router's
+            # correction bias, a frozen embedding) has no new value to
+            # land in its buffer: it goes in undonated, as a fifth
+            # argument, and is put back in its place inside the program
+            _, aux = self._params()
+            written = [i for i in range(len(aux)) if i not in held]
+
+            def pure5(rng_key, in_raws, main_raws, written_raws, held_raws):
+                at = dict(zip(written + list(held),
+                              written_raws + held_raws))
+                return pure_fn(rng_key, in_raws, main_raws,
+                               tuple(at[i] for i in range(len(aux))))
+
+            if 'in_shardings' in jit_kwargs:
+                sh = jit_kwargs['in_shardings']
+                jit_kwargs['in_shardings'] = sh[:3] + (
+                    tuple(sh[3][i] for i in written),
+                    tuple(sh[3][i] for i in held))
+            jitted5 = jax.jit(pure5, **jit_kwargs)
+
+            def jitted(rng_key, in_raws, main_raws, aux_raws):
+                return jitted5(rng_key, in_raws, main_raws,
+                               tuple(aux_raws[i] for i in written),
+                               tuple(aux_raws[i] for i in held))
+        else:
+            jitted = jax.jit(pure_fn, **jit_kwargs)
         if ctx is None:
             return jitted
         # rng key / inputs arrive as committed single-device arrays each
@@ -441,6 +469,30 @@ class _CachedGraph:
             return jitted(rng_key, in_raws, main_raws, aux_raws)
 
         return sharded_fn
+
+    def _aux_handed_back(self, pure_fn, in_nds):
+        """Indices of the aux leaves that this entry's forward hands
+        back as they came in (no ``record_aux_update`` on them), found
+        by one abstract trace of the forward: only a net that has aux
+        leaves and donates them pays it, once an entry."""
+        import jax
+
+        main, aux = self._params()
+        struct = lambda raw: jax.ShapeDtypeStruct(raw.shape, raw.dtype)
+        held = []
+
+        def probe(rng_key, in_raws, main_raws, aux_raws):
+            outs, aux_out = pure_fn(rng_key, in_raws, main_raws, aux_raws)
+            held.extend(i for i, (new, old) in enumerate(
+                zip(aux_out, aux_raws)) if new is old)
+            return outs
+
+        jax.eval_shape(
+            probe, struct(_rng._global()),
+            tuple(struct(x._data) for x in in_nds),
+            tuple(struct(p.data()._data) for p in main),
+            tuple(struct(p.data()._data) for p in aux))
+        return tuple(held)
 
     def _make_pure(self, shapes_key, train_mode, treedef, ctx=None,
                    aux_specs=None):

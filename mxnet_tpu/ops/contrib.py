@@ -166,32 +166,60 @@ def paged_attention_decode(q, k_pool, v_pool, pages, offset,
 @register('multi_head_attention', fused_kernel=True,
           cost=_attention_pallas_cost)
 def multi_head_attention(q, k, v, num_heads, mask=None, dropout_p=0.0,
-                         causal=False, key=None):
+                         causal=False, key=None, sm_scale=None):
     """Fused scaled-dot-product attention (batch, seq, embed) — the TPU-first
     replacement for the interleaved-matmul pipeline. Unmasked/causal cases
     take the Pallas flash path (ops/pallas/flash_attention.py); explicit
-    masks use jax.nn.dot_product_attention, which XLA fuses."""
+    masks use jax.nn.dot_product_attention, which XLA fuses.
+
+    ``v`` may have another head width than ``q`` and ``k``
+    (``v.shape[-1] / num_heads``; latent attention: 192 for the scores,
+    128 for the values): the narrower side is zero-padded to the wider,
+    which changes neither a score nor a kept output column, every branch
+    runs as it does for one width, and the output is (batch, seq,
+    num_heads x v's head width). ``sm_scale`` is the score scale;
+    default 1/sqrt(q's head width)."""
     # one scope round every branch: a device operation of a profile is
     # put down to attention whichever implementation ran
     with jax.named_scope('mx.attention'):
-        return _attention(q, k, v, num_heads, mask, dropout_p, causal, key)
+        return _attention(q, k, v, num_heads, mask, dropout_p, causal, key,
+                          sm_scale)
 
 
-def _attention(q, k, v, num_heads, mask, dropout_p, causal, key):
+def _attention(q, k, v, num_heads, mask, dropout_p, causal, key,
+               sm_scale=None):
     b, sq, e = q.shape
     hd = e // num_heads
+    vd = v.shape[-1] // num_heads
     qh = q.reshape(b, sq, num_heads, hd)
     kh = k.reshape(b, k.shape[1], num_heads, hd)
-    vh = v.reshape(b, v.shape[1], num_heads, hd)
+    vh = v.reshape(b, v.shape[1], num_heads, vd)
+    if vd != hd:
+        if sm_scale is None:
+            sm_scale = hd ** -0.5       # of the width before the padding
+        wide = max(hd, vd)
+        pad = lambda a: a if a.shape[-1] == wide else jnp.pad(
+            a, ((0, 0),) * 3 + ((0, wide - a.shape[-1]),))
+        qh, kh, vh = pad(qh), pad(kh), pad(vh)
+    out = _attention_heads(qh, kh, vh, mask, dropout_p, causal, key,
+                           sm_scale)
+    if vd != hd:
+        out = out[..., :vd]
+    return out.reshape(b, sq, num_heads * vd)
+
+
+def _attention_heads(qh, kh, vh, mask, dropout_p, causal, key, sm_scale):
+    """(B, T, H, d) x (B, S, H, d) x (B, S, H, d) -> (B, T, H, d)."""
+    sq, sk = qh.shape[1], kh.shape[1]
     if mask is None and dropout_p == 0.0:
         from .pallas.flash_attention import flash_attention as _fa
         out = _fa(qh.transpose(0, 2, 1, 3), kh.transpose(0, 2, 1, 3),
-                  vh.transpose(0, 2, 1, 3), causal=causal)
-        return out.transpose(0, 2, 1, 3).reshape(b, sq, e)
+                  vh.transpose(0, 2, 1, 3), sm_scale=sm_scale,
+                  causal=causal)
+        return out.transpose(0, 2, 1, 3)
     if causal:
         # explicit bottom-right-aligned causal mask so this branch agrees
         # with the flash path when T != S (decode with KV cache)
-        sk = k.shape[1]
         tri = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)[None, None]
         mask = tri if mask is None else jnp.logical_and(mask, tri)
     if dropout_p > 0.0:
@@ -199,7 +227,7 @@ def _attention(q, k, v, num_heads, mask, dropout_p, causal, key):
             raise ValueError(
                 'multi_head_attention with dropout_p > 0 needs key= (a '
                 'jax PRNG key); pass one or apply nn.Dropout outside')
-        hd_scale = hd ** -0.5
+        hd_scale = qh.shape[-1] ** -0.5 if sm_scale is None else sm_scale
         s = jnp.einsum('bqhd,bkhd->bhqk', qh.astype(jnp.float32),
                        kh.astype(jnp.float32)) * hd_scale
         if mask is not None:
@@ -207,11 +235,10 @@ def _attention(q, k, v, num_heads, mask, dropout_p, causal, key):
         p = jax.nn.softmax(s, axis=-1)
         keep = jax.random.bernoulli(key, 1.0 - dropout_p, p.shape)
         p = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
-        out = jnp.einsum('bhqk,bkhd->bqhd', p,
-                         vh.astype(jnp.float32)).astype(q.dtype)
-        return out.reshape(b, sq, e)
-    out = jax.nn.dot_product_attention(qh, kh, vh, mask=mask)
-    return out.reshape(b, sq, e)
+        return jnp.einsum('bhqk,bkhd->bqhd', p,
+                          vh.astype(jnp.float32)).astype(qh.dtype)
+    return jax.nn.dot_product_attention(qh, kh, vh, mask=mask,
+                                        scale=sm_scale)
 
 
 # ----------------------------------------------------------- detection utils

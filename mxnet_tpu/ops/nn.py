@@ -33,7 +33,10 @@ def fully_connected(data, weight, bias=None, num_hidden=None, no_bias=False,
     """
     if flatten and data.ndim > 2:
         data = data.reshape(data.shape[0], -1)
-    out = jnp.matmul(data, weight.T)
+    # contract over the weight's second axis where it lies: ``weight.T``
+    # would be a value of its own, and the backward pass would be handed
+    # a transposed copy of every weight as a residual of the forward
+    out = jnp.einsum('...i,oi->...o', data, weight)
     if bias is not None and not no_bias:
         out = out + bias
     return out

@@ -165,3 +165,86 @@ def test_sgd_mom_step_compiles(one_chip, shape):
         return opt_mod.sgd_mom_step(w, g, mom, lr, wd, momentum=0.9)
 
     assert _compile(step, w, w, w, lr, lr) == 1
+
+
+# ------------------------------------------------------------------------
+# The kernels of the deepseek_v3 train path at kanana_2_30b_a3b's widths
+# (chipbench/configs/kanana_2_30b_a3b.json: 2 rows x 1024 positions, 32
+# heads of 192 / 128, experts of 768, 16 of 128 held, 6 a token).
+
+MLA_ROWS, MLA_SEQ, MLA_UNITS, MLA_HEADS = 2, 1024, 2048, 32
+MLA_ADAM_SHAPES = [
+    (16, 768, 2048), (16, 2048, 768),       # experts stacked in one leaf
+    (16032, 2048),                          # embedding, head (a slice)
+    (6144, 2048), (2048, 4096),             # q_proj / dense FFN, o_proj
+    (576, 2048), (8192, 512),               # latent down and up
+    (128, 2048), (1536, 2048),              # router, shared expert
+    (2048,), (512,),                        # RMSNorm gains
+]
+
+
+def test_latent_attention_fwd_bwd_compiles(one_chip, on_tpu):
+    """q and k 192 wide, v 128: the flash forward kernel takes v
+    zero-padded to 192, one custom call; the backward is XLA's."""
+    from mxnet_tpu.ops.contrib import multi_head_attention
+    qk = jax.ShapeDtypeStruct((MLA_ROWS, MLA_SEQ, MLA_HEADS * 192),
+                              jnp.float32, sharding=one_chip)
+    v = jax.ShapeDtypeStruct((MLA_ROWS, MLA_SEQ, MLA_HEADS * 128),
+                             jnp.float32, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = multi_head_attention(q, k, v, MLA_HEADS, causal=True,
+                                   sm_scale=192 ** -0.5)
+        assert out.shape == (MLA_ROWS, MLA_SEQ, MLA_HEADS * 128)
+        return (out ** 2).sum()
+
+    assert _compile(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v) == 1
+
+
+@pytest.mark.parametrize('units', [MLA_UNITS, 512])
+def test_rms_norm_fwd_bwd_compiles(one_chip, on_tpu, units):
+    x = jax.ShapeDtypeStruct((MLA_ROWS * MLA_SEQ, units), jnp.float32,
+                             sharding=one_chip)
+    g = jax.ShapeDtypeStruct((units,), jnp.float32, sharding=one_chip)
+
+    def loss(x, gamma):
+        return (norms_mod.fused_rms_norm(x, gamma, 1e-6) ** 2).sum()
+
+    assert _compile(jax.grad(loss, argnums=(0, 1)), x, g) == 1
+
+
+def test_sparse_experts_take_the_grouped_kernels(one_chip):
+    """jax.lax.ragged_dot at the cell's shapes lowers to the compiler's
+    own grouped-matmul kernels, forward and backward, and not to the
+    dense product over every expert (an f32[16, 12288, ...] buffer)."""
+    from mxnet_tpu.ops.experts import sparse_experts
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                            sharding=one_chip)
+    shapes = (s(MLA_ROWS, MLA_SEQ, MLA_UNITS), s(128, MLA_UNITS), s(128),
+              s(16, 768, MLA_UNITS), s(16, 768, MLA_UNITS),
+              s(16, MLA_UNITS, 768))
+
+    def loss(*a):
+        return (sparse_experts(*a, experts_per_token=6, first_expert=0,
+                               routed_scaling_factor=2.448) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+        *shapes).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 9
+    assert 'f32[16,12288,' not in text
+
+
+@pytest.mark.parametrize('shape', MLA_ADAM_SHAPES, ids=str)
+def test_adam_step_compiles_at_the_sparse_decoders_shapes(one_chip, shape):
+    w, lr, t = _opt_shapes(shape, one_chip)
+
+    def step(w, m, v, g, lr, wd, t):
+        return opt_mod.adam_step(w, g, m, v, lr, wd, t, beta1=0.9,
+                                 beta2=0.999, epsilon=1e-8)
+
+    compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+        w, w, w, w, lr, lr, t).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        3 * 4 * w.size
