@@ -135,6 +135,8 @@ class _SegVjp:
             sel = tuple(self.plan.out_keys[i] for i in idxs)
 
             def vjp_apply(boundary, cts):
+                _tape.note_vjp_trace()      # the span's ``traced``
+
                 def f(*b):
                     env = replay(*b)
                     return tuple(env[ei][oi] for ei, oi in sel)

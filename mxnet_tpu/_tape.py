@@ -13,9 +13,12 @@ Design differences from the reference:
   backward rule for every node is obtained from ``jax.vjp`` — the MXGradient
   pass (src/nnvm/gradient.cc:699) collapses into XLA's autodiff.
 * When both recording and training, the VJP is computed at record time
-  (``jax.vjp`` runs the forward once and keeps residuals) — this mirrors the
-  reference keeping forward activations alive for backward. In
-  predict-record mode we defer and re-linearize at ``backward()`` time.
+  (the forward runs once and keeps residuals) — this mirrors the
+  reference keeping forward activations alive for backward. A single
+  eager op gets it from ``jax.vjp``; a compiled graph's call brings the
+  ``vjp_fn`` of its two built programs (gluon/block.py ``_VjpPrograms``),
+  so no ``jax.vjp`` runs in Python on such a step. In predict-record mode
+  we defer and re-linearize ``node.fn`` at ``backward()`` time.
 * Gradient aggregation (the reference's elemwise_sum/_grad_add nodes and
   kAddTo request) is plain accumulation into a cotangent map.
 """
@@ -35,7 +38,20 @@ def _st():
     if not hasattr(_state, 'recording'):
         _state.recording = False
         _state.training = False
+        _state.vjp_traces = 0
     return _state
+
+
+def note_vjp_trace():
+    """Called wherever a compiled program's vjp is traced in Python (a
+    compiled graph building one of its two programs, a deferred
+    ``jax.vjp``): the ``traced`` attribute of ``mx.graph.launch`` and
+    ``mx.tape.vjp`` is whether this moved during the span."""
+    _st().vjp_traces += 1
+
+
+def vjp_traces():
+    return _st().vjp_traces
 
 
 def is_recording():
@@ -255,8 +271,9 @@ def _node_vjp(node, present, indexed):
     of its outputs."""
     _check_not_updated_in_place(node)
     if indexed is not None:
-        # segment node: zero cotangents are synthesized inside
-        # the jitted vjp (symbolic zeros) instead of N host ops
+        # a bulk segment's node, a compiled graph's recorded call: zero
+        # cotangents are synthesized inside the jitted vjp (symbolic
+        # zeros) instead of N host ops
         return indexed({
             i: (c.dense() if isinstance(c, RowSparseCot) else c)
             for i, c in present.items()})
@@ -271,6 +288,7 @@ def _node_vjp(node, present, indexed):
         # predict-record deferral: the re-trace re-enters
         # _CachedOp's pure_fn Parameter-payload swap, which
         # must not race lock-free inference snapshots
+        note_vjp_trace()
         with node.vjp_lock:
             _, vjp_fn = jax.vjp(node.fn, *node.in_vals)
     else:
@@ -342,14 +360,18 @@ def _backward(heads, head_grads, retain_graph, train_mode, variables,
                 continue
             indexed = getattr(node.vjp_fn, 'indexed', None)
             if indexed is not None or node.vjp_lock is not None:
-                # the node is a compiled program (a bulk segment's
-                # indexed vjp, a compiled graph's call, which alone
-                # brings a lock): its vjp launches the backward
-                # programs. Single eager ops get no span of their own.
+                # the node is a compiled program (a bulk segment's or a
+                # recorded graph call's indexed vjp; a graph's call in
+                # predict-record mode, which brings a lock and defers):
+                # its vjp launches the backward programs. Single eager
+                # ops get no span of their own.
                 with _trace.child_span('mx.tape.vjp') as launch:
+                    traces = vjp_traces()
                     in_cots = _node_vjp(node, present, indexed)
                     if launch.live:
-                        launch.set(n_out=len(in_cots))
+                        launch.set(
+                            n_out=sum(c is not None for c in in_cots),
+                            traced=int(vjp_traces() != traces))
             else:
                 in_cots = _node_vjp(node, present, indexed)
             for parent, cot in zip(node.parents, in_cots):

@@ -279,6 +279,181 @@ class Block:
         bucket prewarm — the zero-recompiles-under-traffic guarantee."""
         return sum(child.compile_count for child in self._children.values())
 
+    @property
+    def vjp_trace_count(self):
+        """Calls of this block's subtree on which ``jax.vjp``'s Python
+        ran (monotonic): a hybridized block traces the vjp of a compiled
+        entry once, on its first call under ``record()`` in train mode,
+        and later recorded calls launch the two programs built then. It
+        rises again only for a new input shape, train mode or mesh."""
+        return sum(child.vjp_trace_count
+                   for child in self._children.values())
+
+
+# where a leaf of a built vjp_fn comes from (_VjpPrograms.sources): the
+# recorded forward's residual outputs, its own arguments, or the trace
+_RESIDUAL, _ARGUMENT, _CONSTANT = 'residual', 'argument', 'constant'
+
+
+def _constant_of(leaf):
+    """The value of a residual leaf that is a constant of the trace, None
+    where it is computed."""
+    import jax
+    if not isinstance(leaf, jax.core.Tracer):
+        return leaf
+    return leaf.get_const()
+
+
+class _Entry:
+    """One compiled entry of a :class:`_CachedGraph`: ``forward`` is the
+    jitted forward, ``vjp`` the :class:`_VjpPrograms` a call under
+    ``record()`` in train mode takes (None on an entry of predict
+    mode)."""
+
+    __slots__ = ('forward', 'vjp')
+
+    def __init__(self, forward, vjp):
+        self.forward = forward
+        self.vjp = vjp
+
+
+class _VjpPrograms:
+    """The vjp of one compiled entry as two jitted programs, so that a
+    recorded call runs none of ``jax.vjp``'s Python after the first.
+
+    ``forward`` is ``jax.vjp`` of the entry's forward under one
+    ``jax.jit``: it returns the outputs, the new aux values and the
+    residuals that are not its own arguments. A residual that is the
+    tracer of an argument (every matrix; under ``remat`` every residual)
+    would come back as a copy, so which leaves of the ``vjp_fn`` are
+    arguments is found at trace time and kept here with its treedef, and
+    ``backward`` is handed those arguments beside the returned
+    residuals; a leaf that is a constant of the trace stays with the
+    treedef. An aux leaf the forward hands back as it came in is taken
+    from the arguments likewise. ``backward`` rebuilds the ``vjp_fn`` and
+    calls it; nothing is donated into it (``retain_graph=True`` calls it
+    twice).
+    """
+
+    def __init__(self, graph, prog, jit_kwargs, place, pack,
+                 main_shardings):
+        import jax
+        import jax.numpy as jnp
+
+        self.graph = graph
+        self.place = place
+        self.pack = pack
+        self.treedef = None         # of the vjp_fn, set by forward's trace
+        self.sources = None         # per leaf of the vjp_fn: (kind, at)
+        self.forwarded = None       # positions in _arguments() handed on
+        self.aux_forwarded = None   # per aux leaf: its position, or None
+        self.out_avals = None
+        self.n_out = None           # buffers the forward hands back
+        # a donated aux buffer is gone after the call: as a residual it
+        # is returned like any other
+        self._donated = jit_kwargs.get('donate_argnums', ())
+
+        def recorded_forward(rng_key, in_raws, main_raws, *aux_parts):
+            graph.vjp_traces += 1
+            _tape.note_vjp_trace()
+            outs, vjp_fn, aux_out = jax.vjp(
+                lambda ins, mains: prog(rng_key, ins, mains, *aux_parts),
+                in_raws, main_raws, has_aux=True)
+            leaves, self.treedef = jax.tree.flatten(vjp_fn)
+            position = {id(a): i for i, a in enumerate(self._arguments(
+                rng_key, in_raws, main_raws, aux_parts))}
+            slot, residuals, forwarded, sources = {}, [], [], []
+            for leaf in leaves:
+                at = position.get(id(leaf))
+                const = None if at is not None else _constant_of(leaf)
+                if const is not None:
+                    # a literal of the trace (a scale, 0.5): the backward
+                    # program closes over it, no buffer carries it
+                    sources.append((_CONSTANT, const))
+                    continue
+                into = residuals if at is None else forwarded
+                if id(leaf) not in slot:
+                    slot[id(leaf)] = len(into)
+                    into.append(leaf if at is None else at)
+                sources.append((_RESIDUAL if at is None else _ARGUMENT,
+                                slot[id(leaf)]))
+            self.sources, self.forwarded = sources, tuple(forwarded)
+            self.aux_forwarded = tuple(position.get(id(a)) for a in aux_out)
+            aux_out = tuple(a for a, at in zip(aux_out, self.aux_forwarded)
+                            if at is None)
+            self.out_avals = [(o.shape, o.dtype) for o in outs]
+            self.n_out = len(outs) + len(aux_out) + len(residuals)
+            return outs, aux_out, tuple(residuals)
+
+        def recorded_backward(residuals, forwarded, cots):
+            _tape.note_vjp_trace()
+            vjp_fn = jax.tree.unflatten(self.treedef, [
+                at if kind is _CONSTANT else
+                (residuals if kind is _RESIDUAL else forwarded)[at]
+                for kind, at in self.sources])
+            in_cots, main_cots = vjp_fn(tuple(
+                jnp.zeros(*aval) if c is None else c
+                for c, aval in zip(cots, self.out_avals)))
+            if main_shardings is not None:
+                # p.grad and the fused update see the parameter's layout
+                main_cots = tuple(
+                    jax.lax.with_sharding_constraint(c, sh)
+                    for c, sh in zip(main_cots, main_shardings))
+            # an integer input's cotangent is float0: the tape takes None
+            return tuple(None if c.dtype == jax.dtypes.float0 else c
+                         for c in in_cots + main_cots)
+
+        self.forward = jax.jit(recorded_forward, **jit_kwargs)
+        self.backward = jax.jit(recorded_backward)
+
+    def _arguments(self, rng_key, in_raws, main_raws, aux_parts):
+        """The forward's arguments that outlive the call, flat."""
+        flat = [rng_key, *in_raws, *main_raws]
+        for argnum, part in enumerate(aux_parts, 3):
+            if argnum not in self._donated:
+                flat.extend(part)
+        return flat
+
+    def __call__(self, rng_key, in_raws, main_raws, aux_raws):
+        """``(outs, aux_out, vjp_fn)`` of one recorded call."""
+        rng_key, in_raws = self.place(rng_key, in_raws)
+        aux_parts = self.pack(aux_raws)
+        outs, written, residuals = self.forward(rng_key, in_raws, main_raws,
+                                                *aux_parts)
+        args = self._arguments(rng_key, in_raws, main_raws, aux_parts)
+        written = iter(written)
+        aux_out = tuple(next(written) if at is None else args[at]
+                        for at in self.aux_forwarded)
+        return outs, aux_out, _Vjp(
+            self, residuals, tuple(args[i] for i in self.forwarded))
+
+
+class _Vjp:
+    """The ``vjp_fn`` of one recorded call (a TapeNode's): the call's
+    residuals and the launch of its entry's backward program. The tape
+    calls ``indexed`` with the cotangents that arrived; zeros for the
+    others are made inside the program."""
+
+    __slots__ = ('programs', 'residuals', 'forwarded')
+
+    def __init__(self, programs, residuals, forwarded):
+        self.programs = programs
+        self.residuals = residuals
+        self.forwarded = forwarded
+
+    def indexed(self, present):
+        # aux outputs follow the outputs in the node and get no cotangent
+        cots = self.programs.backward(
+            self.residuals, self.forwarded,
+            tuple(present.get(i)
+                  for i in range(len(self.programs.out_avals))))
+        # the outputs of a program are ready together: the last will do
+        self.programs.graph._backward = cots[-1]
+        return cots
+
+    def __call__(self, cots):
+        return self.indexed(dict(enumerate(cots)))
+
 
 class _CachedGraph:
     """Compiled-executable cache for one HybridBlock (≙ CachedOp,
@@ -304,6 +479,12 @@ class _CachedGraph:
         # the serving layer's zero-recompiles-after-warmup guarantee is
         # checked against this, so re-hybridize churn must show up too)
         self.compiles = 0
+        # calls on which jax.vjp's Python ran: once an entry, when its
+        # recorded forward is traced (Block.vjp_trace_count)
+        self.vjp_traces = 0
+        # an output of the last backward program launched from this
+        # graph, until the next recorded call has waited for it
+        self._backward = None
         self._compiled = {}
         self._out_trees = {}       # per cache entry: output pytree structure
         self._param_order = None
@@ -430,45 +611,55 @@ class _CachedGraph:
             _, aux = self._params()
             written = [i for i in range(len(aux)) if i not in held]
 
-            def pure5(rng_key, in_raws, main_raws, written_raws, held_raws):
+            def prog(rng_key, in_raws, main_raws, written_raws, held_raws):
                 at = dict(zip(written + list(held),
                               written_raws + held_raws))
                 return pure_fn(rng_key, in_raws, main_raws,
                                tuple(at[i] for i in range(len(aux))))
 
+            def pack(aux_raws):
+                return (tuple(aux_raws[i] for i in written),
+                        tuple(aux_raws[i] for i in held))
+
             if 'in_shardings' in jit_kwargs:
                 sh = jit_kwargs['in_shardings']
-                jit_kwargs['in_shardings'] = sh[:3] + (
-                    tuple(sh[3][i] for i in written),
-                    tuple(sh[3][i] for i in held))
-            jitted5 = jax.jit(pure5, **jit_kwargs)
-
-            def jitted(rng_key, in_raws, main_raws, aux_raws):
-                return jitted5(rng_key, in_raws, main_raws,
-                               tuple(aux_raws[i] for i in written),
-                               tuple(aux_raws[i] for i in held))
+                jit_kwargs['in_shardings'] = sh[:3] + pack(sh[3])
         else:
-            jitted = jax.jit(pure_fn, **jit_kwargs)
+            prog = pure_fn
+
+            def pack(aux_raws):
+                return (aux_raws,)
+
+        jitted = jax.jit(prog, **jit_kwargs)
+        main_shardings = None
         if ctx is None:
-            return jitted
-        # rng key / inputs arrive as committed single-device arrays each
-        # call while the params are committed to the mesh — jax rejects
-        # mixed device sets, so place them on the mesh at dispatch.
-        # device_put is a traceable primitive, so the autograd vjp
-        # re-trace of this wrapper stays valid.
-        from jax.sharding import NamedSharding, PartitionSpec as _P
-        key_sh = NamedSharding(ctx.mesh, _P())
-        in_shs = tuple(NamedSharding(ctx.mesh, s) for s in in_specs)
+            def place(rng_key, in_raws):
+                return rng_key, in_raws
+        else:
+            # rng key / inputs arrive as committed single-device arrays
+            # each call while the params are committed to the mesh — jax
+            # rejects mixed device sets, so place them on the mesh at
+            # dispatch. device_put is a traceable primitive, so a vjp
+            # re-trace of ``forward`` (create_graph, predict-record)
+            # stays valid.
+            from jax.sharding import NamedSharding, PartitionSpec as _P
+            key_sh = NamedSharding(ctx.mesh, _P())
+            in_shs = tuple(NamedSharding(ctx.mesh, s) for s in in_specs)
+            main_shardings = jit_kwargs['in_shardings'][2]
 
-        def sharded_fn(rng_key, in_raws, main_raws, aux_raws):
-            rng_key = jax.device_put(rng_key, key_sh)
-            in_raws = tuple(
-                jax.device_put(r, sh)
-                if getattr(r, 'ndim', None) is not None else r
-                for r, sh in zip(in_raws, in_shs))
-            return jitted(rng_key, in_raws, main_raws, aux_raws)
+            def place(rng_key, in_raws):
+                return jax.device_put(rng_key, key_sh), tuple(
+                    jax.device_put(r, sh)
+                    if getattr(r, 'ndim', None) is not None else r
+                    for r, sh in zip(in_raws, in_shs))
 
-        return sharded_fn
+        def forward(rng_key, in_raws, main_raws, aux_raws):
+            rng_key, in_raws = place(rng_key, in_raws)
+            return jitted(rng_key, in_raws, main_raws, *pack(aux_raws))
+
+        return _Entry(forward, _VjpPrograms(
+            self, prog, jit_kwargs, place, pack, main_shardings)
+            if train_mode else None)
 
     def _aux_handed_back(self, pure_fn, in_nds):
         """Indices of the aux leaves that this entry's forward hands
@@ -584,10 +775,27 @@ class _CachedGraph:
             # compile.
             with _trace.child_span('mx.graph.flush'):
                 _bulk.flush_current()
+                if _tape.is_recording():
+                    self._await_backward()
             out = self._call_static(args, span)
             if span.live:
                 span.set(compiled=self.compiles - built)
             return out
+
+    def _await_backward(self):
+        """A recorded forward's residuals are allocated when it is
+        enqueued. Enqueued while the backward of the call before it has
+        not run, they are a second set on the device beside that call's
+        (and a third where the host is two steps ahead): a host that is
+        faster than the device would fill the chip with them. So a
+        recorded call first waits for the last backward launched from
+        this graph; the update that follows a backward on the device
+        covers the forward's dispatch."""
+        import jax
+
+        launched, self._backward = self._backward, None
+        if launched is not None:
+            jax.block_until_ready(launched)
 
     def _call_static(self, args, span):
         import jax
@@ -637,8 +845,9 @@ class _CachedGraph:
         # is thread-safe. The lock serializes (a) tracing, because
         # jax.jit traces lazily on first execution and pure_fn swaps
         # traced values into the SHARED Parameter payloads, and (b) any
-        # autograd-recorded call, whose jax.vjp re-traces the jitted
-        # function and re-enters that swap. Parameter snapshots on the
+        # autograd-recorded call: the first of an entry traces its
+        # recorded forward and re-enters that swap, and every one
+        # rebinds donated aux state. Parameter snapshots on the
         # lock-free path still acquire the lock briefly so they can
         # never observe a mid-trace swap.
         if key in self._ready and not recording:
@@ -648,14 +857,14 @@ class _CachedGraph:
                 # cache since the unlocked _ready probe. out_tree is
                 # snapshotted here too — _execute must not re-read the
                 # dict after the lock drops.
-                jfn = self._compiled.get(key)
+                entry = self._compiled.get(key)
                 out_tree = self._out_trees.get(key)
                 main_nds = [p.data() for p in main]
                 aux_raws = tuple(p.data()._data for p in aux)
-            if jfn is not None and out_tree is not None:
+            if entry is not None and out_tree is not None:
                 try:
-                    return self._execute(args, key, jfn, in_nds, main_nds,
-                                         aux_raws, out_tree)
+                    return self._execute(args, key, entry, in_nds,
+                                         main_nds, aux_raws, out_tree)
                 except RuntimeError as e:
                     if 'deleted' not in str(e).lower():
                         raise
@@ -676,10 +885,10 @@ class _CachedGraph:
                                                   donate=donate, ctx=ctx,
                                                   in_nds=in_nds)
                 self.compiles += 1
-            jfn = self._compiled[key]
+            entry = self._compiled[key]
             main_nds = [p.data() for p in main]
             aux_raws = tuple(p.data()._data for p in aux)
-            out = self._execute(args, key, jfn, in_nds, main_nds,
+            out = self._execute(args, key, entry, in_nds, main_nds,
                                 aux_raws, None)
             self._ready.add(key)
             if self.check and not self._checked:
@@ -718,7 +927,7 @@ class _CachedGraph:
             warnings.warn(str(report), stacklevel=4)
         report.raise_if_errors()
 
-    def _execute(self, args, key, jfn, in_nds, main_nds, aux_raws,
+    def _execute(self, args, key, entry, in_nds, main_nds, aux_raws,
                  out_tree):
         import jax
         from ..ops.registry import Op, apply_op, DynamicShapeError
@@ -727,27 +936,40 @@ class _CachedGraph:
         rng_key = _rng.next_key()
         n_in = len(in_nds)
         n_aux = len(aux)
+        recorded = False
 
         def fn(*raws):
-            ins = raws[:n_in]
-            ps = raws[n_in:]
-            outs, aux_out = jfn(rng_key, tuple(ins), tuple(ps), aux_raws)
+            outs, aux_out = entry.forward(rng_key, raws[:n_in], raws[n_in:],
+                                          aux_raws)
             return tuple(outs) + tuple(aux_out)
 
+        def record(*raws):
+            # fn with its vjp, from the entry's two built programs: what
+            # a recorded call in train mode runs in place of jax.vjp(fn)
+            nonlocal recorded
+            outs, aux_out, vjp_fn = entry.vjp(rng_key, raws[:n_in],
+                                              raws[n_in:], aux_raws)
+            recorded = True
+            return tuple(outs) + tuple(aux_out), vjp_fn
+
         op = Op('_CachedOp', fn, differentiable=True)
-        # predict-record mode defers jax.vjp to backward() time
+        # predict-record mode defers jax.vjp(fn) to backward() time
         # (_tape.py); that re-trace re-enters pure_fn's shared-Parameter
         # payload swap and must hold this graph's lock (ADVICE r4)
         op.vjp_lock = self._lock
         try:
-            # the jitted call down to PjRt (under record() jax.vjp's
-            # forward, residuals and all)
+            # the jitted call down to PjRt (under record() in train mode
+            # the recorded forward, which hands back its residuals too)
             with _trace.child_span('mx.graph.launch') as launch:
+                traces = _tape.vjp_traces()
                 res = apply_op(op, in_nds + main_nds, fn,
-                               name='_CachedOp', lift=False)
+                               name='_CachedOp', lift=False,
+                               record=record if entry.vjp else None)
                 if launch.live:
                     launch.set(
-                        n_out=len(res) if isinstance(res, tuple) else 1)
+                        n_out=entry.vjp.n_out if recorded else
+                        len(res) if isinstance(res, tuple) else 1,
+                        traced=int(_tape.vjp_traces() != traces))
         except DynamicShapeError:
             # a dynamic-output-shape op inside the graph (boolean_mask,
             # unique, ...): permanently switch this block to eager
@@ -874,6 +1096,14 @@ class HybridBlock(Block):
         own = self._cached_graph.compiles if isinstance(
             self._cached_graph, _CachedGraph) else 0
         return own + sum(c.compile_count for c in self._children.values())
+
+    @property
+    def vjp_trace_count(self):
+        """See :attr:`Block.vjp_trace_count`; adds this block's own."""
+        own = self._cached_graph.vjp_traces if isinstance(
+            self._cached_graph, _CachedGraph) else 0
+        return own + sum(c.vjp_trace_count
+                         for c in self._children.values())
 
     def prewarm(self, input_specs, dtype='float32'):
         """Compile executables for a declared set of input shapes before

@@ -69,7 +69,8 @@ class Op:
         # held while a DEFERRED jax.vjp re-traces fn at backward() time
         # (predict-record mode): _CachedOp's re-trace swaps shared
         # Parameter payloads and must serialize with the graph lock
-        # exactly like record-time tracing does (docs/threading.md)
+        # exactly like the tracing of an entry's programs does
+        # (docs/threading.md)
         self.vjp_lock = None
         self.differentiable = differentiable
         self.stochastic = stochastic
@@ -199,13 +200,16 @@ def _hashable(x):
 
 
 def apply_op(op, arrays, fn, n_out=None, name=None, _from_invoke=False,
-             bulk_key=None, lift=True):
+             bulk_key=None, lift=True, record=None):
     """Imperative dispatch of a pure function over NDArray inputs.
 
     ``arrays``: NDArray inputs participating in autograd. ``fn``: closure over
     their raw arrays (constants already baked in). Returns raw output(s);
     the caller wraps them. If autograd is recording and any input is tracked,
     a TapeNode is attached to the outputs (reference: Imperative::RecordOp).
+    ``record``, where given, is ``fn`` with its vjp already built:
+    ``record(*raws) -> (outs, vjp_fn)`` takes the place of ``jax.vjp(fn,
+    *raws)`` (a compiled graph's two programs, gluon/block.py).
 
     Under deferred-compute capture, direct apply_op calls (closure-based
     dispatchers like fused RNN) record an *opaque* node: the captured graph
@@ -250,7 +254,8 @@ def apply_op(op, arrays, fn, n_out=None, name=None, _from_invoke=False,
         import time as _time
         _t0 = _time.perf_counter()
     if recording and op.differentiable and _tape.is_training():
-        outs, vjp_fn = jax.vjp(fn, *raws)
+        outs, vjp_fn = (jax.vjp(fn, *raws) if record is None
+                        else record(*raws))
     else:
         outs = fn(*raws)
     if profiling:

@@ -24,9 +24,9 @@ TREE = {
 }
 ATTRS = {
     'mx.graph.call': {'n_in', 'n_params', 'compiled'},
-    'mx.graph.launch': {'n_out'},
+    'mx.graph.launch': {'n_out', 'traced'},
     'mx.tape.backward': {'n_nodes', 'n_vars'},
-    'mx.tape.vjp': {'n_out'},
+    'mx.tape.vjp': {'n_out', 'traced'},
     'mx.bulk.flush': {'n_ops', 'n_out', 'compiled'},
     'mx.trainer.step': {'n_params'},
     'mx.trainer.hyper': {'uploaded'},
@@ -102,6 +102,14 @@ class Loop:
         after = _bulk.stats()
         return telemetry.events(), {k: after[k] - before[k] for k in after}
 
+    def handed_back(self):
+        """Buffers the recorded forward of the one train entry hands
+        back: the output and the residuals that are not its arguments."""
+        (programs,) = [e.vjp for e in
+                       self.net._cached_graph._compiled.values() if e.vjp]
+        assert self.net.vjp_trace_count == 1
+        return programs.n_out
+
 
 @pytest.fixture(scope='module')
 def bulked_steps():
@@ -111,12 +119,13 @@ def bulked_steps():
     with _bulk.force(True):
         for layers in (2, 4):
             loop = Loop(layers)
-            out[layers] = loop.traced_step() + (loop.n_params,)
+            out[layers] = loop.traced_step() + (loop.n_params,
+                                                loop.handed_back())
     return out
 
 
 def test_a_step_is_one_connected_tree(bulked_steps):
-    events, _, _ = bulked_steps[2]
+    events = bulked_steps[2][0]
     tids = telemetry.trace_ids(events)
     assert len(tids) == 1
     roots = telemetry.trace_tree(events, tids[0])
@@ -126,7 +135,7 @@ def test_a_step_is_one_connected_tree(bulked_steps):
 
 def test_the_tree_holds_every_span_each_child_inside_its_parent(
         bulked_steps):
-    events, _, _ = bulked_steps[2]
+    events = bulked_steps[2][0]
     by_id = {e['span']: e for e in events}
     children = {}
     for e in events:
@@ -139,7 +148,7 @@ def test_the_tree_holds_every_span_each_child_inside_its_parent(
 
 
 def test_the_spans_carry_their_counts(bulked_steps):
-    events, _, n_params = bulked_steps[2]
+    events, _, n_params, handed_back = bulked_steps[2]
     for e in events:
         if e['name'] in ATTRS:
             assert set(e['attrs']) == ATTRS[e['name']], e['name']
@@ -147,7 +156,10 @@ def test_the_spans_carry_their_counts(bulked_steps):
            if e['name'] != 'mx.tape.vjp' and 'attrs' in e}
     assert one['mx.graph.call'] == {'n_in': 3, 'n_params': n_params,
                                     'compiled': 0}
-    assert one['mx.graph.launch'] == {'n_out': 1}
+    # the recorded forward's output and residuals; the programs were
+    # built two steps ago and this call launched them
+    assert one['mx.graph.launch'] == {'n_out': handed_back, 'traced': 0}
+    assert handed_back > 1
     assert one['mx.trainer.step'] == {'n_params': n_params}
     assert one['mx.trainer.hyper'] == {'uploaded': 1}
     # w, g and Adam's two slots in; w and the slots out, each written
@@ -159,7 +171,10 @@ def test_the_spans_carry_their_counts(bulked_steps):
     # the two compiled nodes of the tape: the loss segment, the net
     vjps = sorted(e['attrs']['n_out'] for e in events
                   if e['name'] == 'mx.tape.vjp')
-    assert vjps[-1] == n_params + 3 and len(vjps) == 2
+    # a gradient a parameter; the three integer inputs get none
+    assert vjps[-1] == n_params and len(vjps) == 2
+    assert [e['attrs']['traced'] for e in events
+            if e['name'] == 'mx.tape.vjp'] == [0, 0]
     assert one['mx.tape.backward']['n_nodes'] == 2
 
 
@@ -172,7 +187,7 @@ def test_the_number_of_spans_does_not_depend_on_depth(bulked_steps):
 
 
 def test_bulk_stats_grow_with_the_flushes(bulked_steps):
-    events, grew, _ = bulked_steps[2]
+    events, grew = bulked_steps[2][:2]
     flushes = [e for e in events if e['name'] == 'mx.bulk.flush']
     assert grew['flushes'] == len(flushes) == 1
     assert flushes[0]['attrs']['n_ops'] >= flushes[0]['attrs']['n_out'] > 0
