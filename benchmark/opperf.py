@@ -1,26 +1,18 @@
-"""Per-operator forward/backward latency benchmark.
+"""Input rules for every registered op, and the kvstore fault soak.
 
-Reference: ``benchmark/opperf/opperf.py`` (rule-driven per-op fwd/bwd
-latency + memory across all registered ops, SURVEY §4 "Benchmarks as
-tests"). Here: ops are pulled from the live registry, inputs come from
-category rules (CATEGORY_RULES below), timing is wall-clock around a
-``block_until_ready`` sync (JAX async dispatch ≙ the reference's engine
-push + WaitToRead).
+Two things live here, under the name the reference gave its per-op
+benchmark (``benchmark/opperf/opperf.py``); nothing in this file times
+an op (the benchmark is ``chipbench/run.py``, ``PERF.md`` §1):
 
-Usage:
-    python benchmark/opperf.py                     # curated default set
-    python benchmark/opperf.py --ops relu,dot     # specific ops
-    python benchmark/opperf.py --all              # everything with a rule
-    python benchmark/opperf.py --cpu --runs 20
-Output: one JSON line per op with fwd/bwd latency (ms).
-
-KVStore soak mode (`--kvstore-soak N`): N push/pull rounds on an
-in-process ``dist_async`` store under a fixed fault spec
-(``--fault-spec``, default a deterministic periodic connection reset),
-verifying exactly-once delivery against the server's apply counters and
-printing one JSON line with retry/injection/apply counts — regressions
-in the recovery path show up in the bench trajectory. Exit status is
-non-zero when verification fails.
+- ``_RULES`` / ``rule`` / ``_register_rules``: an example input per op
+  family (≙ the reference's ``benchmark/opperf/rules/``), the input table
+  of ``tests/test_op_sweep.py`` and ``tests/test_op_coverage_meta.py``.
+- ``kvstore_soak`` (``--kvstore-soak N``): N push/pull rounds on an
+  in-process ``dist_async`` store under a fixed fault spec
+  (``--fault-spec``, default a deterministic periodic connection reset),
+  verifying exactly-once delivery against the server's apply counters and
+  printing one JSON line with retry/injection/apply counts. Exit status
+  is non-zero when verification fails.
 
     python benchmark/opperf.py --cpu --kvstore-soak 50
     python benchmark/opperf.py --cpu --kvstore-soak 200 \
@@ -31,7 +23,6 @@ import argparse
 import json
 import os
 import sys
-import time
 
 _RULES = {}
 
@@ -45,7 +36,7 @@ def _register_rules(np_, large=(1024, 1024), nn_scale=8):
     """Input-shape rules per op family (≙ benchmark/opperf/rules/).
 
     ``large``/``nn_scale`` shrink the inputs for the correctness sweep in
-    tests/test_op_sweep.py (bench uses the defaults)."""
+    tests/test_op_sweep.py."""
     u = lambda *s: np_.random.uniform(0.5, 1.5, s).astype('float32')  # noqa: E731
     LARGE = large
     sc = nn_scale
@@ -234,100 +225,6 @@ def _register_rules(np_, large=(1024, 1024), nn_scale=8):
          kwargs={'heads': 8})
 
 
-DEFAULT_SET = [
-    'relu', 'sigmoid', 'gelu', 'exp', 'add', 'multiply', 'sum', 'mean',
-    'dot', 'matmul', 'batch_dot', 'einsum', 'transpose', 'reshape',
-    'concat', 'softmax', 'topk', 'fully_connected', 'convolution',
-    'pooling', 'batch_norm_inference', 'layer_norm', 'embedding',
-    'multi_head_attention', 'take', 'where', 'cumsum', 'clip',
-    'sgd_update', 'adam_update',
-]
-
-
-def bench_op(mx, name, runs=10, warmup=3, backward=True):
-    import numpy as np
-    from mxnet_tpu import autograd
-
-    spec = _RULES[name]
-    raw_args = [a for a in spec['args']()]
-    args = [mx.np.array(a) if isinstance(a, np.ndarray) else a
-            for a in raw_args]
-    kwargs = spec['kwargs_fn']() if 'kwargs_fn' in spec \
-        else spec.get('kwargs', {})
-    fn = getattr(mx.npx, name, None) or getattr(mx.np, name)
-
-    # Per-run value perturbation: no timed call repeats another's
-    # byte-identical arguments. All perturbed variants of the first float
-    # tensor (a ~1e-6 relative shrink per run, staying inside op domains)
-    # are materialized BEFORE the timed loops so the multiply is never
-    # part of a measured run, and the fwd and fwd+bwd phases draw from
-    # disjoint variant ranges so no (program, inputs) pair ever repeats.
-    fidx = next((j for j, a in enumerate(args)
-                 if hasattr(a, 'dtype') and
-                 str(a.dtype).startswith('float')), None)
-    n_variants = 2 * (warmup + runs)
-    if fidx is not None:
-        variants = [args[fidx] * (1.0 - (i + 1) * 2.0 ** -20)
-                    for i in range(n_variants)]
-        for v in variants:
-            v.wait_to_read()
-    else:
-        variants = None
-
-    def perturbed(i):
-        a = list(args)
-        if variants is not None:
-            a[fidx] = variants[i]
-        return a
-
-    def fwd(i):
-        out = fn(*perturbed(i), **kwargs)
-        (out[0] if isinstance(out, (tuple, list)) else out).wait_to_read()
-        return out
-
-    for i in range(warmup):
-        fwd(i)
-    t0 = time.perf_counter()
-    for i in range(runs):
-        fwd(warmup + i)
-    fwd_ms = (time.perf_counter() - t0) / runs * 1e3
-
-    bwd_ms = None
-    from mxnet_tpu.ops.registry import get_op
-    differentiable = get_op(name).differentiable and \
-        not spec.get('no_grad', False)
-    if backward and differentiable:
-        grads_on = [a for a in args if hasattr(a, 'attach_grad')]
-        for a in grads_on:
-            a.attach_grad()
-        if variants is not None:
-            for v in variants:
-                if hasattr(v, 'attach_grad'):
-                    v.attach_grad()
-
-        def step(i):
-            a = perturbed(i)
-            sync = a[fidx] if variants is not None and \
-                hasattr(a[fidx], 'attach_grad') else grads_on[0]
-            with autograd.record():
-                out = fn(*a, **kwargs)
-                first = out[0] if isinstance(out, (tuple, list)) else out
-                loss = (first * first).sum()
-            loss.backward()
-            sync.grad.wait_to_read()
-
-        base = warmup + runs    # disjoint from the fwd phase's variants
-        for i in range(warmup):
-            step(base + i)
-        t0 = time.perf_counter()
-        for i in range(runs):
-            step(base + warmup + i)
-        bwd_ms = (time.perf_counter() - t0) / runs * 1e3
-
-    return {'op': name, 'fwd_ms': round(fwd_ms, 4),
-            'fwd_bwd_ms': round(bwd_ms, 4) if bwd_ms is not None else None}
-
-
 def kvstore_soak(rounds, fault_spec, size=1024, keys=2, port=None):
     """N rounds of push/pull per key on an in-process ``dist_async``
     store with a fault plan armed; returns the result record. The
@@ -380,18 +277,11 @@ def kvstore_soak(rounds, fault_spec, size=1024, keys=2, port=None):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument('--ops', default=None,
-                    help='comma-separated op names (default: curated set)')
-    ap.add_argument('--all', action='store_true',
-                    help='run every op with a rule')
-    ap.add_argument('--runs', type=int, default=10)
-    ap.add_argument('--warmup', type=int, default=3)
-    ap.add_argument('--no-backward', action='store_true')
     ap.add_argument('--cpu', action='store_true')
-    ap.add_argument('--kvstore-soak', type=int, default=None,
+    ap.add_argument('--kvstore-soak', type=int, required=True,
                     metavar='N',
                     help='run N dist_async push/pull rounds under '
-                         '--fault-spec instead of op benchmarks')
+                         '--fault-spec')
     ap.add_argument('--fault-spec',
                     default='reset_every:push:7;delay:push:1ms',
                     help='MXNET_KVSTORE_FAULT_SPEC grammar for the '
@@ -399,40 +289,16 @@ def main():
     args = ap.parse_args()
 
     # repo root on sys.path regardless of device: `python
-    # benchmark/opperf.py` puts only benchmark/ there, so the TPU-mode
-    # import of mxnet_tpu died with ModuleNotFoundError (r5 smoke)
+    # benchmark/opperf.py` puts only benchmark/ there
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     if args.cpu:
         import _cpu_guard
         _cpu_guard.force_cpu()
 
-    if args.kvstore_soak is not None:
-        res = kvstore_soak(args.kvstore_soak, args.fault_spec)
-        print(json.dumps(res), flush=True)
-        sys.exit(0 if res['verified_exactly_once'] else 1)
-
-    import numpy as np
-    import mxnet_tpu as mx
-    _register_rules(np)
-
-    names = (args.ops.split(',') if args.ops
-             else sorted(_RULES) if getattr(args, 'all')
-             else DEFAULT_SET)
-    results = []
-    for name in names:
-        if name not in _RULES:
-            print(f'# no rule for op {name!r}, skipping', file=sys.stderr)
-            continue
-        try:
-            res = bench_op(mx, name, runs=args.runs, warmup=args.warmup,
-                           backward=not args.no_backward)
-        except Exception as e:   # keep sweeping (reference opperf does too)
-            res = {'op': name, 'error': f'{type(e).__name__}: {e}'}
-        results.append(res)
-        print(json.dumps(res), flush=True)
-    ok = [r for r in results if 'error' not in r]
-    print(f'# {len(ok)}/{len(results)} ops benchmarked', file=sys.stderr)
+    res = kvstore_soak(args.kvstore_soak, args.fault_spec)
+    print(json.dumps(res), flush=True)
+    sys.exit(0 if res['verified_exactly_once'] else 1)
 
 
 if __name__ == '__main__':

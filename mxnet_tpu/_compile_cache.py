@@ -5,8 +5,7 @@ runs: it is either what the environment says
 (``JAX_COMPILATION_CACHE_DIR``, which JAX reads itself — nothing is set
 in code then) or one fixed path under the checkout, ``.jax_cache``
 (git-ignored). Entry points that compile large programs call
-:func:`place` once before their first jit: ``chip_smoke.py`` and
-``bench.py``'s children.
+:func:`place` once before their first jit (``chip_smoke.py``).
 """
 
 import os

@@ -104,8 +104,8 @@ def cost_report(fn_or_block, *example_args, train=False,
 
     ``device_spec`` picks the roofline device: a name from
     :data:`DEVICE_SPECS`, a JSON path, or a dict (default: the
-    BENCH_r05 measured entry, overridable via
-    ``MXNET_ANALYSIS_DEVICE_SPEC``). ``while_trips=N`` sets the assumed
+    ``bench-r05`` entry, read on an earlier development device,
+    overridable via ``MXNET_ANALYSIS_DEVICE_SPEC``). ``while_trips=N`` sets the assumed
     trip count for ``lax.while_loop`` equations (static analysis cannot
     know it; the assumption is recorded on the report).
     """

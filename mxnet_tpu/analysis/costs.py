@@ -1,10 +1,8 @@
 """Static roofline cost model over traced jaxprs (``mx.analysis.costs``).
 
-BENCH_r05 frames the perf frontier in roofline terms — train MFU 0.106
-of spec, HBM at 7.6% of spec, machine balance 1524 flop/B — but those
-are *measured* aggregates; nothing could point at the equations
-responsible. This pass computes, statically over the exact jaxpr
-``hybridize`` compiles:
+A measured aggregate (an MFU, a share of the HBM roofline) cannot point
+at the equations responsible. This pass computes, statically over the
+exact jaxpr ``hybridize`` compiles:
 
 * per-equation **FLOPs** and **bytes in/out** from closed-form
   per-primitive cost functions (dot_general ``2·B·M·N·K``, conv
@@ -14,12 +12,14 @@ responsible. This pass computes, statically over the exact jaxpr
   per-op override hook (``Op.cost`` in ops/registry.py);
 * per-graph totals, **arithmetic intensity**, and a roofline
   classification against a device-spec table
-  (analysis/device_specs.py — default: the BENCH_r05 measured numbers);
+  (analysis/device_specs.py — default: ``bench-r05``, read on an
+  earlier development device: 95 TFLOP/s, 62.5 GB/s);
 * a donation-aware **liveness walk** predicting peak HBM bytes.
 
 FLOP-counting conventions (documented so fixtures stay comparable):
-2 flops per MAC (the BENCH MFU convention, bench.py
-``RESNET50_FWD_FLOPS``); transcendentals count 1 flop/element like any
+2 flops per MAC (the MFU convention: ResNet-50's forward is 7.72 GFLOP
+an image, tests/test_cost_model.py); transcendentals count 1
+flop/element like any
 other elementwise op; ``scan`` bodies count once per iteration;
 ``while`` bodies count ``while_trips`` iterations (default 1, recorded
 as an assumption); ``cond`` takes the most expensive branch.

@@ -7,7 +7,7 @@ graphs. Two kinds of entries live here:
 
 * ``*-spec`` — the datasheet numbers (what the silicon promises);
 * ``bench-r05`` — a HISTORICAL entry: what an early round of this repo
-  read on a development device (BENCH_r05: 95.25 TFLOP/s matmul peak,
+  read on a development device (round 5: 95.25 TFLOP/s matmul peak,
   62.5 GB/s saxpy HBM, machine balance 1524 flop/B). It is still the
   default because the lint thresholds and their tests were calibrated
   against it; it does not describe a v5e chip. Name ``v5e-spec`` to
@@ -24,7 +24,7 @@ import os
 __all__ = ['DEVICE_SPECS', 'get_device_spec', 'machine_balance']
 
 DEVICE_SPECS = {
-    # historical: read in round 5 (BENCH_r05), kept as the default the
+    # historical: read in round 5, kept as the default the
     # lint thresholds were calibrated against
     'bench-r05': {
         'name': 'bench-r05',
@@ -32,8 +32,8 @@ DEVICE_SPECS = {
         'peak_int8_flops': 190.5e12,    # 2x bf16 (MXU int8 path)
         'hbm_bytes_s': 62.5e9,          # measured saxpy bandwidth
         'hbm_bytes': 16e9,
-        'source': 'BENCH_r05 measured (matmul_peak_bf16_8192, '
-                  'hbm_bandwidth_saxpy)',
+        'source': 'read in round 5 on an earlier development device '
+                  '(matmul_peak_bf16_8192, hbm_bandwidth_saxpy)',
     },
     # datasheet entries
     'v5e-spec': {

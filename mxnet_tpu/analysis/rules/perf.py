@@ -1,9 +1,9 @@
 """Roofline-driven performance lints over the analysis.costs pass.
 
 Four rules, all fed by the same cached :func:`costs.cost_of_graph`
-report — they turn BENCH_r05's aggregate observations (train MFU 0.106,
-int8 at 0.63x bf16, bandwidth at 7.6% of spec) into findings that point
-at equations:
+report — they turn aggregate observations (a low train MFU, int8
+slower than bf16, a small share of the HBM roofline) into findings that
+point at equations:
 
 ==========================  ==================================================
 rule                        catches
@@ -11,8 +11,9 @@ rule                        catches
 unfused-dequant             an int8 dequantize living as its own equation
                             chain next to a matmul instead of a fused
                             epilogue/prologue — the exact pattern behind
-                            int8 losing to bf16 (BENCH_r05 int8_speedup
-                            0.63; docs/quantization.md round-trip note)
+                            int8 losing to bf16 (0.63x on an earlier
+                            development device; docs/quantization.md
+                            round-trip note)
 bandwidth-bound-chain       a data-dependent run of elementwise/reduce
                             equations whose arithmetic intensity sits below
                             machine balance and which no ops/pallas fused
@@ -192,7 +193,7 @@ def unfused_dequant(graph, report, config):
                            '— three full HBM passes that a fused '
                            'requantize epilogue on the first matmul '
                            'would eliminate (the pattern behind int8 '
-                           'trailing bf16 in BENCH_r05)')
+                           'trailing bf16)')
                     pattern = 'dequant-requant-round-trip'
                 else:
                     msg = (f'int8 dequantize feeds a {dt} '
@@ -262,10 +263,9 @@ def chain_coverage(graph, config=None):
     ``bandwidth-bound-chain`` rule finds them, but chains attributed to
     a ``fused_kernel=True`` op count as covered instead of exempt.
     Returns (covered_bytes / total_chain_bytes, total_chain_bytes) —
-    (1.0, 0) for a graph with no qualifying chains. bench.py reports
-    this as ``fused_kernel_coverage`` so kernel regressions (a fused op
-    silently falling back to an unattributed chain) show up as a
-    coverage drop, not just throughput drift."""
+    (1.0, 0) for a graph with no qualifying chains. A kernel regression
+    (a fused op silently falling back to an unattributed chain) shows
+    up as a coverage drop, not just throughput drift."""
     config = config or {}
     cost = cost_of_graph(graph)
     balance = cost.machine_balance

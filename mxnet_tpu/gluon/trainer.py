@@ -112,10 +112,21 @@ class Trainer:
     def _init_kvstore(self):
         """Reference trainer.py:188 — decides kvstore type +
         update_on_kvstore. Here: multi-worker → dist_tpu_sync allreduce
-        (never server-side updates: there are no servers)."""
+        (never server-side updates: there are no servers).
+
+        As in the reference's ``_create_kvstore``, one context under a
+        store name without ``dist`` in it gets no kvstore: there is
+        nothing to aggregate, and a local store would keep a copy of
+        every weight that nothing reads. ``compression_params`` then
+        compress nothing, because nothing is exchanged. A ``KVStoreBase``
+        instance, a ``dist*`` name, several contexts or
+        ``update_on_kvstore=True`` get their store."""
         config = self._kvstore_params
         kv = config['kvstore']
-        if kv is None or kv == '' or not self._contexts:
+        one_device = (isinstance(kv, str) and 'dist' not in kv
+                      and len(self._contexts) == 1
+                      and config['update_on_kvstore'] is not True)
+        if kv is None or kv == '' or not self._contexts or one_device:
             self._kvstore = None
             self._update_on_kvstore = False
         else:

@@ -1,10 +1,10 @@
 """Int8 matmul with a fused dequantize epilogue — Pallas TPU kernel.
 
-The reason BENCH_r05 measured int8 inference at 0.63x bf16: the int32
-accumulator left the matmul, round-tripped HBM as f32 for the scale
-multiply and bias add, then round-tripped again for the downcast. This
-kernel keeps the epilogue where the accumulator already lives — VMEM:
-int8 x int8 -> int32 on the MXU (the int8 path the MXU natively runs at
+Why int8 inference read 0.63x bf16 on an earlier development device
+(95 TFLOP/s, 62.5 GB/s): the int32 accumulator left the matmul,
+round-tripped HBM as f32 for the scale multiply and bias add, then
+round-tripped again for the downcast. This kernel keeps the epilogue
+where the accumulator already lives — VMEM: int8 x int8 -> int32 on the MXU (the int8 path the MXU natively runs at
 2x bf16 throughput), then per-output-channel scale, bias, and the bf16
 downcast applied to the register-resident accumulator before the single
 HBM write. One read of x, one read of w, one write of out — the

@@ -168,7 +168,8 @@ class _QuantizedLayer(HybridBlock):
         # inter-layer activations leave in this dtype: bf16 halves the
         # HBM bytes between layers vs f32 — on a bandwidth-bound device
         # an f32-activation int8 net is SLOWER than the bf16 float net
-        # (r4 roofline analysis, docs/perf_resnet.md); the int32->float
+        # (shown on an earlier development device at 62.5 GB/s; not
+        # measured on the v5e); the int32->float
         # rescale still happens in f32 before the downcast
         self._act_dtype = jnp.dtype(activation_dtype)
         w = float_layer.weight.data()._data.astype(jnp.float32)

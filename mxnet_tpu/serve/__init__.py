@@ -21,7 +21,7 @@ Environment knobs: ``MXNET_SERVE_BUCKETS``, ``MXNET_SERVE_MAX_WAIT_US``,
 ``MXNET_SERVE_QUEUE_DEPTH``, ``MXNET_SERVE_DEADLINE_MS``,
 ``MXNET_SERVE_FAULT_SPEC``, ``MXNET_SERVE_PAGE_SIZE``,
 ``MXNET_SERVE_PAGES``, ``MXNET_SERVE_PREFILL_CHUNK``,
-``MXNET_SERVE_PREFIX_CACHE``, ``MXNET_SERVE_REPLICAS``,
+``MXNET_SERVE_PREFIX_CACHE``,
 ``MXNET_SERVE_DRAIN_S``, ``MXNET_SERVE_HEDGE_MS`` (docs/env_vars.md;
 the design docs are docs/serving.md and docs/deployment.md).
 """

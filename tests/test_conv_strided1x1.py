@@ -2,8 +2,8 @@
 
 ops/nn.py rewrites a 1x1 stride-s pad-0 conv as a stride-grid slice plus a
 stride-1 conv, so the VJP stays at the low resolution instead of XLA's
-full-resolution lhs-dilated expansion (docs/perf_resnet.md — the ResNet-50
-downsample data-gradients were 4x oversized). Reference parity target:
+full-resolution lhs-dilated expansion (the ResNet-50 downsample
+data-gradients were 4x oversized). Reference parity target:
 src/operator/nn/convolution.cc strided conv semantics.
 """
 import jax

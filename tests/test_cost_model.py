@@ -3,8 +3,8 @@ forms (dense, conv, adam, rms_norm), control-flow multipliers
 (scan x length, while_trips, cond max-branch), the Op.cost /
 fused_kernel hooks for Pallas kernels, donation-aware peak-HBM
 liveness against an independent reference walk, device-spec
-resolution, and the checked-in resnet50 fixture vs the BENCH
-analytical count (docs/static-analysis.md)."""
+resolution, and the checked-in resnet50 fixture and the ResNet-50 train
+step vs the analytical count (docs/static-analysis.md)."""
 
 import json
 import os
@@ -77,13 +77,51 @@ def test_rms_norm_xla_closed_form():
     assert rel_err(c.flops, 4 * n + 3 * rows) < 0.01, c.by_primitive
 
 
-def test_resnet50_fixture_matches_bench_analytical():
+# ResNet-50's forward pass at 224x224: 7.72 GFLOP an image, at 2 FLOP a
+# multiply-accumulate
+RESNET50_FWD_FLOPS = 7.72e9
+
+
+def test_resnet50_fixture_matches_analytical():
     # the checked-in perf_lint fixture (regenerated only on INTENDED
-    # graph changes) must stay within 10% of the BENCH MFU analytical
-    # count: RESNET50_FWD_FLOPS = 7.72e9 per image at 224x224
+    # graph changes) must stay within 10% of the analytical count
     with open(os.path.join(FIXTURE_DIR, 'resnet50.json')) as f:
         fixture = json.load(f)
-    assert rel_err(fixture['flops'], 7.72e9) < 0.10
+    assert rel_err(fixture['flops'], RESNET50_FWD_FLOPS) < 0.10
+
+
+def test_resnet50_train_step_cost_matches_analytical():
+    # cost_report over the ResNet-50 train step itself (forward, backward
+    # and an SGD update): within 10% of 3 x the forward count an image,
+    # the denominator of a training MFU
+    from mxnet_tpu.gluon.model_zoo import vision
+
+    B = 2
+    with mx.cpu():
+        net = vision.resnet50_v1()
+        net.initialize()
+        net(mx.np.ones((1, 3, 224, 224)))
+        x0 = mx.np.ones((B, 3, 224, 224))
+        pure, in_raws, params, aux = net.pure_function(x0, train=True)
+    labels = jnp.arange(B, dtype=jnp.int32) % 1000
+    key = jax.random.PRNGKey(0)
+
+    def train_step(x, ps, aux_s):
+        def loss_of(ps_):
+            outs, new_aux = pure(key, (x,), ps_, aux_s)
+            logp = jax.nn.log_softmax(outs[0])
+            return -logp[jnp.arange(B), labels].mean(), new_aux
+
+        (loss, new_aux), grads = jax.value_and_grad(
+            loss_of, has_aux=True)(ps)
+        new_ps = jax.tree.map(lambda w, g: w - 0.05 * g, ps, grads)
+        return loss, new_ps, new_aux
+
+    c = analysis.cost_report(train_step, in_raws[0], params, tuple(aux),
+                             name='resnet50-train-step')
+    assert rel_err(c.flops, 3 * RESNET50_FWD_FLOPS * B) < 0.10, c.flops
+    assert c.peak_hbm_bytes > 0
+    assert 0 < c.mfu_bound <= 1.0
 
 
 # ------------------------------------------------- Pallas Op.cost hooks

@@ -536,3 +536,99 @@ def test_elastic_snapshot_of_a_sharded_param_outlives_the_step(
             np.testing.assert_array_equal(saved[n], q.data().asnumpy())
         assert trainer.optimizer.num_update == 2
         et.close()
+
+
+# ------------------------------------- one device in one process: no kvstore
+def _odd_net(ctx=None, shape=(7, 13)):
+    net = nn.Dense(shape[0], in_units=shape[1])
+    net.initialize(ctx=ctx)
+    return net
+
+
+def _n_live(shape):
+    import gc
+    import jax
+    gc.collect()
+    return sum(1 for a in jax.live_arrays()
+               if a.shape == shape and not a.is_deleted())
+
+
+@pytest.mark.parametrize('kv', ['device', 'local', 'nccl'])
+def test_one_device_trainer_makes_no_kvstore(kv):
+    """The reference's rule (``_create_kvstore``): one context under a
+    store name without ``dist`` gets no kvstore, and the weights are
+    those of ``kvstore=None``."""
+    net, ref = _odd_net(), _odd_net()
+    for p, q in zip(net.collect_params().values(),
+                    ref.collect_params().values()):
+        q.set_data(p.data())
+    kwargs = {} if kv == 'device' else {'kvstore': kv}   # the default
+    trainer = gluon.Trainer(net.collect_params(), 'sgd',
+                            {'learning_rate': 0.1}, **kwargs)
+    none = gluon.Trainer(ref.collect_params(), 'sgd',
+                         {'learning_rate': 0.1}, kvstore=None)
+    x = mx.np.array(np.random.randn(4, 13).astype('float32'))
+    for n_, t_ in ((net, trainer), (ref, none)):
+        _steps(n_, t_, 3, x=x)
+    assert trainer._kv_initialized
+    assert trainer._kvstore is None
+    assert trainer._update_on_kvstore is False
+    for p, q in zip(net.collect_params().values(),
+                    ref.collect_params().values()):
+        np.testing.assert_array_equal(p.data().asnumpy(),
+                                      q.data().asnumpy())
+
+
+def test_one_device_trainer_holds_no_second_copy_of_a_weight():
+    """The first step() leaves as many arrays of the weight's shape alive
+    as it found (the weight and its gradient): no store's copy beside
+    them. The shape is one nothing else in the process holds."""
+    shape = (11, 17)
+    net = _odd_net(shape=shape)
+    trainer = gluon.Trainer(net.collect_params(), 'sgd',
+                            {'learning_rate': 0.1})
+    x = mx.np.ones((4, shape[1]))
+    with autograd.record():
+        loss = (net(x) ** 2).sum()
+    loss.backward()
+    del loss
+    before = _n_live(shape)
+    trainer.step(4)
+    assert before == 2
+    assert _n_live(shape) == before
+
+
+@pytest.mark.parametrize('case', ['update_on_kvstore', 'instance',
+                                  'dist_sync', 'two_contexts'])
+def test_a_kvstore_is_kept_where_one_is_asked_for(case):
+    """``update_on_kvstore=True``, a ``KVStoreBase`` instance, a ``dist*``
+    name or several contexts get the store they got before."""
+    from mxnet_tpu.kvstore import KVStoreLocal, KVStoreTPUSync
+    kwargs = {
+        'update_on_kvstore': dict(kvstore='local', update_on_kvstore=True),
+        'instance': dict(kvstore=mx.kvstore.create('local')),
+        'dist_sync': dict(kvstore='dist_sync'),
+        'two_contexts': {},
+    }[case]
+    ctxs = [mx.cpu(0), mx.cpu(1)] if case == 'two_contexts' else [mx.cpu(0)]
+    net = _odd_net(ctx=ctxs)
+    trainer = gluon.Trainer(net.collect_params(), 'sgd',
+                            {'learning_rate': 0.1}, **kwargs)
+    w0 = net.weight.data(ctxs[0]).asnumpy().copy()
+    xs = [mx.np.ones((2, 13), ctx=c) * (i + 1) for i, c in enumerate(ctxs)]
+    with autograd.record():
+        losses = [net(x).sum() for x in xs]
+    for l in losses:
+        l.backward()
+    trainer.step(2 * len(ctxs))
+    want = KVStoreTPUSync if case == 'dist_sync' else KVStoreLocal
+    assert isinstance(trainer._kvstore, want), type(trainer._kvstore)
+    if case == 'instance':
+        assert trainer._kvstore is kwargs['kvstore']
+    assert trainer._update_on_kvstore is (case == 'update_on_kvstore')
+    got = [d.asnumpy() for d in net.weight.list_data()]
+    assert not np.allclose(got[0], w0)
+    # every replica holds the same new weight: the store's pushpull
+    # handed each the merged gradient
+    for g in got[1:]:
+        np.testing.assert_array_equal(got[0], g)

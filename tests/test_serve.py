@@ -331,53 +331,6 @@ def test_profiler_serving_section_and_stats():
 
 
 # ----------------------------------------------------- tier-1 subprocesses
-def test_serve_bench_smoke():
-    out = os.path.join('/tmp', f'serve_bench_smoke_{os.getpid()}.json')
-    env = dict(os.environ)
-    env['JAX_PLATFORMS'] = 'cpu'  # conftest leaves it '' in-proc; '' defeats setdefault
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, 'tools', 'serve_bench.py'),
-         '--smoke', '--out', out],
-        capture_output=True, text=True, timeout=480, cwd=REPO, env=env)
-    assert r.returncode == 0, r.stdout[-4000:] + r.stderr[-4000:]
-    import json
-    with open(out) as f:
-        doc = json.load(f)
-    for section in ('resnet', 'llama'):
-        assert section in doc, doc
-        assert doc[section]['completed'] > 0
-        assert doc[section]['recompiles'] == 0
-        assert 'latency_ms' in doc[section]
-    os.unlink(out)
-
-
-def test_serve_bench_replicated_smoke():
-    """ISSUE 12 tier-1 smoke: the replicated bench (router over 2
-    replicas, chaos phase included) completes with ZERO failed
-    requests, ejects and re-admits the killed replica, and states the
-    chaos p99 bound in the artifact."""
-    import json
-    out = os.path.join('/tmp', f'serve_bench_repl_{os.getpid()}.json')
-    env = dict(os.environ)
-    env['JAX_PLATFORMS'] = 'cpu'  # conftest leaves it '' in-proc; '' defeats setdefault
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, 'tools', 'serve_bench.py'),
-         '--smoke', '--replicas', '2', '--out', out],
-        capture_output=True, text=True, timeout=480, cwd=REPO, env=env)
-    assert r.returncode == 0, r.stdout[-4000:] + r.stderr[-4000:]
-    with open(out) as f:
-        doc = json.load(f)
-    rep = doc['replicated']
-    assert rep['replicas'] == 2
-    assert rep['recompiles'] == 0
-    for phase in ('single', 'replicated', 'chaos'):
-        assert rep[phase]['failed'] == 0, rep[phase]
-    assert rep['chaos']['injected']['crash'] == 1
-    assert rep['chaos']['readmitted'] is True
-    assert 'p99_bound' in rep
-    os.unlink(out)
-
-
 def test_threaded_serve_clean_under_race_check():
     """Soak rerun (test_race_ci.py pattern): the threaded serve tests
     must pass — and assert_clean() — with the dynamic race checker

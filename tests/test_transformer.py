@@ -130,7 +130,7 @@ def test_bert_hybridize_matches_eager():
 
 
 def test_bert_hybridized_train_step():
-    """Full hybridized train step (the bench.py path) must work."""
+    """Full hybridized train step must work."""
     net = _tiny_bert(use_classifier=False)
     net.initialize()
     ids = mx.np.zeros((2, 8), dtype='int32')
