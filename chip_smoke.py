@@ -31,7 +31,8 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 IR_DIR = os.path.join(HERE, '.chip_smoke_ir')      # git-ignored
 COMPILE_EVENT = '/jax/core/compile/backend_compile_duration'
 CACHE_HIT_EVENT = '/jax/compilation_cache/cache_hits'
-KERNELS = ('mx_flash_attention', 'mx_fused_layer_norm', 'mx_adam_step')
+KERNELS = ('mx_flash_attention', 'mx_flash_attention_bwd',
+           'mx_fused_layer_norm', 'mx_adam_step')
 
 
 @dataclasses.dataclass
@@ -141,6 +142,29 @@ def one_step(net, trainer, loss_fn, batch):
     return raw, out._data, time.perf_counter() - t0
 
 
+def kernel_sites(text):
+    """Per kernel name, how many tpu_custom_call sites a lowered program
+    (its MLIR text) runs from @main: a site in a private function counts
+    once for every call of that function (the flash kernels' calls are
+    jitted on their own, so a model's layers share one function)."""
+    own, calls, name = {}, {}, None
+    for line in text.splitlines():
+        start = re.match(r'\s*func\.func \w+ @"?([\w.$-]+)', line)
+        if start:
+            name = start.group(1)
+            own[name], calls[name] = dict.fromkeys(KERNELS, 0), []
+        elif name is not None:
+            calls[name] += re.findall(r'(?<![\w.])call @"?([\w.$-]+)', line)
+            if '@tpu_custom_call' in line:
+                for k in KERNELS:
+                    if re.search(rf'\b{k}\b', line):
+                        own[name][k] += 1
+
+    def total(fn, k):
+        return own[fn][k] + sum(total(c, k) for c in calls[fn] if c in own)
+    return {k: total('main', k) for k in KERNELS} if 'main' in own else {}
+
+
 def dumped_kernels():
     """Per kernel name, how many tpu_custom_call sites each program that
     JAX lowered in this process holds (read from the IR dump)."""
@@ -152,12 +176,9 @@ def dumped_kernels():
             continue
         prog = re.sub(r'^jax_ir\d+_|_compile\.mlir$', '',
                       os.path.basename(path))
-        for line in text.splitlines():
-            if '@tpu_custom_call' not in line:
-                continue
-            for k in KERNELS:
-                if re.search(rf'\b{k}\b', line):
-                    found[k][prog] = found[k].get(prog, 0) + 1
+        for k, n in kernel_sites(text).items():
+            if n:
+                found[k][prog] = found[k].get(prog, 0) + n
     return found
 
 
@@ -231,6 +252,7 @@ def train_phase(cfg, ctx, compiles):
         n_adam = sum(fused_optimizer._tileable(p.data()._data)
                      for p in params.values())
         want = {'mx_flash_attention': cfg.layers,
+                'mx_flash_attention_bwd': cfg.layers,
                 'mx_fused_layer_norm': 2 * cfg.layers + 1,
                 'mx_adam_step': n_adam}
         for k, n in want.items():

@@ -72,32 +72,41 @@ def interleaved_matmul_encdec_valatt(keys_values, attention, heads):
 
 
 def _attention_pallas_cost(eqn):
-    """Analytical cost for the fused flash-attention kernel
-    (mx.analysis.costs): two matmuls (QK^T and PV) over the full score
-    grid, 4·B·H·T·S·d flops. Causal kernels skip ~half the blocks; this
-    prices the dense upper bound since masking isn't visible in the eqn.
-    Non-pallas equations return None so the primitive table handles the
-    XLA fallback."""
+    """Analytical cost for the flash-attention kernels
+    (mx.analysis.costs), by the operands each takes. Forward
+    (q, k, v): QK^T over d and PV over v's width, 2·BH·T·S·(d + dv)
+    flops, which is 4·B·H·T·S·d at one width. Backward
+    (q, k, v, do, lse, delta): the five products of a tile, the scores
+    again, dP and dV over dv, dK and dQ over d, 2·BH·T·S·(3d + 2dv).
+    The sum is the same whether the heads are a leading axis or packed
+    along the last one (it is linear in the widths). Causal kernels skip
+    ~half the blocks; this prices the dense upper bound since masking
+    isn't visible in the eqn. Non-pallas equations return None so the
+    primitive table handles the XLA fallback."""
     if eqn.primitive.name != 'pallas_call':
         return None
-    q, k = eqn.invars[0].aval, eqn.invars[1].aval
+    q, k, v = (x.aval for x in eqn.invars[:3])
     t, d = q.shape[-2], q.shape[-1]
-    s = k.shape[-2]
+    s, dv = k.shape[-2], v.shape[-1]
     bh = 1
     for n in q.shape[:-2]:
         bh *= n
-    return 4 * bh * t * s * d
+    backward = len(eqn.invars) == 6
+    return 2 * bh * t * s * (3 * d + 2 * dv if backward else d + dv)
 
 
 @register('flash_attention', f32_only=True, fused_kernel=True,
           cost=_attention_pallas_cost)
-def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=128,
-                    block_k=128):
-    """Blockwise fused attention (Pallas on TPU, XLA fallback elsewhere).
+def flash_attention(q, k, v, sm_scale=None, causal=False, block_q=None,
+                    block_k=None):
+    """Blockwise fused attention (Pallas on TPU, forward and backward;
+    XLA fallback elsewhere).
 
-    q: (..., T, d); k/v: (..., S, d). New TPU-native capability — the
-    reference's closest assets are the interleaved matmul kernels above
-    (transformer.cc:650-826), which materialize the full score matrix.
+    q: (..., T, d); k: (..., S, d); v: (..., S, dv). ``block_q`` and
+    ``block_k`` bound the tile; by default it is chosen from the shape.
+    New TPU-native capability — the reference's closest assets are the
+    interleaved matmul kernels above (transformer.cc:650-826), which
+    materialize the full score matrix.
     """
     from .pallas.flash_attention import flash_attention as _fa
     return _fa(q, k, v, sm_scale=sm_scale, causal=causal,
@@ -174,11 +183,12 @@ def multi_head_attention(q, k, v, num_heads, mask=None, dropout_p=0.0,
 
     ``v`` may have another head width than ``q`` and ``k``
     (``v.shape[-1] / num_heads``; latent attention: 192 for the scores,
-    128 for the values): the narrower side is zero-padded to the wider,
-    which changes neither a score nor a kept output column, every branch
-    runs as it does for one width, and the output is (batch, seq,
-    num_heads x v's head width). ``sm_scale`` is the score scale;
-    default 1/sqrt(q's head width)."""
+    128 for the values): the flash path and the dropout path take each
+    at its own width; for jax.nn.dot_product_attention the narrower side
+    is zero-padded to the wider, which changes neither a score nor a kept
+    output column. The output is (batch, seq, num_heads x v's head
+    width). ``sm_scale`` is the score scale; default 1/sqrt(q's head
+    width)."""
     # one scope round every branch: a device operation of a profile is
     # put down to attention whichever implementation ran
     with jax.named_scope('mx.attention'):
@@ -188,35 +198,27 @@ def multi_head_attention(q, k, v, num_heads, mask=None, dropout_p=0.0,
 
 def _attention(q, k, v, num_heads, mask, dropout_p, causal, key,
                sm_scale=None):
+    if mask is None and dropout_p == 0.0:
+        # the kernels take the heads packed along the last axis, as q, k
+        # and v come; only the XLA branch behind the same gate splits them
+        from .pallas.flash_attention import flash_attention_packed
+        return flash_attention_packed(q, k, v, num_heads,
+                                      sm_scale=sm_scale, causal=causal)
     b, sq, e = q.shape
     hd = e // num_heads
     vd = v.shape[-1] // num_heads
     qh = q.reshape(b, sq, num_heads, hd)
     kh = k.reshape(b, k.shape[1], num_heads, hd)
     vh = v.reshape(b, v.shape[1], num_heads, vd)
-    if vd != hd:
-        if sm_scale is None:
-            sm_scale = hd ** -0.5       # of the width before the padding
-        wide = max(hd, vd)
-        pad = lambda a: a if a.shape[-1] == wide else jnp.pad(
-            a, ((0, 0),) * 3 + ((0, wide - a.shape[-1]),))
-        qh, kh, vh = pad(qh), pad(kh), pad(vh)
     out = _attention_heads(qh, kh, vh, mask, dropout_p, causal, key,
                            sm_scale)
-    if vd != hd:
-        out = out[..., :vd]
     return out.reshape(b, sq, num_heads * vd)
 
 
 def _attention_heads(qh, kh, vh, mask, dropout_p, causal, key, sm_scale):
-    """(B, T, H, d) x (B, S, H, d) x (B, S, H, d) -> (B, T, H, d)."""
+    """The masked and the dropout branch:
+    (B, T, H, d) x (B, S, H, d) x (B, S, H, dv) -> (B, T, H, dv)."""
     sq, sk = qh.shape[1], kh.shape[1]
-    if mask is None and dropout_p == 0.0:
-        from .pallas.flash_attention import flash_attention as _fa
-        out = _fa(qh.transpose(0, 2, 1, 3), kh.transpose(0, 2, 1, 3),
-                  vh.transpose(0, 2, 1, 3), sm_scale=sm_scale,
-                  causal=causal)
-        return out.transpose(0, 2, 1, 3)
     if causal:
         # explicit bottom-right-aligned causal mask so this branch agrees
         # with the flash path when T != S (decode with KV cache)
@@ -237,8 +239,17 @@ def _attention_heads(qh, kh, vh, mask, dropout_p, causal, key, sm_scale):
         p = jnp.where(keep, p / (1.0 - dropout_p), 0.0)
         return jnp.einsum('bhqk,bkhd->bqhd', p,
                           vh.astype(jnp.float32)).astype(qh.dtype)
-    return jax.nn.dot_product_attention(qh, kh, vh, mask=mask,
-                                        scale=sm_scale)
+    hd, vd = qh.shape[-1], vh.shape[-1]
+    if vd != hd:
+        if sm_scale is None:
+            sm_scale = hd ** -0.5       # of the width before the padding
+        wide = max(hd, vd)
+        pad = lambda a: a if a.shape[-1] == wide else jnp.pad(
+            a, ((0, 0),) * 3 + ((0, wide - a.shape[-1]),))
+        qh, kh, vh = pad(qh), pad(kh), pad(vh)
+    out = jax.nn.dot_product_attention(qh, kh, vh, mask=mask,
+                                       scale=sm_scale)
+    return out if vd == hd else out[..., :vd]
 
 
 # ----------------------------------------------------------- detection utils

@@ -75,6 +75,16 @@ def _compile(fn, *shapes):
     return text.count('custom_call_target="tpu_custom_call"')
 
 
+def _assert_the_kernel_pair_alone(fn, seq, *shapes):
+    """The compiled gradient of an attention call holds the forward and
+    the backward kernel, no transpose round them and no (seq, seq)
+    tensor."""
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert ' transpose(' not in text
+    assert f'{seq},{seq}]' not in text
+
+
 def test_shapes_cover_bert_base():
     """ADAM_SHAPES is what the model has: one encoder layer carries every
     distinct shape of twelve."""
@@ -90,14 +100,73 @@ def test_shapes_cover_bert_base():
 
 @pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16])
 def test_flash_attention_fwd_bwd_compiles(one_chip, on_tpu, dtype):
-    qkv = jax.ShapeDtypeStruct((BATCH, HEADS, SEQ, UNITS // HEADS), dtype,
+    """Head-major operands a whole tile wide (Llama's 128), every
+    (batch, head) a group of its own: two custom calls, the forward
+    kernel, which saves each row's logsumexp, and the backward kernel
+    that rebuilds the tiles from it."""
+    qkv = jax.ShapeDtypeStruct((8, 8, 512, 128), dtype, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_mod.flash_attention(q, k, v, causal=True)
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    assert _compile(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv) == 2
+
+
+def test_flash_attention_under_a_tile_wide_takes_xla(one_chip, on_tpu):
+    """Head-major operands 64 wide would move half-empty tiles (117 GB/s
+    and slower than XLA on the chip, PERF.md §6 PR 34): the gate sends
+    them to XLA, and no custom call is compiled."""
+    qkv = jax.ShapeDtypeStruct((BATCH, HEADS, SEQ, UNITS // HEADS),
+                               jnp.float32, sharding=one_chip)
+
+    def loss(q, k, v):
+        return (flash_mod.flash_attention(q, k, v) ** 2).sum()
+
+    assert _compile(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv) == 0
+
+
+def test_smoke_counts_a_kernel_site_for_every_layer(one_chip, on_tpu):
+    """chip_smoke.py wants a forward and a backward kernel site a layer
+    in one program of the step. The two kernel calls are jitted on their
+    own, so the lowered program holds each once, in a private function
+    that every layer calls: the smoke's count follows the calls."""
+    from chip_smoke import kernel_sites
+    from mxnet_tpu.ops.contrib import multi_head_attention
+    x = jax.ShapeDtypeStruct((BATCH, SEQ, UNITS), jnp.float32,
+                             sharding=one_chip)
+
+    def loss(q, k, v):
+        for _ in range(3):
+            q = multi_head_attention(q, k, v, HEADS)
+        return (q ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(x, x, x)\
+        .as_text()
+    assert text.count('@tpu_custom_call') == 2
+    sites = kernel_sites(text)
+    assert sites['mx_flash_attention'] == 3
+    assert sites['mx_flash_attention_bwd'] == 3
+
+
+@pytest.mark.parametrize('batch, seq', [(BATCH, SEQ), (8, 512)],
+                         ids=['b32_s128', 'b8_s512'])
+def test_multi_head_attention_fwd_bwd_compiles(one_chip, on_tpu, batch,
+                                               seq):
+    """As BERT calls it, (batch, seq, 12 x 64) with no mask, at the smoke's
+    shape and at bert_base.pretrain_b8_s512's own: the kernels take two
+    heads a grid step, packed along the lanes as the projections leave
+    them. Two custom calls, no transpose round them, and no (T, S) tensor
+    in the program."""
+    from mxnet_tpu.ops.contrib import multi_head_attention
+    qkv = jax.ShapeDtypeStruct((batch, seq, UNITS), jnp.float32,
                                sharding=one_chip)
 
     def loss(q, k, v):
-        out = flash_mod.flash_attention(q, k, v)
-        return (out.astype(jnp.float32) ** 2).sum()
+        return (multi_head_attention(q, k, v, HEADS) ** 2).sum()
 
-    assert _compile(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv) == 1
+    _assert_the_kernel_pair_alone(jax.grad(loss, argnums=(0, 1, 2)), seq,
+                                  qkv, qkv, qkv)
 
 
 @pytest.mark.parametrize('rows', [BATCH * SEQ, BATCH, 100])
@@ -184,8 +253,10 @@ MLA_ADAM_SHAPES = [
 
 
 def test_latent_attention_fwd_bwd_compiles(one_chip, on_tpu):
-    """q and k 192 wide, v 128: the flash forward kernel takes v
-    zero-padded to 192, one custom call; the backward is XLA's."""
+    """kanana_2_30b_a3b.pretrain_b2_s1024's own shape: q and k 192 wide,
+    v 128, causal, float32. The kernels take two heads a grid step (384
+    and 256 lanes) and v at its own width: two custom calls, forward and
+    backward, no transpose round them and no (1024, 1024) tensor."""
     from mxnet_tpu.ops.contrib import multi_head_attention
     qk = jax.ShapeDtypeStruct((MLA_ROWS, MLA_SEQ, MLA_HEADS * 192),
                               jnp.float32, sharding=one_chip)
@@ -198,7 +269,8 @@ def test_latent_attention_fwd_bwd_compiles(one_chip, on_tpu):
         assert out.shape == (MLA_ROWS, MLA_SEQ, MLA_HEADS * 128)
         return (out ** 2).sum()
 
-    assert _compile(jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v) == 1
+    _assert_the_kernel_pair_alone(jax.grad(loss, argnums=(0, 1, 2)),
+                                  MLA_SEQ, qk, qk, v)
 
 
 @pytest.mark.parametrize('units', [MLA_UNITS, 512])

@@ -154,6 +154,40 @@ def test_flash_attention_pallas_cost_hook():
                     [(b, h, t, d)])
     assert op.cost(eqn) == 4 * b * h * t * s * d
     assert get_op('multi_head_attention').fused_kernel
+    # a value head of its own width: QK^T over d, PV over dv
+    dv = 32
+    eqn = _stub_eqn('pallas_call',
+                    [(b * h, t, d), (b * h, s, d), (b * h, s, dv)],
+                    [(b * h, t, dv), (b * h, 1, t)])
+    assert op.cost(eqn) == 2 * b * h * t * s * (d + dv)
+    # the heads packed along the last axis, as the kernels take them
+    eqn = _stub_eqn('pallas_call',
+                    [(b, t, h * d), (b, s, h * d), (b, s, h * dv)],
+                    [(b, t, h * dv), (b, h // 2, 2, t)])
+    assert op.cost(eqn) == 2 * b * h * t * s * (d + dv)
+
+
+def test_flash_attention_backward_kernel_has_its_own_price():
+    """mx_flash_attention_bwd takes (q, k, v, do, lse, delta): the scores
+    again, dP and dV over v's width, dK and dQ over the scores' width —
+    five products a tile where the forward has two."""
+    from mxnet_tpu.ops.registry import get_op
+    bh, t, s, d, dv = 8, 16, 32, 64, 32
+    eqn = _stub_eqn('pallas_call',
+                    [(bh, t, d), (bh, s, d), (bh, s, dv), (bh, t, dv),
+                     (bh, 1, t), (bh, 1, t)],
+                    [(bh, t, d), (bh, s, d), (bh, s, dv)])
+    for name in ('flash_attention', 'multi_head_attention'):
+        assert get_op(name).cost(eqn) == \
+            2 * bh * t * s * (3 * d + 2 * dv)
+    # at one width: five products to the forward's two
+    same = _stub_eqn('pallas_call',
+                     [(bh, t, d), (bh, s, d), (bh, s, d), (bh, t, d),
+                      (bh, 1, t), (bh, 1, t)], [(bh, t, d)] * 3)
+    fwd = _stub_eqn('pallas_call', [(bh, t, d), (bh, s, d), (bh, s, d)],
+                    [(bh, t, d)])
+    cost = get_op('flash_attention').cost
+    assert 2 * cost(same) == 5 * cost(fwd)
 
 
 # ------------------------------------------------ control-flow multipliers

@@ -309,6 +309,26 @@ def test_int8_matmul_blocked_k_accumulation():
     assert bool((full == split).all())
 
 
+@pytest.mark.parametrize('m', [288, 320, 352, 416])
+def test_int8_matmul_rows_with_no_128_multiple_divisor(m):
+    """``use_pallas`` admits any row count that is a multiple of 32; the
+    block is then the largest divisor up to 256 (144, 160, 176, 208),
+    never 0 (flash attention's sequence rule, which gives 0 here, is not
+    this kernel's)."""
+    from mxnet_tpu.ops.pallas.flash_attention import _choose_block
+    bm = _choose_block(m, 256)
+    assert bm > 128 and m % bm == 0
+    rng = onp.random.RandomState(m)
+    x = jnp.asarray(rng.randint(-127, 128, (m, 128)), jnp.int8)
+    w = jnp.asarray(rng.randint(-127, 128, (128, 128)), jnp.int8)
+    s = jnp.asarray(rng.uniform(1e-3, 2e-2, (128,)), jnp.float32)
+    from mxnet_tpu.ops.quantization_ops import quantized_dense
+    ref = quantized_dense(x, w, s, None, out_dtype=jnp.float32)
+    out = int8_matmul.int8_matmul(x, w, s, None, jnp.float32,
+                                  interpret=True)
+    assert bool(jnp.allclose(out, ref, rtol=1e-6, atol=1e-5))
+
+
 def test_int8_matmul_3d_activations():
     rng = onp.random.RandomState(2)
     x = jnp.asarray(rng.randint(-127, 128, (4, 16, 256)), jnp.int8)
