@@ -249,7 +249,9 @@ def train_phase(cfg, ctx, compiles):
         for d in raw.devices():
             check(d.platform == 'tpu', f'loss lives on {d}')
         kernels = dumped_kernels()
-        n_adam = sum(fused_optimizer._tileable(p.data()._data)
+        # by the update's own gate, asked here as the Trainer's trace
+        # asks it: on the chip, outside a mesh
+        n_adam = sum(fused_optimizer.use_pallas(p.data()._data)
                      for p in params.values())
         want = {'mx_flash_attention': cfg.layers,
                 'mx_flash_attention_bwd': cfg.layers,

@@ -35,16 +35,18 @@ def _prep(grad, weight, rescale_grad, clip_gradient, wd):
     return _rescale_clip(grad, rescale_grad, clip_gradient) + wd * weight
 
 
-# ----------------------------------------------------- fused Pallas updates
-# (docs/kernels.md) The ops the in-repo Optimizer.step actually calls.
-# On TPU with lane-tileable f32 operands they lower to one pallas_call
-# (ops/pallas/fused_optimizer.py) with param/slot buffers aliased in
-# place; elsewhere they fall back to XLA math kept line-for-line
-# identical to the historical Adam.step / SGD.step, so numerics are
-# unchanged on every platform. Registered ``fused_kernel=True`` so the
-# bandwidth-bound-chain lint treats the update as already fused, and
-# with a closed-form ``cost=`` so the roofline model can price the
-# opaque pallas_call.
+# ------------------------------------------------------------ fused updates
+# (docs/kernels.md) The ops the in-repo Optimizer.step actually calls:
+# one Adam or SGD-momentum step of one leaf, in the leaf's own layout,
+# which XLA fuses into one pass over the operands (28 bytes a float32
+# parameter under Adam). Behind ``use_pallas`` each has a Pallas kernel
+# of the same equations (ops/pallas/fused_optimizer.py); since PR 35 the
+# gate is closed, because XLA's fusion measured as fast on the chip
+# (PERF.md §6), and every platform runs the math below. Registered
+# ``fused_kernel=True`` so the bandwidth-bound-chain lint treats the
+# update as already fused, and with a closed-form ``cost=`` so the
+# roofline model can price the opaque pallas_call where a caller takes
+# the kernel.
 
 def _elementwise_pallas_cost(flops_per_elem):
     def cost(eqn):
@@ -59,7 +61,7 @@ def _elementwise_pallas_cost(flops_per_elem):
 _ADAM_FLOPS_PER_ELEM = 18
 _SGD_MOM_FLOPS_PER_ELEM = 7
 # round both sides of the gate: a profile's device operations are put
-# down to the optimizer step whether the kernel or XLA ran it
+# down to the optimizer step whichever side ran it
 _STEP_SCOPE = 'mx.optimizer_step'
 
 

@@ -13,6 +13,8 @@ and keeps it until it exits.
 """
 
 import importlib
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -66,7 +68,7 @@ def one_chip(topo):
 def on_tpu(monkeypatch):
     """The dispatch gates ask jax.devices(), which is the CPU here: steer
     them onto their Pallas branch, as the chip would."""
-    for mod in (flash_mod, norms_mod, opt_mod):
+    for mod in (flash_mod, norms_mod):
         monkeypatch.setattr(mod, '_on_tpu', lambda: True)
 
 
@@ -88,12 +90,12 @@ def _assert_the_kernel_pair_alone(fn, seq, *shapes):
 def test_shapes_cover_bert_base():
     """ADAM_SHAPES is what the model has: one encoder layer carries every
     distinct shape of twelve."""
-    import numpy as np
     import mxnet_tpu as mx
     from chip_smoke import Config, build
     net, _, _, _ = build(Config(layers=1, batch=2, seq=8), mx.cpu(0))
     shapes = {tuple(p.shape) for p in net.collect_params().values()}
-    admitted = {s for s in shapes if np.prod(s) % 128 == 0}
+    admitted = {s for s in shapes if opt_mod._tileable(
+        jax.ShapeDtypeStruct(s, jnp.float32))}
     assert admitted == set(ADAM_SHAPES)
     assert shapes - admitted == {(2,)}          # the head's bias: XLA
 
@@ -191,15 +193,57 @@ def _opt_shapes(shape, one_chip):
     return w, lr, t
 
 
+def _adam(w, m, v, g, lr, wd, t):
+    return opt_mod.adam_step(w, g, m, v, lr, wd, t, beta1=0.9,
+                             beta2=0.999, epsilon=1e-8)
+
+
+def _sgd_mom(w, mom, g, lr, wd):
+    return opt_mod.sgd_mom_step(w, g, mom, lr, wd, momentum=0.9)
+
+
+def _compiled_update(kind, shape, one_chip):
+    """The update of one leaf as Trainer._fused_program compiles it: the
+    weight and its slots donated, the gradient not."""
+    w, lr, t = _opt_shapes(shape, one_chip)
+    if kind == 'adam':
+        return w, jax.jit(_adam, donate_argnums=(0, 1, 2)).lower(
+            w, w, w, w, lr, lr, t).compile()
+    return w, jax.jit(_sgd_mom, donate_argnums=(0, 1)).lower(
+        w, w, w, lr, lr).compile()
+
+
+# an instruction that moves data, with the shape it produces
+_MOVES = re.compile(r'= f32\[([\d,]*)\]\S* (reshape|copy|transpose)\(')
+
+
+def _leaf_moves(text, size):
+    """The instructions of a compiled program that relayout an f32 array
+    of ``size`` elements (the scalars' copies are not of a leaf's size)."""
+    return [(op, dims) for dims, op in _MOVES.findall(text)
+            if math.prod(int(d) for d in dims.split(',') if d) == size]
+
+
+def _assert_the_kernel_alone(kind, shape, one_chip):
+    """One custom call, every weight and slot written over its donated
+    buffer, and no relayout of the leaf round the kernel: under the
+    TPU's (8, 128) tiling a reshape of a leaf to (n, 128) rows and back
+    is a read and a write of all of it (seven of them were 56 of the
+    update's 84 bytes a parameter, PERF.md §6 PR 35). Bitcasts move
+    nothing; the scalars' copies are not of the leaf's size."""
+    w, compiled = _compiled_update(kind, shape, one_chip)
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert not _leaf_moves(text, w.size)
+    slots = 3 if kind == 'adam' else 2
+    assert compiled.memory_analysis().alias_size_in_bytes >= \
+        slots * 4 * w.size
+
+
 @pytest.mark.parametrize('shape', ADAM_SHAPES, ids=str)
 def test_adam_step_compiles(one_chip, shape):
     w, lr, t = _opt_shapes(shape, one_chip)
-
-    def step(w, g, m, v, lr, wd, t):
-        return opt_mod.adam_step(w, g, m, v, lr, wd, t, beta1=0.9,
-                                 beta2=0.999, epsilon=1e-8)
-
-    assert _compile(step, w, w, w, w, lr, lr, t) == 1
+    assert _compile(_adam, w, w, w, w, lr, lr, t) == 1
 
 
 @pytest.mark.parametrize('shape', [(UNITS,), (UNITS, HIDDEN),
@@ -207,17 +251,10 @@ def test_adam_step_compiles(one_chip, shape):
 def test_adam_step_writes_over_its_donated_operands(one_chip, shape):
     """What Trainer._fused_program donates: with w, m and v donated the
     chip's compiler aliases each output onto its operand, through the
-    kernel's own input_output_aliases and the reshapes round it."""
+    kernel's own input_output_aliases and the bitcasts round it."""
     from mxnet_tpu.analysis.rules.donation import \
         parse_input_output_aliases
-    w, lr, t = _opt_shapes(shape, one_chip)
-
-    def step(w, m, v, g, lr, wd, t):
-        return opt_mod.adam_step(w, g, m, v, lr, wd, t, beta1=0.9,
-                                 beta2=0.999, epsilon=1e-8)
-
-    compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
-        w, w, w, w, lr, lr, t).compile()
+    w, compiled = _compiled_update('adam', shape, one_chip)
     assert parse_input_output_aliases(compiled.as_text()) == \
         {0: 0, 1: 1, 2: 2}
     assert compiled.memory_analysis().alias_size_in_bytes >= \
@@ -229,11 +266,38 @@ def test_adam_step_writes_over_its_donated_operands(one_chip, shape):
 def test_sgd_mom_step_compiles(one_chip, shape):
     # the three shapes the old block rule had refused, and one it took
     w, lr, _ = _opt_shapes(shape, one_chip)
+    assert _compile(_sgd_mom, w, w, w, lr, lr) == 1
 
-    def step(w, g, mom, lr, wd):
-        return opt_mod.sgd_mom_step(w, g, mom, lr, wd, momentum=0.9)
 
-    assert _compile(step, w, w, w, lr, lr) == 1
+@pytest.mark.parametrize('kind', ['adam', 'sgd_mom'])
+@pytest.mark.parametrize('shape', ADAM_SHAPES, ids=str)
+def test_update_holds_no_relayout_of_the_leaf(one_chip, kind, shape):
+    _assert_the_kernel_alone(kind, shape, one_chip)
+
+
+@pytest.mark.parametrize('shape', [(VOCAB, UNITS), (VOCAB,), (UNITS,),
+                                   (16, 768, 2048), (16032, 2048)], ids=str)
+def test_the_registered_update_is_xlas_fusion_in_the_leafs_layout(
+        one_chip, shape):
+    """What ships since PR 35 (the gate is closed: XLA's fusion measured
+    as fast as the kernel on the chip, PERF.md §6): the registered op
+    compiles to no custom call, moves no leaf round its fusion and
+    writes the weight and both slots over their donated buffers, at a
+    kernel's shape and at one no kernel takes (the decoder's bias)."""
+    from mxnet_tpu.ops.optimizer_ops import fused_adam_step
+    w = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+    t = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+
+    def step(w, m, v, g, lr, wd, t):
+        return fused_adam_step(w, g, m, v, lr=lr, wd=wd, t=t)
+
+    compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+        w, w, w, w, lr, lr, t).compile()
+    text = compiled.as_text()
+    assert 'tpu_custom_call' not in text
+    assert not _leaf_moves(text, w.size)
+    assert compiled.memory_analysis().alias_size_in_bytes >= 3 * 4 * w.size
 
 
 # ------------------------------------------------------------------------
@@ -250,6 +314,9 @@ MLA_ADAM_SHAPES = [
     (128, 2048), (1536, 2048),              # router, shared expert
     (2048,), (512,),                        # RMSNorm gains
 ]
+# the two down-projections (dense FFN, shared expert): with the list above
+# every distinct trainable shape of DeepseekV3ForCausalLM at these widths
+MLA_DOWN_SHAPES = [(2048, 6144), (2048, 1536)]
 
 
 def test_latent_attention_fwd_bwd_compiles(one_chip, on_tpu):
@@ -308,15 +375,31 @@ def test_sparse_experts_take_the_grouped_kernels(one_chip):
 
 @pytest.mark.parametrize('shape', MLA_ADAM_SHAPES, ids=str)
 def test_adam_step_compiles_at_the_sparse_decoders_shapes(one_chip, shape):
-    w, lr, t = _opt_shapes(shape, one_chip)
-
-    def step(w, m, v, g, lr, wd, t):
-        return opt_mod.adam_step(w, g, m, v, lr, wd, t, beta1=0.9,
-                                 beta2=0.999, epsilon=1e-8)
-
-    compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
-        w, w, w, w, lr, lr, t).compile()
+    w, compiled = _compiled_update('adam', shape, one_chip)
     assert compiled.as_text().count(
         'custom_call_target="tpu_custom_call"') == 1
     assert compiled.memory_analysis().alias_size_in_bytes >= \
         3 * 4 * w.size
+
+
+@pytest.mark.parametrize('kind', ['adam', 'sgd_mom'])
+@pytest.mark.parametrize('shape', MLA_ADAM_SHAPES + MLA_DOWN_SHAPES,
+                         ids=str)
+def test_update_holds_no_relayout_at_the_sparse_decoders_shapes(
+        one_chip, kind, shape):
+    """The stacked experts' leaves collapse to (12288, 2048) and
+    (32768, 768) rows by a bitcast: 768 and 2048 fill whole sublane
+    tiles."""
+    _assert_the_kernel_alone(kind, shape, one_chip)
+
+
+@pytest.mark.parametrize('shape', [(8, 2 ** 19), (1, 24, 2 ** 17)],
+                         ids=str)
+def test_update_splits_a_last_axis_too_long_for_a_block(one_chip, shape):
+    """Eight rows of 2**19 floats are 16 MiB an operand: the grid's
+    second axis takes the last axis in whole 128-lane tiles, the kernel
+    still sees the leaf as it lies, and the declared VMEM holds."""
+    rows, cols = opt_mod._rows_view(shape)
+    bn, lanes = opt_mod._block_rows(rows, cols, 7)
+    assert lanes < cols and cols % lanes == 0 and lanes % 128 == 0
+    _assert_the_kernel_alone('adam', shape, one_chip)

@@ -56,9 +56,18 @@ def _adam_ref(w, g, m, v, lr, wd, t):
     return w - lr * mhat / (jnp.sqrt(vhat) + EPS), m2, v2
 
 
-# 24 rows of 128 lanes; 6 rows (a BERT bias: one whole-array block);
-# 1031 rows (past the VMEM cap: 512-row blocks, ragged last one)
+# 24 rows of 128 lanes in three lane tiles; 6 rows (a BERT bias: one
+# whole-array block of the (n, 128) view); 1031 rows (under `small_blocks`
+# 128-row blocks, ragged last one)
 _OPT_SHAPES = [(8, 384), (768,), (1031, 128)]
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    """Blocks of 64 KiB an operand where the chip gets 512 KiB, so that a
+    leaf of a test's size takes several grid steps: rows in blocks with a
+    ragged last one, a long last axis in lane blocks."""
+    monkeypatch.setattr(fused_optimizer, '_BLOCK_BYTES', 64 * 1024)
 
 
 # weight tolerance per shape. 1e-6 is the old limit and holds for the old
@@ -68,7 +77,7 @@ _OPT_SHAPES = [(8, 384), (768,), (1031, 128)]
 # from the reference
 @pytest.mark.parametrize('shape,w_atol', list(zip(_OPT_SHAPES,
                                                   (1e-6, 1e-6, 2e-6))))
-def test_adam_kernel_slot_updates_bit_exact(shape, w_atol):
+def test_adam_kernel_slot_updates_bit_exact(shape, w_atol, small_blocks):
     w, g = _rand(0, shape), _rand(1, shape)
     m, v = _rand(2, shape, 0.1), jnp.abs(_rand(3, shape, 0.01))
     t, lr, wd = 5, 0.01, 0.001
@@ -103,7 +112,7 @@ def test_adam_kernel_traced_hyper_no_recompile():
 
 
 @pytest.mark.parametrize('shape', [(16, 128)] + _OPT_SHAPES[1:])
-def test_sgd_mom_kernel_bit_exact(shape):
+def test_sgd_mom_kernel_bit_exact(shape, small_blocks):
     w, g, mom = _rand(0, shape), _rand(1, shape), _rand(2, shape, 0.1)
     lr, wd, mu = 0.05, 0.01, 0.9
 
@@ -117,6 +126,132 @@ def test_sgd_mom_kernel_bit_exact(shape):
     ow, om = sgd_mom_step(w, g, mom, lr, wd, momentum=mu, interpret=True)
     assert bool((om == mr).all()), 'momentum slot must be bit-exact'
     assert bool(jnp.allclose(ow, wr, rtol=2e-7, atol=0))
+
+
+# leaves as they lie (PR 35): 30522 x 768 shrunk, four row blocks and a
+# ragged fifth; stacked experts, leading axes collapsed; a last axis in two
+# lane blocks over three row blocks, the last ragged; two rows, one block
+_LIE_SHAPES = [(250, 256), (4, 24, 256), (20, 4096), (2, 768)]
+
+
+def _operands(shape):
+    return (_rand(0, shape), _rand(1, shape), _rand(2, shape, 0.1),
+            jnp.abs(_rand(3, shape, 0.01)))
+
+
+def _assert_same_update(got, want):
+    """The file's contract: slots bit for bit, the weight within an ulp
+    (the traced lr against the folded constant, one contraction)."""
+    assert got[0].shape == want[0].shape
+    for g, w in zip(got[1:], want[1:]):
+        assert bool((g == w).all()), 'slots must be bit-exact'
+    assert bool(jnp.allclose(got[0], want[0], rtol=1e-6, atol=2e-6))
+
+
+@pytest.mark.parametrize('correct_bias', [True, False])
+@pytest.mark.parametrize('clip', [None, 0.5])
+@pytest.mark.parametrize('shape', _LIE_SHAPES, ids=str)
+def test_adam_kernel_takes_a_leaf_as_it_lies(shape, clip, correct_bias,
+                                             small_blocks):
+    """Parity with the XLA update the gate's other side takes, in the
+    leaf's own shape: no (n, 128) view of a leaf of two or more axes."""
+    w, g, m, v = _operands(shape)
+    kw = dict(beta1=B1, beta2=B2, epsilon=EPS, rescale_grad=0.5,
+              clip_gradient=clip, correct_bias=correct_bias)
+    assert not fused_optimizer.use_pallas(w, g, m, v)      # the CPU: XLA
+    want = jax.jit(lambda *a: optimizer_ops.fused_adam_step(
+        *a, lr=0.01, wd=0.001, t=5, **kw))(w, g, m, v)
+    got = adam_step(w, g, m, v, 0.01, 0.001, 5, interpret=True, **kw)
+    _assert_same_update(got, want)
+    # under `small_blocks` all but the two-row leaf take several grid steps
+    rows, cols = fused_optimizer._rows_view(shape)
+    assert (fused_optimizer._block_rows(rows, cols, 7) == (rows, cols)) \
+        == (shape == (2, 768))
+
+
+@pytest.mark.parametrize('clip', [None, 0.5])
+@pytest.mark.parametrize('shape', _LIE_SHAPES, ids=str)
+def test_sgd_mom_kernel_takes_a_leaf_as_it_lies(shape, clip, small_blocks):
+    w, g, mom, _ = _operands(shape)
+    kw = dict(momentum=0.9, rescale_grad=0.5, clip_gradient=clip)
+    want = jax.jit(lambda *a: optimizer_ops.fused_sgd_mom_step(
+        *a, lr=0.05, wd=0.01, **kw))(w, g, mom)
+    got = sgd_mom_step(w, g, mom, 0.05, 0.01, interpret=True, **kw)
+    _assert_same_update(got, want)
+
+
+def test_update_kernel_sees_no_reshape_of_a_matrix():
+    """The traced update of a 2-D leaf is the pallas_call on the leaf
+    itself; a 3-D leaf's leading axes collapse, its last axis stays."""
+    for shape, view in (((250, 256), (250, 256)),
+                        ((4, 24, 256), (96, 256)), ((768,), (6, 128))):
+        w = jnp.zeros(shape, jnp.float32)
+        jaxpr = jax.make_jaxpr(lambda w: adam_step(
+            w, w, w, w, 0.1, 0.0, 1, beta1=B1, beta2=B2, epsilon=EPS,
+            interpret=True))(w)
+        call, = [e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == 'pallas_call']
+        assert {v.aval.shape for v in call.invars[1:]} == {view}
+        reshapes = [e for e in jaxpr.jaxpr.eqns
+                    if e.primitive.name == 'reshape']
+        assert bool(reshapes) == (shape != view)
+
+
+def test_update_blocks_fit_the_declared_vmem():
+    """One rule for both kernels: every operand's block, double-buffered,
+    takes at most half of what the call declares (the body's temporaries
+    have the rest), and the call declares 32 MiB, no more (PERF.md §6
+    PR 34: a kernel that declared 96 MiB spoiled the step round it)."""
+    assert fused_optimizer._VMEM_LIMIT <= 32 * 2 ** 20
+    for shape in [(30522, 768), (12288, 2048), (768, 3072), (6, 128),
+                  (2, 768), (8, 2 ** 19), (10 ** 6, 128)]:
+        for arrays in (7, 5):
+            bn, lanes = fused_optimizer._block_rows(*shape, arrays)
+            assert 2 * arrays * bn * lanes * 4 <= \
+                fused_optimizer._VMEM_LIMIT // 2
+            assert lanes % 128 == 0 and shape[1] % lanes == 0
+            assert bn == shape[0] or bn % 8 == 0
+
+
+@pytest.mark.parametrize('shape, dtype, mesh, taken', [
+    ((768,), jnp.float32, False, True),          # whole 128-lane rows
+    ((2, 768), jnp.float32, False, True),
+    ((30522, 768), jnp.float32, False, True),    # rows no multiple of 8
+    ((16, 768, 2048), jnp.float32, False, True),  # 768 % 8 == 0: bitcast
+    ((1, 5, 128), jnp.float32, False, True),     # nothing above the rows
+    ((30522,), jnp.float32, False, False),       # 1-D, no whole rows
+    ((2,), jnp.float32, False, False),
+    ((64, 3, 7, 7), jnp.float32, False, False),  # last axis 7 (size % 128)
+    ((768, 100), jnp.float32, False, False),     # last axis no lane tile
+    ((3, 5, 128), jnp.float32, False, False),    # 5 rows a slab: a copy
+    ((0, 128), jnp.float32, False, False),
+    ((), jnp.float32, False, False),
+    ((8, 128), jnp.bfloat16, False, False),
+    ((8, 128), jnp.float32, True, True),         # under a mesh: XLA too
+], ids=str)
+def test_update_gate_goes_by_shape_alone(shape, dtype, mesh, taken):
+    """What the kernels take (`_tileable`): float32, a last axis of whole
+    lane tiles, leading axes that collapse as a bitcast. Everything else
+    takes XLA's update in the leaf's own layout, and since the
+    like-for-like reading of PR 35 so does every leaf on the registered
+    ops' path, under a mesh or not: `use_pallas` is closed."""
+    import contextlib
+    w = jax.ShapeDtypeStruct(shape, dtype)
+    assert fused_optimizer._tileable(w, w, w, w) == taken
+    assert fused_optimizer._tileable(w, w, w) == taken
+    if taken:
+        # a gradient of another dtype or shape is not the kernel's
+        g = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        assert not fused_optimizer._tileable(w, g, w, w)
+        g = jax.ShapeDtypeStruct(shape + (1,), dtype)
+        assert not fused_optimizer._tileable(w, g, w, w)
+    scope = mx.sharding.mesh(dp=2) if mesh else contextlib.nullcontext()
+    with scope:
+        assert not fused_optimizer.use_pallas(w, w, w, w)
+        assert not fused_optimizer.use_pallas(w, w, w)
+        if taken:
+            step = jax.make_jaxpr(optimizer_ops.fused_adam_step)(w, w, w, w)
+            assert 'pallas_call' not in str(step)
 
 
 def test_optimizer_kernel_aliases_params_and_slots():
@@ -379,26 +514,30 @@ def test_kernels_fall_back_off_tpu():
 def test_kernel_gates_take_xla_under_a_mesh(monkeypatch):
     """GSPMD cannot partition an opaque pallas_call, so inside an
     mx.sharding mesh context every dispatch gate answers no, on a TPU
-    too; outside it the gates answer by device and shape as before."""
+    too; outside it the gates answer by device and shape as before. The
+    optimizer's gate answers no on both sides (closed, PR 35: XLA's
+    fusion of the update measured as fast on the chip)."""
     import importlib
     import mxnet_tpu as mx
     mods = [importlib.import_module('mxnet_tpu.ops.pallas.' + m) for m in
-            ('flash_attention', 'fused_norms', 'fused_optimizer',
-             'paged_attention', 'int8_matmul')]
+            ('flash_attention', 'fused_norms', 'paged_attention',
+             'int8_matmul')]
     for mod in mods:
         monkeypatch.setattr(mod, '_on_tpu', lambda: True)
-    flash, norms, opt, paged, int8 = mods
+    flash, norms, paged, int8 = mods
     w = jnp.zeros((8, 128), jnp.float32)
     q = jnp.zeros((2, 4, 1, 128), jnp.float32)
     xi, wi = jnp.zeros((32, 128), jnp.int8), jnp.zeros((128, 128), jnp.int8)
 
     def answers():
-        return (norms._use_pallas(768), opt.use_pallas(w),
-                paged.use_pallas(q, q), int8.use_pallas(xi, wi))
+        return (norms._use_pallas(768), paged.use_pallas(q, q),
+                int8.use_pallas(xi, wi))
 
     assert not flash._under_mesh() and all(answers())
+    assert not fused_optimizer.use_pallas(w, w, w, w)
     with mx.sharding.mesh(dp=2):
         assert flash._under_mesh() and not any(answers())
+        assert not fused_optimizer.use_pallas(w, w, w, w)
         jaxpr = jax.make_jaxpr(flash.flash_attention)(q, q, q)
         assert 'pallas_call' not in str(jaxpr)
     assert not flash._under_mesh() and all(answers())
