@@ -32,6 +32,17 @@ How it works
   been recorded eagerly (recording off, non-differentiable, no tracked
   input) get ``lax.stop_gradient`` in the replay, reproducing the eager
   tape's gradient-blocking exactly.
+* Under ``mx.sharding.mesh`` a segment is recorded as it is off one, and
+  its boundary — which may mix arrays committed to the mesh (a sharded
+  graph's outputs) with single-device ones (labels, loss weights) — is
+  reconciled once, at the flush, by the context the segment was opened
+  under (``ShardingContext.lift``: where any boundary array lies on more
+  than one device the single-device ones are placed on the mesh at their
+  batch spec; where none does nothing is placed). The lifted boundary is
+  what the tape node and the segment's vjp keep, and a cotangent that
+  reaches that vjp on one device is lifted the same way: each of the two
+  programs sees one device set. Labels are placed once a step, not once
+  for every op that reads them.
 
 Reference: engine.h:310-317 (bulk API), imperative_utils.h:636 (bulked
 graph execution), docs faq env_var MXNET_ENGINE_BULK_SIZE.
@@ -44,10 +55,22 @@ Correctness guards:
   unstable and ops at it run eagerly — one compile cannot be reused, so
   caching would turn into a compile-per-step storm;
 * dynamic-output-shape ops raise under abstract evaluation and fall back;
+* a plan traced under one mesh context is never replayed under another
+  (an op reads the context while it is traced: the Pallas gates take
+  XLA under a mesh, and ``jax.jit`` keeps one trace an aval signature
+  whatever the sharding): the trie a segment walks is its context's own
+  (a root a mesh fingerprint, one more off a mesh), entering or leaving
+  ``mx.sharding.mesh``/``use`` flushes the calling thread's pending
+  segment as ``engine.bulk``'s exit does, and a plan's programs enter
+  the segment's context whenever they are traced (``_under``: a
+  ``backward()`` after the mesh was left, another thread's settle);
+* what the engine turns away under a mesh is lifted op by op by the
+  eager path and counted ``unbulked``, as before;
 * deferred-compute capture, per-op profiling, ``naive_engine`` and jit
   tracing all bypass bulking (checked by the registry / via tracer inputs).
 """
 
+import functools
 import os
 import threading
 import weakref
@@ -106,14 +129,16 @@ class _TrieNode:
 
 
 class _Plan:
-    __slots__ = ('jfwd', 'fwd_raw', 'replay', 'out_keys', 'vjp_cache')
+    __slots__ = ('jfwd', 'fwd_raw', 'replay', 'out_keys', 'vjp_cache',
+                 'ctx')
 
-    def __init__(self, jfwd, fwd_raw, replay, out_keys):
+    def __init__(self, jfwd, fwd_raw, replay, out_keys, ctx):
         self.jfwd = jfwd
         self.fwd_raw = fwd_raw      # unjitted: boundary -> output tuple
         self.replay = replay        # unjitted full-env replay, for re-vjp
         self.out_keys = out_keys
         self.vjp_cache = {}         # nonzero-cot index tuple -> jitted vjp
+        self.ctx = ctx              # mesh context of the trie it hangs in
 
 
 class _SegVjp:
@@ -143,9 +168,16 @@ class _SegVjp:
                 _, vjp = jax.vjp(f, *boundary)
                 return vjp(cts)
 
-            jf = jax.jit(vjp_apply)
+            jf = jax.jit(_under(self.plan.ctx, vjp_apply))
             self.plan.vjp_cache[idxs] = jf
-        return jf(tuple(self.boundary), tuple(present[i] for i in idxs))
+        cts = [present[i] for i in idxs]
+        ctx = self.plan.ctx
+        if ctx is not None:
+            # a head gradient committed to one device beside a boundary
+            # on the mesh: one device set, as at the flush
+            n = len(self.boundary)
+            cts = ctx.lift([*self.boundary, *cts])[n:]
+        return jf(self.boundary, tuple(cts))
 
     def __call__(self, cots):
         # full-cotangent fallback (create_graph and other tape paths that
@@ -167,11 +199,12 @@ class _Segment:
             self.lock = _race.tracked(self.lock, 'bulk.segment')
             self._race = _race.shared_state('bulk._Segment',
                                             guard=self.lock)
+        self.ctx = _mesh_context()  # recorded and launched under it
         self.boundary = []          # raw jax arrays
         self.boundary_ids = {}      # (id(raw), id(ag)) -> index
         self.boundary_ags = []      # AGInfo|None per boundary input
         self.entries = []
-        self.trie_pos = state.trie
+        self.trie_pos = state.root(self.ctx)
         self.agrefs = []            # ((ei, oi), weakref(AGInfo))
         self.ag_by_key = {}         # (ei, oi) -> weakref(AGInfo) we created
         self.tape_node = None
@@ -348,11 +381,19 @@ class _Segment:
                 env = replay(*boundary)
                 return tuple(env[ei][oi] for ei, oi in out_keys)
 
-            plan = _Plan(jax.jit(fwd), fwd, replay, out_keys)
+            fwd = _under(self.ctx, fwd)
+            plan = _Plan(jax.jit(fwd), fwd, replay, out_keys, self.ctx)
             self.trie_pos.plans[out_keys] = plan
             self.state.compiles += 1
 
-        outs = plan.jfwd(*self.boundary)
+        # under a mesh the boundary may mix arrays committed to the mesh
+        # (a sharded graph's outputs) with single-device ones (labels):
+        # reconciled here, once for the segment, as the eager path does
+        # for each op (sharding/context.py ``lift``). What the tape node
+        # and the segment's vjp keep is the lifted boundary.
+        boundary = self.boundary if self.ctx is None \
+            else self.ctx.lift(self.boundary)
+        outs = plan.jfwd(*boundary)
 
         for i, ref in enumerate(live_refs):
             ref.value = outs[i]
@@ -362,11 +403,11 @@ class _Segment:
             pos = {k: i for i, k in enumerate(out_keys)}
             node = self.tape_node
             node.fn = plan.fwd_raw
-            node.in_vals = list(self.boundary)
+            node.in_vals = list(boundary)
             node.parents = list(self.boundary_ags)
             node.n_out = len(out_keys)
             node.out_avals = [r.aval for r in live_refs]
-            node.vjp_fn = _SegVjp(plan, tuple(self.boundary))
+            node.vjp_fn = _SegVjp(plan, tuple(boundary))
             for key, agw in self.agrefs:
                 ag = agw()
                 if ag is not None and key in pos:
@@ -396,11 +437,27 @@ def _build_replay(entries):
     return replay
 
 
+def _under(ctx, fn):
+    """``fn`` as a plan keeps it: run, which is to say traced, under the
+    mesh context its ops were recorded in. An op reads that context
+    while it is traced (the Pallas gates take XLA under a mesh), and
+    not every trace happens at the flush: ``backward()`` may come after
+    the mesh was left, another thread may settle the segment."""
+    @functools.wraps(fn)
+    def under(*args):
+        from .sharding.context import entered
+        with entered(ctx):
+            return fn(*args)
+
+    return under
+
+
 # ------------------------------------------------------------------- state
 class _State(threading.local):
     def __init__(self):
         self.segment = None
-        self.trie = _TrieNode()
+        self.trie = _TrieNode()     # the root off a mesh
+        self.mesh_tries = {}        # mesh fingerprint -> root under it
         self.size_override = None   # set by force(size=...) for this thread
         self.force_depth = 0
         self.disabled_depth = 0
@@ -409,6 +466,18 @@ class _State(threading.local):
         self.flushes = 0
         self.compiles = 0
         self.unbulked = 0           # eager ops the engine did not take
+
+    def root(self, ctx):
+        """The trie of the context a segment opens under: a plan traced
+        under one mesh context (or none) is never replayed under
+        another, whatever its ops and avals."""
+        if ctx is None:
+            return self.trie
+        key = ctx.fingerprint()
+        root = self.mesh_tries.get(key)
+        if root is None:
+            root = self.mesh_tries[key] = _TrieNode()
+        return root
 
 
 _st = _State()
@@ -420,6 +489,13 @@ _env_default = None
 # scope overrides.
 _enabled = None                 # None = resolve from env/backend
 _size = int(os.environ.get('MXNET_ENGINE_BULK_SIZE', 4096))
+
+
+def _mesh_context():
+    """The calling thread's ``mx.sharding`` context, None off a mesh
+    (``mx.sharding`` loads after the ops do, so not at import)."""
+    from .sharding.context import current
+    return current()
 
 
 def _default_enabled():
@@ -474,7 +550,7 @@ def stats():
 
 def note_unbulked(raws):
     """An op that was the engine's to take went eager, a launch of its
-    own (bulking off, a mesh context, a position that keeps changing).
+    own (bulking off, no bulk key, a position that keeps changing).
     Inside a ``jit`` trace the op launches nothing and is not counted."""
     for r in raws:
         if isinstance(r, jax.core.Tracer):
@@ -486,6 +562,7 @@ def reset():
     """Drop the segment trie and all cached plans (flushes first)."""
     flush_current()
     _st.trie = _TrieNode()
+    _st.mesh_tries = {}
 
 
 class force:

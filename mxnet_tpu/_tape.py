@@ -431,10 +431,10 @@ def _backward(heads, head_grads, retain_graph, train_mode, variables,
                 info.grad._rsp = rsp
                 continue
         info.grad._rsp = None
-        if info.grad_req == 'add':
-            info.grad._data = info.grad._data + cot.astype(info.grad._data.dtype)
-        else:  # 'write'
-            info.grad._data = cot.astype(info.grad._data.dtype)
+        have = info.grad._data
+        if cot.dtype != have.dtype:     # else astype is Python for nothing
+            cot = cot.astype(have.dtype)
+        info.grad._data = have + cot if info.grad_req == 'add' else cot
     del node_index
     return None
 

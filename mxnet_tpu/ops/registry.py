@@ -222,12 +222,13 @@ def apply_op(op, arrays, fn, n_out=None, name=None, _from_invoke=False,
 
     # ---- bulked (lazy) dispatch: record into the segment instead of
     # executing; the flush runs the whole segment as one XLA program.
-    # Not under a mesh context: a segment's boundary may mix arrays
-    # committed to the mesh with single-device ones, and only the eager
-    # path below reconciles them.
+    # Under a mesh context too: a segment's boundary may mix arrays
+    # committed to the mesh with single-device ones, and the flush
+    # reconciles them once for the segment (_bulk._Segment._launch), as
+    # the eager path below does for each op the engine turns away.
     offered = (bulk_key is not None and arrays and not profiling
                and not _dc.is_deferred_compute())
-    if offered and not _on_mesh():
+    if offered:
         grad_active = recording and op.differentiable
         rec = _bulk.try_record(op, arrays, fn, bulk_key, grad_active)
         if rec is not None:

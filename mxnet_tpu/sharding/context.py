@@ -32,6 +32,7 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import rules as _rules
+from .. import _bulk
 
 __all__ = ['ShardingContext', 'MeshGroup', 'mesh', 'current',
            'constrain', 'batch_spec', 'use']
@@ -115,6 +116,26 @@ class ShardingContext:
 
     def put(self, raw, spec):
         return jax.device_put(raw, NamedSharding(self.mesh, spec))
+
+    def lift(self, raws):
+        """Reconcile the device sets of one program's operands: where
+        any of ``raws`` lies on more than one device, every
+        single-device array among them is placed on the mesh at its
+        batch spec; where none does, the same list comes back."""
+        for r in raws:
+            sh = getattr(r, 'sharding', None)
+            if sh is not None and len(sh.device_set) > 1:
+                break
+        else:
+            return raws
+        out = []
+        for r in raws:
+            sh = getattr(r, 'sharding', None)
+            if sh is not None and len(sh.device_set) == 1 \
+                    and getattr(r, 'ndim', None) is not None:
+                r = self.put(r, self.batch_spec(r.shape))
+            out.append(r)
+        return out
 
     def zero1_spec(self, param_spec, shape):
         """Optimizer-slot spec: the parameter's layout plus the data
@@ -309,33 +330,50 @@ def batch_spec(shape):
 
 
 def lift_raws(raws):
-    """Eager-op device reconciliation (called by ``ops.registry``).
+    """Device reconciliation of what runs eagerly under the active mesh
+    (:meth:`ShardingContext.lift`; the same list back where no context
+    is active).
 
-    Inside a mesh context one dispatch may see arrays committed to the
+    Inside a mesh context one program may see arrays committed to the
     full mesh (sharded graph outputs) next to host-fresh single-device
     arrays (labels, loss masks) — jax rejects mixed committed device
-    sets. Lift the single-device ones onto the mesh at their batch spec
-    so eager loss/metric math composes with sharded forwards with zero
-    model-code changes. No-op (same list back) when nothing is
-    multi-device."""
+    sets. Lifting the single-device ones onto the mesh at their batch
+    spec lets eager loss/metric math compose with sharded forwards with
+    zero model-code changes. Two callers: the bulking engine lifts a
+    segment's boundary once, at flush (``_bulk._Segment._launch``, from
+    the context the segment was recorded under), so labels and weights
+    are placed once a step and not once for every op that reads them;
+    ``ops.registry.apply_op`` lifts the operands of an op the engine
+    turned away."""
     ctx = current()
-    if ctx is None:
-        return raws
-    for r in raws:
-        sh = getattr(r, 'sharding', None)
-        if sh is not None and len(sh.device_set) > 1:
-            break
-    else:
-        return raws
-    out = []
-    for r in raws:
-        sh = getattr(r, 'sharding', None)
-        if sh is not None and len(sh.device_set) == 1 \
-                and getattr(r, 'ndim', None) is not None:
-            r = jax.device_put(r, NamedSharding(
-                ctx.mesh, ctx.batch_spec(r.shape)))
-        out.append(r)
-    return out
+    return raws if ctx is None else ctx.lift(raws)
+
+
+@contextmanager
+def entered(ctx):
+    """``ctx`` (None: no mesh) is the calling thread's context inside;
+    nothing is flushed. The bulking engine traces a segment's plan under
+    the context the segment was recorded in, whichever is active when
+    the trace happens."""
+    _stack().append(ctx)
+    try:
+        yield ctx
+    finally:
+        _stack().pop()
+
+
+@contextmanager
+def _changed_to(ctx):
+    """Enter ``ctx`` as a context change: the calling thread's pending
+    bulk segment runs before the change and what was recorded inside
+    runs before the way out, so no segment is recorded under one
+    context and launched under another."""
+    _bulk.flush_current()
+    with entered(ctx):
+        try:
+            yield ctx
+        finally:
+            _bulk.flush_current()
 
 
 def _env_axis(name, value):
@@ -360,6 +398,10 @@ def mesh(dp=None, tp=None, devices=None, rules=None, mode=None,
     context into a no-op. ``rules`` pins an explicit rule table;
     otherwise the registry table for ``arch`` (inferred per block when
     omitted) and the mode ('tp' when tp>1 else 'fsdp') applies.
+
+    Entering and leaving are sync points of the bulking engine: the
+    calling thread's pending segment of eager ops runs first, so that
+    none is recorded under one context and launched under another.
     """
     if os.environ.get('MXNET_SHARDING_DISABLE', '') == '1':
         yield None
@@ -379,22 +421,17 @@ def mesh(dp=None, tp=None, devices=None, rules=None, mode=None,
         sizes = {'dp': len(devices or jax.devices())}
     ctx = ShardingContext(make_mesh(devices=devices, **sizes),
                           rules=rules, mode=mode, arch=arch)
-    _stack().append(ctx)
-    try:
+    with _changed_to(ctx):
         yield ctx
-    finally:
-        _stack().pop()
 
 
 @contextmanager
 def use(ctx):
     """Re-enter an existing :class:`ShardingContext` (e.g. one captured
-    by a server at construction)."""
+    by a server at construction); a sync point of the bulking engine
+    on the way in and out, as :func:`mesh` is."""
     if ctx is None:
         yield None
         return
-    _stack().append(ctx)
-    try:
+    with _changed_to(ctx):
         yield ctx
-    finally:
-        _stack().pop()

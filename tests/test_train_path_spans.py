@@ -254,7 +254,11 @@ def test_under_a_mesh_the_update_places_its_operands():
     by_id = {e['span']: e for e in events}
     place, = [e for e in events if e['name'] == 'mx.trainer.place']
     assert by_id[place['parent']]['name'] == 'mx.trainer.step'
-    assert unbulked >= 3            # subtract, square, mean: one launch each
+    # subtract, square, mean: one launch each on the CPU's default, where
+    # the bulking engine is off. Where it is on (the chip; forced here in
+    # tests/test_bulk_mesh.py) the three are one segment under a mesh too
+    # and this reads 0.
+    assert unbulked >= 3
 
 
 def _lowered(fn, *args):
