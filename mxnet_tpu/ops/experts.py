@@ -6,16 +6,22 @@ function does what one chip of an expert-parallel layer does between the
 exchanges: every token is scored against *all* experts, its ``k`` experts
 are chosen and its weights normalised over them, and then only the
 (token, choice) pairs that fall on a held expert are computed: sorted by
-expert, gathered, taken through the three grouped products of a SwiGLU
-(``jax.lax.ragged_dot``, the per-expert counts as group sizes), weighted
-and summed back onto their tokens. What the absent experts would have
-added is left out, so the shares of all chips add up to the whole layer
-(tests/test_deepseek_v3.py). ``gluon.nn.SparseExperts`` is the Block.
+expert, gathered, taken through the grouped products of the experts
+(``jax.lax.ragged_dot``, the per-expert counts as group sizes: three of a
+SwiGLU, ``down(silu(gate u) * up u)``, or two of an un-gated
+``down(relu(up u)^2)``), weighted and summed back onto their tokens.
+What the absent experts would have added is left out, so the shares of
+all chips add up to the whole layer (tests/test_deepseek_v3.py,
+tests/test_nemotron_h.py). ``gluon.nn.SparseExperts`` is the Block.
 
 There is no capacity: the sorted buffer has a row for every pair
 (tokens x k), so a batch that sends every token to one expert loses
-nothing. Rows past the last held group are computed by nobody and read
-by nobody (both sides of them are masked, forward and backward).
+nothing. Rows past the last held group are computed by nobody: the rows
+that go in and the rows that come out are masked, forward and backward.
+What a grouped product leaves *in between* there is zeros on the CPU and
+whatever the buffer held on the TPU; the un-gated form masks those rows'
+weights too, so that no gradient reads them (the gated form does not
+yet: PERF.md section 7).
 """
 
 import jax
@@ -127,22 +133,35 @@ def _gated(gate, up, weight):
     return jax.nn.silu(gate) * up * weight
 
 
+@jax.checkpoint
+def _squared(up, weight):
+    """relu(up)^2 * weight, made again in the backward pass as
+    :func:`_gated` is."""
+    return jnp.square(jax.nn.relu(up)) * weight
+
+
 @register('sparse_experts', f32_only=True)
 def sparse_experts(x, router_weight, router_bias, experts_gate, experts_up,
                    experts_down, experts_per_token=2, first_expert=0,
                    score_func='sigmoid', norm_topk_prob=True,
-                   routed_scaling_factor=1.0):
+                   routed_scaling_factor=1.0, activation='swiglu'):
     """The routed part of a sparse-expert FFN for the experts held here.
 
     x: (..., U). router_weight: (E, U) and router_bias: (E,) over all E
     experts. experts_gate, experts_up: (n, X, U) and experts_down:
     (n, U, X) for the n experts ``first_expert .. first_expert + n - 1``.
     Returns (..., U): sum over a token's chosen experts that are held
-    here of ``weight * down(silu(gate u) * up u)``.
+    here of ``weight * down(silu(gate u) * up u)`` (``activation``
+    ``'swiglu'``) or of ``weight * down(relu(up u)^2)`` (``'relu2'``:
+    there is no gate, and ``experts_gate`` is None).
     """
+    if activation not in ('swiglu', 'relu2'):
+        raise ValueError(f'unknown activation {activation!r}')
+    if (experts_gate is None) != (activation == 'relu2'):
+        raise ValueError('a gate goes with swiglu, and none with relu2')
     shape = x.shape
     units = shape[-1]
-    held = experts_gate.shape[0]
+    held = experts_up.shape[0]
     k = experts_per_token
     tokens = x.reshape(-1, units)
     # the router's scope is opened beside the experts', not inside it: a
@@ -168,10 +187,23 @@ def sparse_experts(x, router_weight, router_bias, experts_gate, experts_up,
         # the weight goes onto the narrow side of the down projection
         # (linear, so the same sum): what is kept for the weights'
         # gradient is then X wide, not U
-        hidden = _gated(
-            _grouped(rows, experts_gate, sizes),
-            _grouped(rows, experts_up, sizes),
-            _permute(weights.reshape(-1, 1).astype(x.dtype), order, inverse))
+        if activation == 'swiglu':
+            hidden = _gated(
+                _grouped(rows, experts_gate, sizes),
+                _grouped(rows, experts_up, sizes),
+                _permute(weights.reshape(-1, 1).astype(x.dtype), order,
+                         inverse))
+        else:
+            # on the TPU a grouped product leaves the rows past its last
+            # group as the buffer held them (not zeros, though the rows
+            # that went in are): relu(that)^2 times the backward's like
+            # rows would be the gradient of an absent pair's weight, and
+            # through it the router's. The dead rows' weights are masked,
+            # forward and backward, so that it is 0.
+            hidden = _squared(
+                _grouped(rows, experts_up, sizes),
+                _live_rows(_permute(weights.reshape(-1, 1).astype(x.dtype),
+                                    order, inverse), n_live))
         out = _live_rows(_grouped(hidden, experts_down, sizes), n_live)
         out = _permute(out, inverse, order).reshape(-1, k, units).sum(1)
         return out.reshape(shape).astype(x.dtype)

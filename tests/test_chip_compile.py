@@ -275,15 +275,10 @@ def test_update_holds_no_relayout_of_the_leaf(one_chip, kind, shape):
     _assert_the_kernel_alone(kind, shape, one_chip)
 
 
-@pytest.mark.parametrize('shape', [(VOCAB, UNITS), (VOCAB,), (UNITS,),
-                                   (16, 768, 2048), (16032, 2048)], ids=str)
-def test_the_registered_update_is_xlas_fusion_in_the_leafs_layout(
-        one_chip, shape):
-    """What ships since PR 35 (the gate is closed: XLA's fusion measured
-    as fast as the kernel on the chip, PERF.md §6): the registered op
-    compiles to no custom call, moves no leaf round its fusion and
-    writes the weight and both slots over their donated buffers, at a
-    kernel's shape and at one no kernel takes (the decoder's bias)."""
+def _assert_xlas_fusion_in_place(shape, one_chip):
+    """The registered fused Adam step at ``shape``: no custom call, no
+    relayout of the leaf round its fusion, the weight and both slots
+    written over their donated buffers."""
     from mxnet_tpu.ops.optimizer_ops import fused_adam_step
     w = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
     lr = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
@@ -298,6 +293,18 @@ def test_the_registered_update_is_xlas_fusion_in_the_leafs_layout(
     assert 'tpu_custom_call' not in text
     assert not _leaf_moves(text, w.size)
     assert compiled.memory_analysis().alias_size_in_bytes >= 3 * 4 * w.size
+
+
+@pytest.mark.parametrize('shape', [(VOCAB, UNITS), (VOCAB,), (UNITS,),
+                                   (16, 768, 2048), (16032, 2048)], ids=str)
+def test_the_registered_update_is_xlas_fusion_in_the_leafs_layout(
+        one_chip, shape):
+    """What ships since PR 35 (the gate is closed: XLA's fusion measured
+    as fast as the kernel on the chip, PERF.md §6): the registered op
+    compiles to no custom call, moves no leaf round its fusion and
+    writes the weight and both slots over their donated buffers, at a
+    kernel's shape and at one no kernel takes (the decoder's bias)."""
+    _assert_xlas_fusion_in_place(shape, one_chip)
 
 
 # ------------------------------------------------------------------------
@@ -403,3 +410,139 @@ def test_update_splits_a_last_axis_too_long_for_a_block(one_chip, shape):
     bn, lanes = opt_mod._block_rows(rows, cols, 7)
     assert lanes < cols and cols % lanes == 0 and lanes % 128 == 0
     _assert_the_kernel_alone('adam', shape, one_chip)
+
+
+# ------------------------------------------------------------------------
+# The ops of the nemotron_h train path at nemotron_twotower_30b_a3b's
+# widths (chipbench/configs/nemotron_twotower_30b_a3b.json: 2 rows x 1024
+# positions, 2688 wide; 64 Mamba heads of 64, 8 groups, state 128, conv 4,
+# chunk 128; 32 query and 2 key/value heads of 128; experts of 1856, 8 of
+# 128 held, 6 a token).
+
+NH_ROWS, NH_SEQ, NH_UNITS = 2, 1024, 2688
+NH_HEADS, NH_P, NH_GROUPS, NH_STATE, NH_CHUNK = 64, 64, 8, 128, 128
+NH_CONV = NH_HEADS * NH_P + 2 * NH_GROUPS * NH_STATE          # 6144
+# every distinct trainable shape of NemotronHForCausalLM at these widths
+NH_ADAM_SHAPES = [
+    (64,), (6144, 4), (6144,), (4096,),         # A_log / D / dt_bias, conv
+    (10304, 2688), (2688, 4096),                # in_proj, out_proj / o_proj
+    (8, 1856, 2688), (8, 2688, 1856),           # experts stacked in a leaf
+    (16384, 2688),                              # embedding, head (a slice)
+    (2688,), (128, 2688),                       # RMSNorm gains, router
+    (3712, 2688), (2688, 3712),                 # the shared expert
+    (4096, 2688), (256, 2688),                  # q_proj; k_proj, v_proj
+]
+
+
+def test_the_nemotron_shapes_are_what_the_model_has():
+    """NH_ADAM_SHAPES against the zoo's own leaves: a period at the
+    published widths, no array made."""
+    import json
+    import os
+    from mxnet_tpu.gluon.model_zoo.nemotron_h import (NemotronHConfig,
+                                                      NemotronHForCausalLM)
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    from chipbench.flops.nemotron_h import moved_param_count
+    with open(os.path.join(here, 'chipbench', 'configs',
+                           'nemotron_twotower_30b_a3b.json')) as f:
+        cfg = json.load(f)
+    params = NemotronHForCausalLM(NemotronHConfig(**cfg)).collect_params()
+    assert {tuple(p.shape) for p in params.values()
+            if p.grad_req != 'null'} == set(NH_ADAM_SHAPES)
+    # 528.1 M under Adam, by the leaves and by the benchmark's count
+    assert sum(math.prod(p.shape) for p in params.values()
+               if p.grad_req != 'null') == moved_param_count(cfg) \
+        == 528_092_736
+
+
+def _scan_shapes(one_chip):
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                            sharding=one_chip)
+    return (s(NH_ROWS, NH_SEQ, NH_HEADS, NH_P), s(NH_ROWS, NH_SEQ, NH_HEADS),
+            s(NH_HEADS), s(NH_ROWS, NH_SEQ, NH_GROUPS, NH_STATE),
+            s(NH_ROWS, NH_SEQ, NH_GROUPS, NH_STATE), s(NH_HEADS))
+
+
+def test_ssm_scan_fwd_bwd_compiles(one_chip):
+    """The chunked scan at the cell's shapes, forward and backward: plain
+    XLA (no custom call), and what it keeps for the backward is its
+    inputs, not a (128, 128) array a head: the program's peak stays under
+    eight of them (67 MB each)."""
+    from mxnet_tpu.ops.ssm import ssm_scan
+
+    def loss(*a):
+        return (ssm_scan(*a, chunk_size=NH_CHUNK) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        *_scan_shapes(one_chip)).compile()
+    assert 'tpu_custom_call' not in compiled.as_text()
+    per_head_square = 4 * NH_ROWS * NH_SEQ * NH_HEADS * NH_CHUNK
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 8 * per_head_square
+
+
+def test_ssm_conv_fwd_bwd_compiles(one_chip):
+    from mxnet_tpu.ops.ssm import ssm_conv
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                            sharding=one_chip)
+
+    def loss(x, w, b):
+        return (ssm_conv(x, w, b) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        s(NH_ROWS, NH_SEQ, NH_CONV), s(NH_CONV, 4), s(NH_CONV)) \
+        .compile().as_text()
+    assert 'tpu_custom_call' not in text
+
+
+def test_grouped_query_attention_takes_the_flash_pair(one_chip, on_tpu):
+    """32 query heads of 128 over 1024 positions, K and V repeated from 2
+    heads as the zoo's layer does: the kernels take one head a grid step.
+    Two custom calls, forward and backward, and no (1024, 1024) tensor."""
+    from mxnet_tpu.ops.contrib import multi_head_attention
+    s = lambda heads: jax.ShapeDtypeStruct(
+        (NH_ROWS, NH_SEQ, heads * 128), jnp.float32, sharding=one_chip)
+
+    def loss(q, k, v):
+        rep = lambda a: jnp.repeat(
+            a.reshape(NH_ROWS, NH_SEQ, 2, 128), 16, axis=2).reshape(
+            NH_ROWS, NH_SEQ, -1)
+        out = multi_head_attention(q, rep(k), rep(v), 32, causal=True)
+        return (out ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        s(32), s(2), s(2)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert f'{NH_SEQ},{NH_SEQ}]' not in text
+
+
+def test_un_gated_experts_take_the_grouped_kernels(one_chip):
+    """relu2 experts at the cell's shapes (8 held of 128, 1856 wide): the
+    two grouped products and their gradients lower to the compiler's own
+    grouped-matmul kernels, as the three of a SwiGLU do above."""
+    from mxnet_tpu.ops.experts import sparse_experts
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                            sharding=one_chip)
+    shapes = (s(NH_ROWS, NH_SEQ, NH_UNITS), s(128, NH_UNITS), s(128),
+              s(8, 1856, NH_UNITS), s(8, NH_UNITS, 1856))
+
+    def loss(x, rw, rb, up, down):
+        return (sparse_experts(x, rw, rb, None, up, down,
+                               experts_per_token=6, first_expert=0,
+                               routed_scaling_factor=2.5,
+                               activation='relu2') ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4))).lower(
+        *shapes).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') >= 6
+    assert 'f32[8,12288,' not in text
+
+
+@pytest.mark.parametrize('shape', NH_ADAM_SHAPES, ids=str)
+def test_the_update_at_the_hybrids_shapes_is_one_fusion_in_place(
+        one_chip, shape):
+    """The registered fused Adam step (XLA's fusion since PR 35) at every
+    leaf shape of the hybrid, the 1-D (64,) and the (6144, 4) convolution
+    among them: no custom call, no relayout of the leaf, the weight and
+    both slots written over their donated buffers."""
+    _assert_xlas_fusion_in_place(shape, one_chip)

@@ -254,6 +254,73 @@ def test_no_token_is_dropped_when_all_choose_the_same(favoured, held):
     close(got, want)
 
 
+def _sparse_experts_as_it_was(x, router_weight, router_bias, experts_gate,
+                              experts_up, experts_down, experts_per_token,
+                              first_expert, routed_scaling_factor):
+    """ops/experts.py's ``sparse_experts`` before it took an
+    ``activation`` (PR 36), over the module's own helpers."""
+    ex = mx.ops.experts
+    shape = x.shape
+    units = shape[-1]
+    held = experts_gate.shape[0]
+    k = experts_per_token
+    tokens = x.reshape(-1, units)
+    with jax.named_scope(ex.ROUTER_SCOPE):
+        chosen, weights = ex.route(
+            tokens, router_weight, router_bias, k, 'sigmoid', True,
+            routed_scaling_factor)
+        local = chosen.reshape(-1) - first_expert
+        local = jnp.where((local >= 0) & (local < held), local, held)
+        order = jnp.argsort(local, stable=True).astype(jnp.int32)
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        sizes = (local[:, None] == jnp.arange(held, dtype=jnp.int32)
+                 ).sum(0, dtype=jnp.int32)
+        n_live = sizes.sum()
+    with jax.named_scope(ex.SCOPE):
+        rows = ex._permute(jnp.repeat(tokens, k, axis=0), order, inverse)
+        rows = ex._live_rows(rows, n_live)
+        hidden = ex._gated(
+            ex._grouped(rows, experts_gate, sizes),
+            ex._grouped(rows, experts_up, sizes),
+            ex._permute(weights.reshape(-1, 1).astype(x.dtype), order,
+                        inverse))
+        out = ex._live_rows(ex._grouped(hidden, experts_down, sizes), n_live)
+        out = ex._permute(out, inverse, order).reshape(-1, k, units).sum(1)
+        return out.reshape(shape).astype(x.dtype)
+
+
+def test_swiglu_experts_are_the_program_they_were():
+    """The default activation: the same jaxpr as before the un-gated
+    form came, forward and backward, and so the same bits; and the
+    un-gated Block has no gate to hold."""
+    lp = layer_weights(seed=6)
+    held = slice(2, 6)
+    x = jnp.asarray(np.random.default_rng(3).normal(0, 1, (2, 10, UNITS)),
+                    jnp.float32)
+    args = (x, lp['router_w'], lp['router_b'], lp['experts_gate'][held],
+            lp['experts_up'][held], lp['experts_down'][held])
+    new = lambda *a: mx.ops.experts.sparse_experts(
+        *a, experts_per_token=PER_TOKEN, first_expert=2,
+        routed_scaling_factor=1.7)
+    old = lambda *a: _sparse_experts_as_it_was(*a, PER_TOKEN, 2, 1.7)
+    for fn in (lambda f: f, lambda f: jax.grad(
+            lambda *a: (f(*a) ** 2).sum(), (0, 1, 3, 4, 5))):
+        assert str(jax.make_jaxpr(fn(new))(*args)) == \
+            str(jax.make_jaxpr(fn(old))(*args))
+    assert np.array_equal(np.asarray(new(*args)), np.asarray(old(*args)))
+    # and through the Block, as the zoo's decoder calls it
+    got = share(lp, range(2, 6))(mx.np.array(x)).asnumpy()
+    assert np.array_equal(got, np.asarray(old(*args)))
+    swiglu = nn.SparseExperts(UNITS, EXPERTS, PER_TOKEN, SIZE)
+    relu2 = nn.SparseExperts(UNITS, EXPERTS, PER_TOKEN, SIZE,
+                             activation='relu2')
+    assert 'experts_gate' in swiglu.collect_params()
+    assert set(swiglu.collect_params()) - set(relu2.collect_params()) == \
+        {'experts_gate'}
+    with pytest.raises(ValueError, match='a gate goes with swiglu'):
+        mx.ops.experts.sparse_experts(*args, activation='relu2')
+
+
 def test_a_held_range_outside_the_experts_is_refused():
     with pytest.raises(ValueError, match='consecutive experts'):
         nn.SparseExperts(UNITS, EXPERTS, PER_TOKEN, SIZE,
