@@ -14,15 +14,37 @@ What the absent experts would have added is left out, so the shares of
 all chips add up to the whole layer (tests/test_deepseek_v3.py,
 tests/test_nemotron_h.py). ``gluon.nn.SparseExperts`` is the Block.
 
-There is no capacity: the sorted buffer has a row for every pair
-(tokens x k), so a batch that sends every token to one expert loses
-nothing. Rows past the last held group are computed by nobody: the rows
-that go in and the rows that come out are masked, forward and backward.
-What a grouped product leaves *in between* there is zeros on the CPU and
-whatever the buffer held on the TPU; the un-gated form masks those rows'
-weights too, so that no gradient reads them (the gated form does not
-yet: PERF.md section 7).
+**The buffer.** The (token, choice) pairs are sorted by held expert, the
+pairs of absent experts last: the live rows are the first ``n_live`` of
+``m = tokens x k``. There is no capacity: a batch that sends every token
+to a held expert has ``m`` live rows and loses nothing.
+
+**The ladder.** A chip that holds ``held`` of ``E`` experts expects
+``m x held / E`` live rows, and the gathers, the masks and the
+compiler's grouped kernel (in 512-row tiles) cost by the rows they are
+given, not by the live ones. So everything under ``mx.experts`` runs
+over the first ``P`` rows only, ``P`` the shortest of
+a few static prefixes (:func:`prefix_ladder`, a function of the shapes
+alone) that holds the live rows, chosen on the device by ``lax.switch``.
+The last rung is ``m``, the whole buffer. A layer that holds every expert
+(or whose buffer is no longer than the first rung) has one rung and no
+``cond``. The switch sits inside one ``custom_vjp``: what is kept for the
+backward pass is the op's inputs and the routing, whichever rung runs,
+and the backward's own switch makes the taken rung's body again and
+takes its vjp (a differentiated ``cond`` keeps the union of every
+branch's residuals). Each rung opens the scope ``rows_<P>`` inside
+``mx.experts``: a profile's operation names say which rung ran.
+
+**Dead rows.** A prefix still has rows past the last held group
+(``n_live .. P``), computed by nobody: the rows that go in and the rows
+that come out are masked, forward and backward. What a grouped product
+leaves *in between* there is zeros on the CPU and whatever the buffer
+held on the TPU, so both activations mask those rows' routing weights
+too, forward and backward: no gradient reads them (PERF.md section 7,
+fault 10).
 """
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -34,23 +56,69 @@ ROUTER_SCOPE = 'mx.router'      # scores, top-k, the sort by expert, counts
 SCOPE = 'mx.experts'            # gathers, grouped products, the sum back
 
 
-@jax.custom_vjp
-def _permute(x, perm, inverse):
-    """``x[perm]`` for a permutation of the rows: its gradient is a
-    gather by the inverse, where XLA's for a gather is a scatter-add."""
-    return x[perm]
+# The ladder's numbers, settled on the v5e (PERF.md section 6, PR 38). A
+# router's load on the held experts is uneven: 0.2 to 2.3 times the
+# expected count of live rows were read over a run with 16 of 128 held,
+# nothing to 6.6 times it with 8 held. A rung is a copy of the body in
+# the forward and in the backward program (27 MB of code a rung with
+# four such layers, on a chip 91 % full), so there are few.
+TILE = 512          # rows of a tile of the compiler's grouped kernel
+MARGIN = 1.5        # the first rung over the expected live count
+RUNGS = 2           # at most so many rungs below the whole buffer
 
 
-def _permute_fwd(x, perm, inverse):
-    return x[perm], (perm, inverse)
+def prefix_ladder(m, held, experts):
+    """The static prefixes of a sorted buffer of ``m`` rows that a layer
+    holding ``held`` of ``experts`` experts chooses from: whole tiles,
+    the first ``MARGIN`` times the expected live count ``m x held /
+    experts``, ``RUNGS`` of them in all under ``m`` in equal ratios from
+    the first to ``m``, then ``m`` itself. ``(m,)`` where every expert
+    is held, or where the first rung would be no shorter than the
+    buffer."""
+    if held >= experts:
+        return (m,)
+    tiles = lambda rows: -(-int(rows) // TILE) * TILE
+    first = tiles(MARGIN * m * held / experts)
+    ratio = (m / first) ** (1 / RUNGS)
+    rungs = sorted({tiles(first * ratio ** i) for i in range(RUNGS)})
+    return (*(p for p in rungs if p < m), m)
 
 
-def _permute_bwd(res, g):
-    _, inverse = res
-    return g[inverse], None, None
+def _spread(x, head, k):
+    """(n, w) -> (p, w): row ``i`` is the row of pair ``head[i]``, where
+    ``x`` has a row for every ``k`` pairs in a row."""
+    return x[head // k]
 
 
-_permute.defvjp(_permute_fwd, _permute_bwd)
+def _sum_back(y, inverse, k):
+    """(p, w) -> (m / k, w): pair ``j`` is row ``inverse[j]`` of ``y``, or
+    zero where that is past ``y``'s rows, and ``k`` pairs in a row sum
+    to a row. One gather of ``m`` rows, where XLA's gradient of
+    :func:`_spread` is a scatter-add; gathered a choice at a time,
+    (k, m / k, w), so that the sum runs over whole tiles (a (m / k, k, w)
+    view of the rows is a padded copy of them on the TPU)."""
+    return y.at[inverse.reshape(-1, k).T].get(
+        mode='fill', fill_value=0).sum(0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _sorted_rows(x, head, inverse, k):
+    """:func:`_spread`, with :func:`_sum_back` for its gradient."""
+    return _spread(x, head, k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _onto_tokens(y, head, inverse, k):
+    """:func:`_sum_back`, with :func:`_spread` for its gradient."""
+    return _sum_back(y, inverse, k)
+
+
+_sorted_rows.defvjp(
+    lambda x, head, inverse, k: (_spread(x, head, k), (head, inverse)),
+    lambda k, res, g: (_sum_back(g, res[1], k), None, None))
+_onto_tokens.defvjp(
+    lambda y, head, inverse, k: (_sum_back(y, inverse, k), (head, inverse)),
+    lambda k, res, g: (_spread(g, res[0], k), None, None))
 
 
 def route(x, router_weight, router_bias, experts_per_token, score_func,
@@ -140,6 +208,80 @@ def _squared(up, weight):
     return jnp.square(jax.nn.relu(up)) * weight
 
 
+def _prefix(p, activation, k, routing, tokens, column, leaves):
+    """The routed experts over the first ``p`` rows of the sorted buffer,
+    which hold every live row: (tokens, U) -> (tokens, U)."""
+    order, inverse, sizes, n_live = routing
+    with jax.named_scope(f'rows_{p}'):
+        head = order[:p]
+        rows = _live_rows(_sorted_rows(tokens, head, inverse, k), n_live)
+        # the dead rows' weights are masked too: what a grouped product
+        # leaves in those rows on the TPU, times the backward's like
+        # rows, would be the gradient of an absent pair's weight, and
+        # through it the router's
+        weight = _live_rows(_sorted_rows(column, head, inverse, 1), n_live)
+        # the weight goes onto the narrow side of the down projection
+        # (linear, so the same sum): what is kept for the weights'
+        # gradient is then X wide, not U
+        if activation == 'swiglu':
+            gate, up, down = leaves
+            hidden = _gated(_grouped(rows, gate, sizes),
+                            _grouped(rows, up, sizes), weight)
+        else:
+            up, down = leaves
+            hidden = _squared(_grouped(rows, up, sizes), weight)
+        out = _live_rows(_grouped(hidden, down, sizes), n_live)
+        return _onto_tokens(out, head, inverse, k)
+
+
+@functools.lru_cache(maxsize=None)
+def _branches(body, ladder, activation, k):
+    """``body`` over each prefix of ``ladder``: the same callables for
+    every layer of a model, so that JAX traces a rung's body once for
+    all the layers of one shape."""
+    return tuple(functools.partial(body, p, activation, k) for p in ladder)
+
+
+def _rung(ladder, n_live):
+    """The index of the shortest prefix that holds ``n_live`` rows."""
+    return (n_live > jnp.asarray(ladder[:-1], jnp.int32)).sum()
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _shortest_prefix(ladder, activation, k, routing, tokens, column, leaves):
+    """:func:`_prefix` over the shortest prefix of ``ladder`` that holds
+    the live rows. Differentiated by hand, not as a ``cond``: JAX keeps
+    for a differentiated ``cond`` the residuals of all its branches,
+    each of its own buffer's size. Kept here: the arguments."""
+    return lax.switch(_rung(ladder, routing[3]),
+                      _branches(_prefix, ladder, activation, k),
+                      routing, tokens, column, leaves)
+
+
+def _shortest_prefix_fwd(ladder, activation, k, routing, tokens, column,
+                         leaves):
+    return (_shortest_prefix(ladder, activation, k, routing, tokens, column,
+                             leaves), (routing, tokens, column, leaves))
+
+
+def _prefix_grads(p, activation, k, routing, inputs, g):
+    """The body over ``p`` rows made again, and its vjp at ``g``: what
+    it kept lives no longer than this branch."""
+    _, vjp = jax.vjp(functools.partial(_prefix, p, activation, k, routing),
+                     *inputs)
+    return vjp(g)
+
+
+def _shortest_prefix_bwd(ladder, activation, k, res, g):
+    routing, *inputs = res
+    return (None, *lax.switch(_rung(ladder, routing[3]),
+                              _branches(_prefix_grads, ladder, activation, k),
+                              routing, inputs, g))
+
+
+_shortest_prefix.defvjp(_shortest_prefix_fwd, _shortest_prefix_bwd)
+
+
 @register('sparse_experts', f32_only=True)
 def sparse_experts(x, router_weight, router_bias, experts_gate, experts_up,
                    experts_down, experts_per_token=2, first_expert=0,
@@ -179,31 +321,16 @@ def sparse_experts(x, router_weight, router_bias, experts_gate, experts_up,
         sizes = (local[:, None] == jnp.arange(held, dtype=jnp.int32)
                  ).sum(0, dtype=jnp.int32)
         n_live = sizes.sum()
+    leaves = (experts_up, experts_down) if experts_gate is None \
+        else (experts_gate, experts_up, experts_down)
+    ladder = prefix_ladder(order.shape[0], held, router_weight.shape[0])
     with jax.named_scope(SCOPE):
-        # pair p is token p // k: the sorted rows are a permutation of
-        # the tokens repeated k times
-        rows = _permute(jnp.repeat(tokens, k, axis=0), order, inverse)
-        rows = _live_rows(rows, n_live)
-        # the weight goes onto the narrow side of the down projection
-        # (linear, so the same sum): what is kept for the weights'
-        # gradient is then X wide, not U
-        if activation == 'swiglu':
-            hidden = _gated(
-                _grouped(rows, experts_gate, sizes),
-                _grouped(rows, experts_up, sizes),
-                _permute(weights.reshape(-1, 1).astype(x.dtype), order,
-                         inverse))
+        column = weights.reshape(-1, 1).astype(x.dtype)
+        routing = (order, inverse, sizes, n_live)
+        if len(ladder) == 1:
+            out = _prefix(ladder[0], activation, k, routing, tokens, column,
+                          leaves)
         else:
-            # on the TPU a grouped product leaves the rows past its last
-            # group as the buffer held them (not zeros, though the rows
-            # that went in are): relu(that)^2 times the backward's like
-            # rows would be the gradient of an absent pair's weight, and
-            # through it the router's. The dead rows' weights are masked,
-            # forward and backward, so that it is 0.
-            hidden = _squared(
-                _grouped(rows, experts_up, sizes),
-                _live_rows(_permute(weights.reshape(-1, 1).astype(x.dtype),
-                                    order, inverse), n_live))
-        out = _live_rows(_grouped(hidden, experts_down, sizes), n_live)
-        out = _permute(out, inverse, order).reshape(-1, k, units).sum(1)
+            out = _shortest_prefix(ladder, activation, k, routing, tokens,
+                                   column, leaves)
         return out.reshape(shape).astype(x.dtype)

@@ -359,10 +359,33 @@ def test_rms_norm_fwd_bwd_compiles(one_chip, on_tpu, units):
     assert _compile(jax.grad(loss, argnums=(0, 1)), x, g) == 1
 
 
+def _assert_every_rung_takes_the_grouped_kernels(text, held, a_rung):
+    """A cell's 2,048 tokens x 6 on ``held`` of 128 experts: each prefix
+    of its ladder (``ops/experts.py`` ``prefix_ladder``) has ``a_rung``
+    grouped-matmul kernels over its own rows, forward (once more for the
+    backward's own recomputation) and the two gradients of each, and no
+    dense product over every expert; and its operations carry the scope
+    ``rows_<P>`` inside ``mx.experts``, forward and backward, which is
+    how a profile says which rung ran."""
+    from mxnet_tpu.ops.experts import SCOPE, prefix_ladder
+    ladder = prefix_ladder(MLA_ROWS * MLA_SEQ * 6, held, 128)
+    assert 1 < len(ladder) <= 5 and ladder[-1] == MLA_ROWS * MLA_SEQ * 6
+    kernels = [line for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    for p in ladder:
+        assert sum(f'f32[{p},' in line for line in kernels) == a_rung, p
+        assert f'f32[{held},{p},' not in text
+        names = re.findall(rf'op_name="([^"]*rows_{p}\b[^"]*)"', text)
+        assert names and all(SCOPE in n[:n.index('rows_')] for n in names)
+        assert {n.startswith('jit(loss)/transpose(') for n in names} == \
+            {False, True}
+
+
 def test_sparse_experts_take_the_grouped_kernels(one_chip):
     """jax.lax.ragged_dot at the cell's shapes lowers to the compiler's
-    own grouped-matmul kernels, forward and backward, and not to the
-    dense product over every expert (an f32[16, 12288, ...] buffer)."""
+    own grouped-matmul kernels, forward and backward, on every rung of
+    the cell's ladder, and not to the dense product over every expert
+    (an f32[16, rows, ...] buffer)."""
     from mxnet_tpu.ops.experts import sparse_experts
     s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
                                             sharding=one_chip)
@@ -376,8 +399,8 @@ def test_sparse_experts_take_the_grouped_kernels(one_chip):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
         *shapes).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') >= 9
-    assert 'f32[16,12288,' not in text
+    # three products forward, gate and up again, six gradients
+    _assert_every_rung_takes_the_grouped_kernels(text, 16, 11)
 
 
 @pytest.mark.parametrize('shape', MLA_ADAM_SHAPES, ids=str)
@@ -519,7 +542,8 @@ def test_grouped_query_attention_takes_the_flash_pair(one_chip, on_tpu):
 def test_un_gated_experts_take_the_grouped_kernels(one_chip):
     """relu2 experts at the cell's shapes (8 held of 128, 1856 wide): the
     two grouped products and their gradients lower to the compiler's own
-    grouped-matmul kernels, as the three of a SwiGLU do above."""
+    grouped-matmul kernels on every rung of the cell's ladder, as the
+    three of a SwiGLU do above."""
     from mxnet_tpu.ops.experts import sparse_experts
     s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
                                             sharding=one_chip)
@@ -534,8 +558,8 @@ def test_un_gated_experts_take_the_grouped_kernels(one_chip):
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4))).lower(
         *shapes).compile().as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') >= 6
-    assert 'f32[8,12288,' not in text
+    # two products forward, up again, four gradients
+    _assert_every_rung_takes_the_grouped_kernels(text, 8, 7)
 
 
 @pytest.mark.parametrize('shape', NH_ADAM_SHAPES, ids=str)

@@ -287,7 +287,9 @@ def layer_weights(seed=5, scale=0.3):
             'experts_up': draw(EXPERTS, SIZE, UNITS),
             'experts_down': draw(EXPERTS, UNITS, SIZE),
             'shared_up': draw(SHARED, UNITS),
-            'shared_down': draw(UNITS, SHARED)}
+            'shared_down': draw(UNITS, SHARED),
+            # for the gated form of the shared op; the model has none
+            'experts_gate': draw(EXPERTS, SIZE, UNITS)}
 
 
 LAYER_CFG = dict(hidden_size=UNITS, n_routed_experts=EXPERTS,
@@ -344,6 +346,177 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer():
     close(share(lp, range(6, 6 + each))(mx.np.array(x)).asnumpy(), want_cut)
 
 
+def dense_loop(lp, cfg, u, activation):
+    """The held experts' part of the layer an expert at a time, every
+    token through every held expert."""
+    first, held = cfg.get('first_expert', 0), cfg['n_routed_experts']
+    w = ref.expert_weights(lp, cfg, u)[..., first:first + held]
+    out = jnp.zeros_like(u)
+    for j in range(held):
+        up = u @ lp['experts_up'][j].T
+        hidden = jax.nn.silu(u @ lp['experts_gate'][j].T) * up \
+            if activation == 'swiglu' else jnp.square(jax.nn.relu(up))
+        out = out + w[..., j:j + 1] * (hidden @ lp['experts_down'][j].T)
+    return out
+
+
+def leaf_names(activation):
+    return ('experts_gate',) * (activation == 'swiglu') + ref.STACKED
+
+
+def held_experts(activation, x, router_w, router_b, first, *leaves):
+    """``sparse_experts`` for the consecutive experts from ``first`` on
+    whose stacked ``leaves`` (a gate first, under swiglu) are given."""
+    return mx.ops.experts.sparse_experts(
+        x, router_w, router_b, leaves[0] if activation == 'swiglu' else None,
+        *leaves[-2:], experts_per_token=PER_TOKEN, first_expert=first,
+        routed_scaling_factor=2.5, activation=activation)
+
+
+# 512 tokens x 3 pairs on 4 of 32 experts: 192 live rows expected of
+# 1536, and a ladder with two rungs under the whole buffer
+TOKENS, FIRST, HELD = 512, 8, 4
+LADDER = (512, 1024, 1536)
+# a router's bias and the rung it sends the layer to
+ROUTINGS = {
+    'none_held': (dict.fromkeys(range(FIRST, FIRST + HELD), -9.0), 512),
+    'expected_share': ({}, 512),
+    'one_rung_up': ({FIRST + 1: 9.0}, 1024),
+    'all_held': (dict.fromkeys(range(FIRST, FIRST + 3), 9.0), 1536),
+}
+
+
+def partly_held(activation, routing, seed=9):
+    """(the op on its differentiable arguments, the dense loop on the
+    same, the arguments, live rows) of a layer that holds HELD of its
+    EXPERTS experts under the bias of ``routing``."""
+    lp = layer_weights(seed=seed)
+    bias = np.asarray(lp['router_b']).copy()
+    for expert, b in ROUTINGS[routing][0].items():
+        bias[expert] = b
+    lp['router_b'] = jnp.asarray(bias)
+    names = leaf_names(activation)
+    cut = dict(LAYER_CFG, n_routed_experts=HELD, first_expert=FIRST)
+    x = jnp.asarray(np.random.default_rng(1).normal(0, 1, (TOKENS, UNITS)),
+                    jnp.float32)
+    args = (x, lp['router_w'], *(lp[n][FIRST:FIRST + HELD] for n in names))
+
+    def got(x, rw, *leaves):
+        return held_experts(activation, x, rw, lp['router_b'], FIRST, *leaves)
+
+    def want(x, rw, *leaves):
+        return dense_loop(dict(lp, router_w=rw, **dict(zip(names, leaves))),
+                          cut, x, activation)
+
+    chosen, _ = mx.ops.experts.route(x, lp['router_w'], lp['router_b'],
+                                     PER_TOKEN, 'sigmoid', True, 2.5)
+    live = int(((chosen >= FIRST) & (chosen < FIRST + HELD)).sum())
+    return got, want, args, live
+
+
+def agree_with_the_dense_loop(activation, routing):
+    """Output and the gradient of every differentiable argument."""
+    got, want, args, _ = partly_held(activation, routing)
+    sq = lambda f: lambda *a: (f(*a) ** 2).sum()
+    every = tuple(range(len(args)))
+    with jax.default_matmul_precision('highest'):
+        out, grads = want(*args), jax.grad(sq(want), every)(*args)
+    close(got(*args), out)
+    for a, e in zip(jax.grad(sq(got), every)(*args), grads):
+        close(a, e)
+
+
+@pytest.mark.parametrize('routing', ROUTINGS)
+@pytest.mark.parametrize('activation', ['swiglu', 'relu2'])
+def test_every_rung_of_the_ladder_is_the_dense_loop(activation, routing):
+    """A layer that holds 4 of its 32 experts computes a prefix of its
+    sorted buffer: whichever rung the routing lands on (no pair on a
+    held expert; the expected share; one rung up; every token on held
+    experts, the whole buffer, nothing dropped), the output and the
+    gradients of x, the router and the stacked leaves are the dense
+    loop's."""
+    assert mx.ops.experts.prefix_ladder(
+        TOKENS * PER_TOKEN, HELD, EXPERTS) == LADDER
+    _, _, _, live = partly_held(activation, routing)
+    rung = ROUTINGS[routing][1]
+    assert live <= rung and all(p >= rung or p < live for p in LADDER), live
+    assert (live == 0) == (routing == 'none_held')
+    assert (live == TOKENS * PER_TOKEN) == (routing == 'all_held')
+    agree_with_the_dense_loop(activation, routing)
+
+
+@pytest.mark.parametrize('activation', ['swiglu', 'relu2'])
+def test_the_shares_of_a_cut_buffer_add_up_to_the_uncut_layer(activation):
+    """Eight chips hold four experts each and each computes a prefix:
+    their parts add up to what one chip that holds all 32 gives over
+    the whole buffer."""
+    lp = layer_weights(seed=9)
+    x = jnp.asarray(np.random.default_rng(1).normal(0, 1, (TOKENS, UNITS)),
+                    jnp.float32)
+    op = lambda first, n: held_experts(
+        activation, x, lp['router_w'], lp['router_b'], first,
+        *(lp[name][first:first + n] for name in leaf_names(activation)))
+    parts = [np.asarray(op(first, HELD)) for first in range(0, EXPERTS, HELD)]
+    assert all(np.abs(p).max() > 0 for p in parts)
+    close(sum(parts), op(0, EXPERTS))
+
+
+@pytest.mark.parametrize('activation', ['swiglu', 'relu2'])
+def test_a_partly_held_layer_keeps_its_arguments_and_the_routing(activation):
+    """What ``jax.vjp`` keeps of a layer with a ladder is no more than
+    its arguments and the routing (a row's order, inverse and weight a
+    pair, a count an expert): JAX would keep for a differentiated
+    ``cond`` the residuals of every branch, each of its buffer's size."""
+    got, _, args, _ = partly_held(activation, 'expected_share')
+    _, vjp = jax.vjp(got, *args)
+    kept = sum(a.nbytes for a in jax.tree_util.tree_leaves(vjp))
+    pairs = TOKENS * PER_TOKEN
+    # the router's own: scores and logits (tokens, E) twice, the chosen
+    # and their weights a pair, a few counts
+    routing = 2 * TOKENS * EXPERTS * 4 + 8 * pairs * 4 + 1024
+    assert kept <= sum(a.nbytes for a in args) + routing, kept
+    # a row of the buffer is UNITS wide: one buffer-sized array is more
+    assert kept < sum(a.nbytes for a in args) + pairs * UNITS * 4
+
+
+def _conds(jaxpr):
+    """The ``cond`` equations of a jaxpr, those inside its equations'
+    own jaxprs (a jit, a custom_vjp's call) among them."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == 'cond':
+            found.append(eqn)
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _conds(sub)
+    return found
+
+
+@pytest.mark.parametrize('activation', ['swiglu', 'relu2'])
+def test_a_fully_held_layer_has_no_cond_and_a_partly_held_one_has_one(
+        activation):
+    ladder = mx.ops.experts.prefix_ladder
+    # the cells' own: 2,048 tokens x 6 on 16 and on 8 of 128 experts
+    for held in (16, 8):
+        rungs = ladder(12288, held, 128)
+        assert len(rungs) <= 5 and rungs[-1] == 12288
+        assert rungs[0] >= 1.5 * 12288 * held / 128
+        assert all(p % 512 == 0 for p in rungs)
+        assert list(rungs) == sorted(set(rungs))
+    assert ladder(12288, 128, 128) == (12288,)
+    assert ladder(60, 4, 8) == (60,)       # shorter than a tile: no rung
+    got, _, args, _ = partly_held(activation, 'expected_share')
+    conds = _conds(jax.make_jaxpr(got)(*args).jaxpr)
+    assert len(conds) == 1 and len(conds[0].params['branches']) == len(LADDER)
+    whole = lambda x, rw, *leaves: held_experts(
+        activation, x, rw, layer_weights(seed=9)['router_b'], 0, *leaves)
+    x, rw, *leaves = args
+    every = [jnp.concatenate([a] * (EXPERTS // HELD)) for a in leaves]
+    assert _conds(jax.make_jaxpr(whole)(x, rw, *every).jaxpr) == []
+    assert _conds(jax.make_jaxpr(jax.grad(
+        lambda *a: whole(*a).sum(), (0, 1)))(x, rw, *every).jaxpr) == []
+
+
 def test_the_un_gated_experts_gradients_agree_with_the_dense_loop():
     lp = layer_weights(seed=9)
     held = range(8, 16)
@@ -369,13 +542,15 @@ def test_the_un_gated_experts_gradients_agree_with_the_dense_loop():
         close(a, e)
 
 
+@pytest.mark.parametrize('routing', ['expected_share', 'one_rung_up'])
+@pytest.mark.parametrize('activation', ['swiglu', 'relu2'])
 def test_what_a_grouped_product_leaves_past_its_groups_reaches_no_gradient(
-        monkeypatch):
+        monkeypatch, activation, routing):
     """On the TPU the rows of a grouped product past its last group are
     whatever the buffer held (PERF.md section 7; the CPU writes zeros).
-    Here they are dirtied on purpose, forward and backward: the un-gated
-    experts' outputs and every gradient, the router's among them, are
-    still the dense loop's."""
+    Here they are dirtied on purpose, forward and backward, in a prefix
+    that has such rows: both forms' outputs and every gradient, the
+    router's among them, are still the dense loop's."""
     real = jax.lax.ragged_dot
 
     def dirty(lhs, rhs, group_sizes, **kw):
@@ -384,7 +559,16 @@ def test_what_a_grouped_product_leaves_past_its_groups_reaches_no_gradient(
         return jnp.where(live, out, 7.5)
 
     monkeypatch.setattr(jax.lax, 'ragged_dot', dirty)
-    test_the_un_gated_experts_gradients_agree_with_the_dense_loop()
+    _, _, _, live = partly_held(activation, routing)
+    assert live < ROUTINGS[routing][1]        # the prefix has dead rows
+    # a rung's body is traced once for all layers of a shape: here it
+    # has to be traced anew, over the dirtied product, and not be kept
+    fresh = mx.ops.experts._branches.cache_clear
+    fresh()
+    try:
+        agree_with_the_dense_loop(activation, routing)
+    finally:
+        fresh()
 
 
 # ------------------------------------------------ what a profile's reader finds
