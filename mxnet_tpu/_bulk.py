@@ -70,6 +70,7 @@ Correctness guards:
   tracing all bypass bulking (checked by the registry / via tracer inputs).
 """
 
+import collections
 import functools
 import os
 import threading
@@ -394,6 +395,8 @@ class _Segment:
         boundary = self.boundary if self.ctx is None \
             else self.ctx.lift(self.boundary)
         outs = plan.jfwd(*boundary)
+        if span.live:
+            span.set(**launch_attrs(outs[0] if outs else None))
 
         for i, ref in enumerate(live_refs):
             ref.value = outs[i]
@@ -556,6 +559,77 @@ def note_unbulked(raws):
         if isinstance(r, jax.core.Tracer):
             return
     _st.unbulked += 1
+
+
+# ---------------------------------------------------------------- the queue
+class LaunchRecord:
+    """What the device still had queued when a launch was enqueued: one
+    output of each of the last ``WATCHED`` launches of the train path (a
+    compiled call's forward, a tape node's vjp, a segment's flush, the
+    fused update), of every thread, since they share the device.
+
+    An output is held by weak reference, so nothing is kept alive and no
+    donated buffer is pinned. One that was collected or deleted (donated
+    to a later launch) counts as finished, and ``is_ready()`` is never
+    asked of it: on a deleted array it kills the process on the CPU
+    client (jax 0.9). Such an output was, as a rule, the operand of a
+    later launch, which is watched itself: ``ahead`` 0 stays exact."""
+
+    WATCHED = 8
+
+    def __init__(self):
+        self._watched = collections.deque(maxlen=self.WATCHED)
+        self._lock = threading.Lock()
+
+    def __len__(self):
+        return len(self._watched)
+
+    @staticmethod
+    def _pending(ref):
+        out = ref()
+        return out is not None and not out.is_deleted() \
+            and not out.is_ready()
+
+    def note(self, out):
+        """A launch that made ``out`` (None: nothing to watch) has just
+        been enqueued. Returns ``ahead``: how many of the earlier
+        launches watched had not finished on the device. 0 means the
+        device had run out of work, and idled for whatever part of this
+        launch outlasted what was queued."""
+        with self._lock:
+            pending = [r for r in self._watched if self._pending(r)]
+            self._watched.clear()
+            self._watched.extend(pending)
+            if out is not None:
+                self._watched.append(weakref.ref(out))
+            return len(pending)
+
+
+_launches = LaunchRecord()
+
+
+def launch_attrs(out):
+    """A launch span's attributes once its call has returned, for the
+    launch that made the array ``out`` (one of its outputs; None or a
+    tracer: nothing to watch): ``ahead`` (:class:`LaunchRecord`) and,
+    where the client keeps memory statistics (the CPU's does not),
+    ``in_use``: bytes in use on the fullest of the devices that hold
+    ``out``, this launch's outputs allocated. Computed only while a span
+    listens (``if span.live``)."""
+    if not isinstance(out, jax.Array) or isinstance(out, jax.core.Tracer):
+        out = None
+    attrs = {'ahead': _launches.note(out)}
+    used = None if out is None else bytes_in_use(out.sharding.device_set)
+    if used is not None:
+        attrs['in_use'] = used
+    return attrs
+
+
+def bytes_in_use(devices):
+    """``bytes_in_use`` of the fullest of ``devices`` by their
+    ``memory_stats()``; None where none keeps statistics."""
+    used = [(d.memory_stats() or {}).get('bytes_in_use') for d in devices]
+    return max((u for u in used if u is not None), default=None)
 
 
 def reset():

@@ -371,7 +371,10 @@ def _backward(heads, head_grads, retain_graph, train_mode, variables,
                     if launch.live:
                         launch.set(
                             n_out=sum(c is not None for c in in_cots),
-                            traced=int(vjp_traces() != traces))
+                            traced=int(vjp_traces() != traces),
+                            **_bulk.launch_attrs(next(
+                                (c for c in in_cots if c is not None),
+                                None)))
             else:
                 in_cots = _node_vjp(node, present, indexed)
             for parent, cot in zip(node.parents, in_cots):

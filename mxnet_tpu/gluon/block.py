@@ -790,12 +790,15 @@ class _CachedGraph:
         faster than the device would fill the chip with them. So a
         recorded call first waits for the last backward launched from
         this graph; the update that follows a backward on the device
-        covers the forward's dispatch."""
+        covers the forward's dispatch. The wait is the span
+        ``mx.graph.await``, inside ``mx.graph.flush``, opened only where
+        there is a backward to wait for."""
         import jax
 
         launched, self._backward = self._backward, None
         if launched is not None:
-            jax.block_until_ready(launched)
+            with _trace.child_span('mx.graph.await'):
+                jax.block_until_ready(launched)
 
     def _call_static(self, args, span):
         import jax
@@ -966,10 +969,12 @@ class _CachedGraph:
                                name='_CachedOp', lift=False,
                                record=record if entry.vjp else None)
                 if launch.live:
+                    first = res[0] if isinstance(res, tuple) else res
                     launch.set(
                         n_out=entry.vjp.n_out if recorded else
                         len(res) if isinstance(res, tuple) else 1,
-                        traced=int(_tape.vjp_traces() != traces))
+                        traced=int(_tape.vjp_traces() != traces),
+                        **_bulk.launch_attrs(first._data))
         except DynamicShapeError:
             # a dynamic-output-shape op inside the graph (boolean_mask,
             # unique, ...): permanently switch this block to eager

@@ -650,6 +650,7 @@ class Trainer:
     def _fused_update(self, live):
         import numpy as _onp
         import jax.numpy as jnp
+        from .. import _bulk
 
         opt = self._optimizer
         fn, donated, kept, graws = self._fused_program(live)
@@ -675,14 +676,15 @@ class Trainer:
             if hyper.live:
                 hyper.set(uploaded=3 if fresh else 1)
         with _trace.child_span('mx.trainer.launch') as launch:
+            new_ws, new_ss = fn(donated, kept, graws, lrs, wds, ts)
             if launch.live:
                 n_state = sum(map(len, donated[1]))
                 n_kept = 0 if kept is None else sum(
                     e is not None for l in (kept[0], *kept[1]) for e in l)
                 launch.set(n_in=2 * len(live) + n_state + 3,
                            n_out=len(live) + n_state,
-                           donated=len(live) + n_state - n_kept)
-            new_ws, new_ss = fn(donated, kept, graws, lrs, wds, ts)
+                           donated=len(live) + n_state - n_kept,
+                           **_bulk.launch_attrs(new_ws[0]))
         for (i, param), nw, ns in zip(live, new_ws, new_ss):
             datas = param.list_data()
             datas[0]._rebind(nw)

@@ -18,20 +18,24 @@ from mxnet_tpu.telemetry import trace as _trace
 TREE = {
     'train.step': {'mx.graph.call', 'mx.tape.backward', 'mx.trainer.step'},
     'mx.graph.call': {'mx.graph.flush', 'mx.graph.launch'},
+    'mx.graph.flush': {'mx.graph.await'},
     'mx.tape.backward': {'mx.tape.flush', 'mx.tape.vjp'},
     'mx.tape.flush': {'mx.bulk.flush'},
     'mx.trainer.step': {'mx.trainer.hyper', 'mx.trainer.launch'},
 }
 ATTRS = {
     'mx.graph.call': {'n_in', 'n_params', 'compiled'},
-    'mx.graph.launch': {'n_out', 'traced'},
+    'mx.graph.launch': {'n_out', 'traced', 'ahead'},
     'mx.tape.backward': {'n_nodes', 'n_vars'},
-    'mx.tape.vjp': {'n_out', 'traced'},
-    'mx.bulk.flush': {'n_ops', 'n_out', 'compiled'},
+    'mx.tape.vjp': {'n_out', 'traced', 'ahead'},
+    'mx.bulk.flush': {'n_ops', 'n_out', 'compiled', 'ahead'},
     'mx.trainer.step': {'n_params'},
     'mx.trainer.hyper': {'uploaded'},
-    'mx.trainer.launch': {'n_in', 'n_out', 'donated'},
+    'mx.trainer.launch': {'n_in', 'n_out', 'donated', 'ahead'},
 }
+# the spans round a jitted call down to PjRt
+LAUNCHES = ('mx.graph.launch', 'mx.tape.vjp', 'mx.bulk.flush',
+            'mx.trainer.launch')
 
 
 @pytest.fixture(autouse=True)
@@ -151,9 +155,11 @@ def test_the_spans_carry_their_counts(bulked_steps):
     events, _, n_params, handed_back = bulked_steps[2]
     for e in events:
         if e['name'] in ATTRS:
+            # in_use is not among them: the CPU client keeps no memory
+            # statistics
             assert set(e['attrs']) == ATTRS[e['name']], e['name']
-    one = {e['name']: e['attrs'] for e in events
-           if e['name'] != 'mx.tape.vjp' and 'attrs' in e}
+    one = {e['name']: {k: v for k, v in e['attrs'].items() if k != 'ahead'}
+           for e in events if e['name'] != 'mx.tape.vjp' and 'attrs' in e}
     assert one['mx.graph.call'] == {'n_in': 3, 'n_params': n_params,
                                     'compiled': 0}
     # the recorded forward's output and residuals; the programs were
@@ -182,7 +188,7 @@ def test_the_number_of_spans_does_not_depend_on_depth(bulked_steps):
     names = {layers: sorted(e['name'] for e in bulked_steps[layers][0])
              for layers in (2, 4)}
     assert names[2] == names[4]
-    assert len(names[2]) == 12          # train.step and eleven of its own
+    assert len(names[2]) == 13          # train.step and twelve of its own
     assert bulked_steps[4][2] > bulked_steps[2][2]     # more parameters
 
 
@@ -192,6 +198,33 @@ def test_bulk_stats_grow_with_the_flushes(bulked_steps):
     assert grew['flushes'] == len(flushes) == 1
     assert flushes[0]['attrs']['n_ops'] >= flushes[0]['attrs']['n_out'] > 0
     assert grew['unbulked'] == 0 and grew['compiles'] == 0
+
+
+def test_every_launch_says_how_much_work_was_queued_ahead_of_it(
+        bulked_steps):
+    events = bulked_steps[2][0]
+    launches = [e for e in events if e['name'] in LAUNCHES]
+    assert {e['name'] for e in launches} == set(LAUNCHES)
+    # the earlier launches still running on the device, of the eight
+    # watched
+    for e in launches:
+        assert 0 <= e['attrs']['ahead'] <= _bulk.LaunchRecord.WATCHED
+
+
+def test_the_wait_for_the_last_backward_is_its_own_span(bulked_steps):
+    events = bulked_steps[2][0]
+    by_id = {e['span']: e for e in events}
+    wait, = [e for e in events if e['name'] == 'mx.graph.await']
+    assert by_id[wait['parent']]['name'] == 'mx.graph.flush'
+    assert 'attrs' not in wait
+
+
+def test_a_call_with_no_backward_to_wait_for_opens_no_wait():
+    loop = Loop(2)
+    with telemetry.span('train.step', step=0):
+        loop.step()
+    names = [e['name'] for e in telemetry.events()]
+    assert 'mx.graph.flush' in names and 'mx.graph.await' not in names
 
 
 def test_with_bulking_off_every_eager_op_is_counted_unbulked():
