@@ -380,6 +380,11 @@ def _backward(heads, head_grads, retain_graph, train_mode, variables,
             for parent, cot in zip(node.parents, in_cots):
                 _push(parent, cot)
             if not retain_graph:
+                # a recorded graph call's residuals go back to its entry,
+                # for the next recorded forward to write over
+                spent = getattr(node.vjp_fn, 'spent', None)
+                if spent is not None:
+                    spent()
                 node.vjp_fn = None
     finally:
         set_training(prev_train)

@@ -333,6 +333,19 @@ class _VjpPrograms:
     from the arguments likewise. ``backward`` rebuilds the ``vjp_fn`` and
     calls it; nothing is donated into it (``retain_graph=True`` calls it
     twice).
+
+    The forward writes its residuals over those of the call before it.
+    When the tape is done with a call's vjp (a backward without
+    ``retain_graph``), :meth:`_Vjp.spent` hands its residual buffers back
+    here as ``spares``, and the next call takes them as a last argument,
+    donated and otherwise unused: PjRt writes each residual into a spare
+    of its aval instead of allocating one. A call that finds no spares
+    (an entry's first, a second forward before a backward, one after
+    ``retain_graph``) takes fresh buffers from a program that only
+    allocates. The graph's wait for the last backward
+    (``_CachedGraph._await_backward``) is what makes the spares free by
+    then. An entry whose forward hands back no residuals of its own
+    (every one under ``remat``) has nothing to recycle and takes none.
     """
 
     def __init__(self, graph, prog, jit_kwargs, place, pack,
@@ -349,9 +362,19 @@ class _VjpPrograms:
         self.aux_forwarded = None   # per aux leaf: its position, or None
         self.out_avals = None
         self.n_out = None           # buffers the forward hands back
+        self.n_res = None           # of them the residuals
+        self.recycles = False       # whether the forward takes spares
+        # the residual buffers of a spent call, until the next call takes
+        # them; and how many of them the last call wrote over
+        self.spares = None
+        self.recycled = 0
         # a donated aux buffer is gone after the call: as a residual it
         # is returned like any other
         self._donated = jit_kwargs.get('donate_argnums', ())
+        self._jit_kwargs = jit_kwargs
+        self._mesh = main_shardings is not None
+        self.forward = None         # built on the first call (_launcher)
+        self._fresh = None
 
         def recorded_forward(rng_key, in_raws, main_raws, *aux_parts):
             graph.vjp_traces += 1
@@ -383,6 +406,7 @@ class _VjpPrograms:
                             if at is None)
             self.out_avals = [(o.shape, o.dtype) for o in outs]
             self.n_out = len(outs) + len(aux_out) + len(residuals)
+            self.n_res = len(residuals)
             return outs, aux_out, tuple(residuals)
 
         def recorded_backward(residuals, forwarded, cots):
@@ -403,8 +427,71 @@ class _VjpPrograms:
             return tuple(None if c.dtype == jax.dtypes.float0 else c
                          for c in in_cots + main_cots)
 
-        self.forward = jax.jit(recorded_forward, **jit_kwargs)
+        # traced once an entry, by _launcher; inline, so that the program
+        # launched holds its equations as its own
+        self._traced = jax.jit(recorded_forward, inline=True)
         self.backward = jax.jit(recorded_backward)
+
+    def _launcher(self, head, anchor):
+        """Build ``forward`` and ``_fresh`` from the residuals that one
+        trace of the forward at the arguments ``head`` finds.
+
+        The forward is compiled before any spare is made, so that none
+        stands on the device while it compiles. Without a mesh the spares
+        are placed as the arguments are (on the device of ``anchor``, the
+        first committed input or weight, committed as the residuals come
+        back: a spare that is not would compile the forward again), and
+        the jitted call finds that compile. Under a mesh the residuals'
+        shardings are the compiler's to choose: each residual is held to
+        its spare's (``shard_alike``), the spares' placement is left open
+        in the compile, and they are made as it chose. Either way the
+        forward compiles once for a placement."""
+        import jax
+        import jax.numpy as jnp
+        from jax.experimental.shard_alike import shard_alike
+
+        traced, mesh = self._traced, self._mesh
+        residuals = jax.eval_shape(traced, *head)[2]
+        self.recycles = bool(residuals) and not any(
+            jax.dtypes.issubdtype(r.dtype, jax.dtypes.extended)
+            for r in residuals)
+
+        def recorded_forward(*args):
+            *args, spares = args
+            outs, aux_out, residuals = traced(*args)
+            if mesh and spares:
+                residuals = tuple(shard_alike(r, s)[0]
+                                  for r, s in zip(residuals, spares))
+            return outs, aux_out, residuals
+
+        jit_kwargs = dict(self._jit_kwargs)
+        if 'in_shardings' in jit_kwargs:
+            jit_kwargs['in_shardings'] += (None,)
+        if not self.recycles:
+            self.forward = jax.jit(recorded_forward, **jit_kwargs)
+            self._fresh = lambda anchor: ()
+            return
+        # the spares are otherwise unused: kept, so that they can be
+        # donated
+        jit_kwargs['keep_unused'] = True
+        jit_kwargs['donate_argnums'] = self._donated + (len(head),)
+        jitted = jax.jit(recorded_forward, **jit_kwargs)
+        avals = [(r.shape, r.dtype) for r in residuals]
+        on = None if mesh or anchor is None else anchor.sharding
+        compiled = jitted.lower(*head, tuple(
+            jax.ShapeDtypeStruct(s, d, sharding=on) for s, d in avals)
+        ).compile()
+        if mesh:
+            self.forward = compiled
+            self._fresh = jax.jit(
+                lambda anchor: tuple(jnp.zeros(s, d) for s, d in avals),
+                out_shardings=compiled.input_shardings[0][-1])
+        else:
+            self.forward = jitted
+            # an allocation: lax.empty writes nothing into the buffers
+            self._fresh = jax.jit(
+                lambda anchor: tuple(jax.lax.empty(s, d) for s, d in avals),
+                keep_unused=True)
 
     def _arguments(self, rng_key, in_raws, main_raws, aux_parts):
         """The forward's arguments that outlive the call, flat."""
@@ -418,8 +505,19 @@ class _VjpPrograms:
         """``(outs, aux_out, vjp_fn)`` of one recorded call."""
         rng_key, in_raws = self.place(rng_key, in_raws)
         aux_parts = self.pack(aux_raws)
-        outs, written, residuals = self.forward(rng_key, in_raws, main_raws,
-                                                *aux_parts)
+        head = (rng_key, in_raws, main_raws, *aux_parts)
+        anchor = next((a for a in (*in_raws, *main_raws)
+                       if getattr(a, 'committed', False)), None)
+        if self.forward is None:
+            self._launcher(head, anchor)
+        # one swap: a hand-back that races it is freed, never shared
+        spares, self.spares = self.spares, None
+        if spares and not self._mesh and anchor is not None and \
+                spares[0].sharding != anchor.sharding:
+            spares = None       # the weights moved: the spent set stays
+        self.recycled = len(spares) if spares is not None else 0
+        outs, written, residuals = self.forward(
+            *head, self._fresh(anchor) if spares is None else spares)
         args = self._arguments(rng_key, in_raws, main_raws, aux_parts)
         written = iter(written)
         aux_out = tuple(next(written) if at is None else args[at]
@@ -453,6 +551,14 @@ class _Vjp:
 
     def __call__(self, cots):
         return self.indexed(dict(enumerate(cots)))
+
+    def spent(self):
+        """The tape is done with this vjp (a backward without
+        ``retain_graph`` has run it): its residuals become its entry's
+        spares, one store, replacing a set no call has taken."""
+        residuals, self.residuals = self.residuals, None
+        if self.programs.recycles:
+            self.programs.spares = residuals
 
 
 class _CachedGraph:
@@ -974,7 +1080,12 @@ class _CachedGraph:
                         n_out=entry.vjp.n_out if recorded else
                         len(res) if isinstance(res, tuple) else 1,
                         traced=int(_tape.vjp_traces() != traces),
-                        **_bulk.launch_attrs(first._data))
+                        **_bulk.launch_attrs(first._data),
+                        # how many of its residuals the forward wrote
+                        # over the last backward's spent ones
+                        **({'residuals': entry.vjp.n_res,
+                            'recycled': entry.vjp.recycled}
+                           if recorded else {}))
         except DynamicShapeError:
             # a dynamic-output-shape op inside the graph (boolean_mask,
             # unique, ...): permanently switch this block to eager

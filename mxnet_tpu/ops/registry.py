@@ -306,7 +306,10 @@ def invoke(op_name, args, kwargs):
         # mx.analysis dead-code rule flagged exactly this in the zoo)
         kwargs.setdefault('key', _rng.next_key())
 
-    # split tracked NDArrays (incl. inside list/tuple args, e.g. concat)
+    # split tracked NDArrays (incl. inside list/tuple args, e.g. concat).
+    # ``fn`` fills their slots of ``consts``, which hold None: a bulk
+    # segment's cached plan keeps ``fn``, and an NDArray held there would
+    # keep its tape node, the graph that recorded it and all they hold
     arr_slots = []   # (pos, sub_index or None)
     arrays = []
     consts = list(args)
@@ -329,12 +332,14 @@ def invoke(op_name, args, kwargs):
             else:
                 arr_slots.append((i, None))
                 arrays.append(a)
+                consts[i] = None
         elif isinstance(a, (list, tuple)):
             consts[i] = list(a)
             for j, e in enumerate(a):
                 if isinstance(e, NDArray):
                     arr_slots.append((i, j))
                     arrays.append(e)
+                    consts[i][j] = None
     kw_arr = {k: v for k, v in kwargs.items() if isinstance(v, NDArray)
               and k not in op.static_argnames}
     kw_static = {k: (v._data if isinstance(v, NDArray) else v)

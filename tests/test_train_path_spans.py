@@ -25,7 +25,7 @@ TREE = {
 }
 ATTRS = {
     'mx.graph.call': {'n_in', 'n_params', 'compiled'},
-    'mx.graph.launch': {'n_out', 'traced', 'ahead'},
+    'mx.graph.launch': {'n_out', 'traced', 'ahead', 'residuals', 'recycled'},
     'mx.tape.backward': {'n_nodes', 'n_vars'},
     'mx.tape.vjp': {'n_out', 'traced', 'ahead'},
     'mx.bulk.flush': {'n_ops', 'n_out', 'compiled', 'ahead'},
@@ -163,8 +163,11 @@ def test_the_spans_carry_their_counts(bulked_steps):
     assert one['mx.graph.call'] == {'n_in': 3, 'n_params': n_params,
                                     'compiled': 0}
     # the recorded forward's output and residuals; the programs were
-    # built two steps ago and this call launched them
-    assert one['mx.graph.launch'] == {'n_out': handed_back, 'traced': 0}
+    # built two steps ago and this call launched them, writing every
+    # residual over the last step's spent ones
+    assert one['mx.graph.launch'] == {'n_out': handed_back, 'traced': 0,
+                                      'residuals': handed_back - 1,
+                                      'recycled': handed_back - 1}
     assert handed_back > 1
     assert one['mx.trainer.step'] == {'n_params': n_params}
     assert one['mx.trainer.hyper'] == {'uploaded': 1}
