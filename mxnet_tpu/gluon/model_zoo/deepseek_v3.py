@@ -55,7 +55,10 @@ __all__ = ['DeepseekV3Config', 'MLAttention', 'DecoderLayer',
 class DeepseekV3Config:
     """The published keys of a ``deepseek_v3`` ``config.json``, plus
     ``router_width`` (all experts; default ``n_routed_experts``) and
-    ``first_expert`` where ``n_routed_experts`` is a chip's share."""
+    ``first_expert`` where ``n_routed_experts`` is a chip's share, and
+    ``kimi_linear``'s ``mla_use_nope`` (no rotary embedding)."""
+
+    model_type = 'deepseek_v3'
 
     def __init__(self, vocab_size=129280, hidden_size=7168,
                  intermediate_size=18432, moe_intermediate_size=2048,
@@ -69,7 +72,7 @@ class DeepseekV3Config:
                  norm_topk_prob=True, n_group=1, topk_group=1,
                  hidden_act='silu', attention_bias=False, rope_scaling=None,
                  tie_word_embeddings=False, router_width=None,
-                 first_expert=0, **ignored):
+                 first_expert=0, mla_use_nope=False, **ignored):
         for what, ok in (
                 ('query compression (q_lora_rank)', q_lora_rank is None),
                 ('grouped choice of experts', n_group == topk_group == 1),
@@ -78,7 +81,7 @@ class DeepseekV3Config:
                 ('rope_scaling', rope_scaling is None),
                 ('tie_word_embeddings', not tie_word_embeddings)):
             if not ok:
-                raise NotImplementedError(f'deepseek_v3: {what}')
+                raise NotImplementedError(f'{self.model_type}: {what}')
         self.vocab_size = vocab_size
         self.units = hidden_size
         self.hidden_size = intermediate_size        # LlamaMLP's names
@@ -96,6 +99,7 @@ class DeepseekV3Config:
         self.v_head_dim = v_head_dim
         self.rms_norm_eps = rms_norm_eps
         self.rope_theta = float(rope_theta)
+        self.mla_use_nope = mla_use_nope
         self.routed_scaling_factor = routed_scaling_factor
         self.scoring_func = scoring_func
         self.norm_topk_prob = norm_topk_prob
@@ -117,7 +121,10 @@ def _rotary(x, theta):
 
 
 class MLAttention(HybridBlock):
-    """Multi-head latent attention, expanded form, causal."""
+    """Multi-head latent attention, expanded form, causal. Under the
+    published ``mla_use_nope`` (Kimi Linear) no rotary embedding is
+    applied: the ``qk_rope_head_dim`` columns of the queries and of the
+    one shared key enter the scores as they are."""
 
     def __init__(self, cfg):
         super().__init__()
@@ -125,6 +132,7 @@ class MLAttention(HybridBlock):
         self._nope, self._pe = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
         self._vd, self._latent = cfg.v_head_dim, cfg.kv_lora_rank
         self._theta = cfg.rope_theta
+        self._rotary = not cfg.mla_use_nope
         dense = lambda out, inp: nn.Dense(out, use_bias=False, flatten=False,
                                           in_units=inp)
         self.q_proj = dense(self._heads * (self._nope + self._pe), cfg.units)
@@ -139,10 +147,13 @@ class MLAttention(HybridBlock):
         b, s, _ = x.shape
         h, nope, pe, vd = self._heads, self._nope, self._pe, self._vd
         q = self.q_proj(x).reshape(b, s, h, nope + pe)
-        q_pe = _rotary(q[..., nope:], self._theta)
+        q_pe = q[..., nope:]
+        if self._rotary:
+            q_pe = _rotary(q_pe, self._theta)
         kva = self.kv_a_proj_with_mqa(x)
-        k_pe = _rotary(kva[..., self._latent:].reshape(b, s, 1, pe),
-                       self._theta)
+        k_pe = kva[..., self._latent:].reshape(b, s, 1, pe)
+        if self._rotary:
+            k_pe = _rotary(k_pe, self._theta)
         kv = self.kv_b_proj(self.kv_a_layernorm(kva[..., :self._latent]))
         kv = kv.reshape(b, s, h, nope + vd)
         q = mnp.concatenate([q[..., :nope], q_pe], axis=-1)
@@ -156,12 +167,13 @@ class MLAttention(HybridBlock):
 
 
 class DecoderLayer(HybridBlock):
-    """Pre-norm: latent attention, then a dense or a sparse FFN."""
+    """Pre-norm: latent attention, or what ``mixer(cfg, layer)`` makes,
+    then a dense or a sparse FFN."""
 
-    def __init__(self, cfg, layer):
+    def __init__(self, cfg, layer, mixer=None):
         super().__init__()
         self.input_layernorm = RMSNorm(cfg.units, cfg.rms_norm_eps)
-        self.self_attn = MLAttention(cfg)
+        self.self_attn = mixer(cfg, layer) if mixer else MLAttention(cfg)
         self.post_attention_layernorm = RMSNorm(cfg.units, cfg.rms_norm_eps)
         if cfg.is_sparse(layer):
             first = cfg.first_expert
@@ -186,15 +198,16 @@ class DecoderLayer(HybridBlock):
 
 
 class DeepseekV3Model(HybridBlock):
-    """Token embedding, the layers, the final norm."""
+    """Token embedding, the layers, the final norm; ``mixer`` as
+    ``DecoderLayer`` takes it."""
 
-    def __init__(self, cfg):
+    def __init__(self, cfg, mixer=None):
         super().__init__()
         self.cfg = cfg
         self.embed_tokens = nn.Embedding(cfg.vocab_size, cfg.units)
         self.layers = []
         for i in range(cfg.num_layers):
-            self.layers.append(DecoderLayer(cfg, i))
+            self.layers.append(DecoderLayer(cfg, i, mixer))
             self.register_child(self.layers[-1], f'layers{i}')
         self.norm = RMSNorm(cfg.units, cfg.rms_norm_eps)
 
@@ -208,10 +221,10 @@ class DeepseekV3Model(HybridBlock):
 class DeepseekV3ForCausalLM(HybridBlock):
     """(B, S) token ids -> (B, S, vocab) logits; the head is untied."""
 
-    def __init__(self, cfg):
+    def __init__(self, cfg, mixer=None):
         super().__init__()
         self.cfg = cfg
-        self.model = DeepseekV3Model(cfg)
+        self.model = DeepseekV3Model(cfg, mixer)
         self.lm_head = nn.Dense(cfg.vocab_size, use_bias=False,
                                 flatten=False, in_units=cfg.units)
 

@@ -54,17 +54,19 @@ _GATED_NORM = Op('gated_group_rms_norm', _gated_group_rms_norm)
 
 class _CausalConv(HybridBlock):
     """The depthwise causal convolution over the positions, with silu:
-    ``weight`` (channels, kernel), ``bias`` (channels,)."""
+    ``weight`` (channels, kernel), ``bias`` (channels,) or none."""
 
-    def __init__(self, channels, kernel):
+    def __init__(self, channels, kernel, use_bias=True):
         super().__init__()
         bound = 1.0 / math.sqrt(kernel)     # Conv1d's own, fan-in = kernel
         draw = _Filled(lambda shape: _np.random.uniform(-bound, bound, shape))
         self.weight = Parameter('weight', shape=(channels, kernel), init=draw)
-        self.bias = Parameter('bias', shape=(channels,), init=draw)
+        self.bias = Parameter('bias', shape=(channels,), init=draw) \
+            if use_bias else None
 
     def forward(self, x):
-        return _op('ssm_conv', x, self.weight.data(), self.bias.data())
+        return _op('ssm_conv', x, self.weight.data(),
+                   None if self.bias is None else self.bias.data())
 
 
 class _GateNorm(HybridBlock):

@@ -24,6 +24,7 @@ from . import nn            # noqa: F401
 from . import contrib       # noqa: F401
 from . import experts       # noqa: F401
 from . import ssm           # noqa: F401
+from . import kda           # noqa: F401
 from . import optimizer_ops  # noqa: F401
 from . import fft_ops       # noqa: F401
 from . import quantization_ops  # noqa: F401

@@ -570,3 +570,57 @@ def test_the_update_at_the_hybrids_shapes_is_one_fusion_in_place(
     among them: no custom call, no relayout of the leaf, the weight and
     both slots written over their donated buffers."""
     _assert_xlas_fusion_in_place(shape, one_chip)
+
+
+# ------------------------------------------------------------------------
+# The delta rule of the kimi_linear train path at kimi_linear_48b_a3b's
+# widths (chipbench/configs/kimi_linear_48b_a3b.json: 2 rows x 1024
+# positions, 2304 wide; KDA of 32 heads of 128, chunks of 64).
+
+KL_ROWS, KL_SEQ, KL_HEADS, KL_D, KL_CHUNK = 2, 1024, 32, 128, 64
+
+
+def test_the_kimi_shapes_are_what_the_model_has():
+    """602.4 M parameters under Adam, by the zoo's leaves and by the
+    benchmark's count, no array made."""
+    import json
+    import os
+    from mxnet_tpu.gluon.model_zoo.kimi_linear import (
+        KimiLinearConfig, KimiLinearForCausalLM)
+    from chipbench.flops.kimi_linear import moved_param_count
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, 'chipbench', 'configs',
+                           'kimi_linear_48b_a3b.json')) as f:
+        cfg = json.load(f)
+    net = KimiLinearForCausalLM(KimiLinearConfig(**cfg))
+    mixer = net.model.layers[0].self_attn
+    assert mixer.q_proj.weight.shape == (KL_HEADS * KL_D, 2304)
+    assert mixer._chunk == KL_CHUNK
+    params = net.collect_params()
+    # LlamaMLP's Dense layers learn their input width at the first call:
+    # 2304 for gate and up, the width of gate's output for down
+    width = lambda n, p: 2304 if 'down_proj' not in n else params[
+        n.replace('down_proj', 'gate_proj')].shape[0]
+    assert sum(math.prod(p.shape[:-1]) * (p.shape[-1] or width(n, p))
+               for n, p in params.items() if p.grad_req != 'null') \
+        == moved_param_count(cfg) == 602_433_408
+
+
+def test_kda_scan_fwd_bwd_compiles(one_chip):
+    """The chunked delta rule at the cell's shapes, forward and backward:
+    plain XLA (no custom call; the triangular solves are XLA's own), and
+    it never holds a whole chunk's (position, position, channel) decays:
+    its temporaries stay under half of one layer's of them (2.1 GB)."""
+    from mxnet_tpu.ops.kda import kda_scan
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                            sharding=one_chip)
+    qk = s(KL_ROWS, KL_SEQ, KL_HEADS, KL_D)
+
+    def loss(*a):
+        return (kda_scan(*a, chunk_size=KL_CHUNK) ** 2).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=tuple(range(5)))).lower(
+        qk, qk, qk, qk, s(KL_ROWS, KL_SEQ, KL_HEADS)).compile()
+    assert 'tpu_custom_call' not in compiled.as_text()
+    whole_chunk = 4 * KL_ROWS * KL_SEQ * KL_HEADS * KL_CHUNK * KL_D
+    assert compiled.memory_analysis().temp_size_in_bytes < whole_chunk / 2
