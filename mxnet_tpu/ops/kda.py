@@ -22,10 +22,17 @@ inside a chunk (float32, ``G_0 = 0``)::
     O          = ((q o e^G) S + A_qk V') / sqrt(K)
     S         <- Diag(e^{G_C}) S + (k o e^{G_C - G})^T V'
 
-The inverse is a triangular solve; the states are carried from chunk to
-chunk by a ``lax.scan`` over T / C steps. Plain ``jax.numpy``,
-differentiated by JAX; one form, no kernel. ``gluon.nn.KimiDeltaAttention``
-is the Block.
+``(I + A_kk)^-1`` is built by products (:func:`_unit_lower_inverse`):
+the inverses of the diagonal blocks of 1, 2, 4, ... positions are merged
+pairwise up to C, two (C, C) products a doubling at ``highest`` precision,
+so the MXU does it in log2(C) dependent steps where a triangular solve
+takes C. Applying it is a product for W and one for U, and
+:func:`_wy_solve` gives its own backward (``dR = X^T dY`` for each,
+``dA_kk = -(dRk W^T + dRv U^T)`` below the diagonal), which neither
+solves nor inverts again. The states are carried
+from chunk to chunk by a ``lax.scan`` over T / C steps. Plain
+``jax.numpy``, the rest differentiated by JAX; one form, no kernel.
+``gluon.nn.KimiDeltaAttention`` is the Block.
 
 No exponential is ever taken of a positive number: ``exp(G_i - G_j)`` for
 ``j <= i`` is at most 1, but ``exp(G_i) exp(-G_j)`` would overflow
@@ -84,24 +91,81 @@ def _decayed_products(x, k, g, strict):
     return out + off.reshape(*lead, length, length)
 
 
+def _product(a, b):
+    """a @ b as float32 work: at the TPU's default a float32 product is
+    one bfloat16 pass."""
+    return jnp.matmul(a, b, precision=lax.Precision.HIGHEST)
+
+
+def _unit_lower_inverse(n):
+    """``(I + N)^-1`` of the strictly lower part of ``n`` (..., C, C).
+
+    The inverse of a block lower triangular matrix of two blocks is
+    ``[[X11, 0], [-X22 N21 X11, X22]]``. With ``X`` the inverses of the
+    diagonal blocks of ``size`` positions side by side, the blocks of
+    twice that size are ``X - X O X``, ``O`` the part of ``N`` below each
+    pair's diagonal blocks: C is reached in ceil(log2 C) doublings (the
+    first needs no product). Every product is of inverses of blocks
+    and of ``N`` itself, never of powers of ``N``, whose entries grow
+    like binomial coefficients where neighbouring keys are alike."""
+    length = n.shape[-1]
+    rows = jnp.arange(length)
+
+    def below(size):
+        """Where a pair of blocks of ``size`` holds ``N21``."""
+        pair, block = rows // (2 * size), rows // size
+        return (pair[:, None] == pair[None, :]) \
+            & (block[:, None] > block[None, :])
+
+    x = jnp.eye(length, dtype=n.dtype) - jnp.where(below(1), n, 0)
+    size = 2
+    while size < length:
+        x = x - _product(_product(x, jnp.where(below(size), n, 0)), x)
+        size *= 2
+    return x
+
+
+@jax.custom_vjp
+def _wy_solve(n, rk, rv):
+    """``(I + N)^-1 Rk`` and ``(I + N)^-1 Rv`` for ``N`` the strictly
+    lower part of ``n`` (..., C, C), by the inverse's products; Rk
+    (..., C, K), Rv (..., C, V)."""
+    return _wy_solve_fwd(n, rk, rv)[0]
+
+
+def _wy_solve_fwd(n, rk, rv):
+    x = _unit_lower_inverse(n)
+    w, u = _product(x, rk), _product(x, rv)
+    return (w, u), (x, w, u)
+
+
+def _wy_solve_bwd(res, cot):
+    x, w, u = res
+    dw, du = cot
+    xt = jnp.swapaxes(x, -1, -2)
+    drk, drv = _product(xt, dw), _product(xt, du)
+    dn = _product(drk, jnp.swapaxes(w, -1, -2)) \
+        + _product(drv, jnp.swapaxes(u, -1, -2))
+    return -jnp.tril(dn, -1), drk, drv
+
+
+_wy_solve.defvjp(_wy_solve_fwd, _wy_solve_bwd)
+
+
 @jax.checkpoint
 def _chunk_local(q, k, v, g, beta):
     """What a chunk gives without the state it starts from.
 
     q, k: (..., C, K); v: (..., C, V); g: (..., C, K) float32, the running
     sum of the log decays inside the chunk; beta: (..., C). Returns (W, U,
-    A_qk, q o e^G, k o e^{G_C - G}, e^{G_C}). The (SUB, SUB, K) decays are
-    made again in the backward pass rather than kept."""
+    A_qk, q o e^G, k o e^{G_C - G}, e^{G_C}). The (SUB, SUB, K) decays and
+    the inverse are made again in the backward pass rather than kept."""
     dtype = q.dtype
-    length = q.shape[-2]
     a_kk = beta[..., :, None] * _decayed_products(k, k, g, strict=True)
     a_qk = _decayed_products(q, k, g, strict=False)
-    rhs = beta[..., None] * jnp.concatenate(
-        [k * jnp.exp(g).astype(dtype), v], axis=-1)
-    eye = jnp.eye(length, dtype=dtype)
-    wu = jax.scipy.linalg.solve_triangular(
-        eye + a_kk, rhs, lower=True, unit_diagonal=True)
-    w, u = wu[..., :k.shape[-1]], wu[..., k.shape[-1]:]
+    with jax.named_scope('wy_inverse'):
+        w, u = _wy_solve(a_kk, beta[..., None] * k * jnp.exp(g).astype(dtype),
+                         beta[..., None] * v)
     last = g[..., -1:, :]
     return (w, u, a_qk, q * jnp.exp(g).astype(dtype),
             k * jnp.exp(last - g).astype(dtype), jnp.exp(last[..., 0, :]))

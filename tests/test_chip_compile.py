@@ -608,7 +608,7 @@ def test_the_kimi_shapes_are_what_the_model_has():
 
 def test_kda_scan_fwd_bwd_compiles(one_chip):
     """The chunked delta rule at the cell's shapes, forward and backward:
-    plain XLA (no custom call; the triangular solves are XLA's own), and
+    plain XLA (no Pallas kernel; the inverses are XLA's products), and
     it never holds a whole chunk's (position, position, channel) decays:
     its temporaries stay under half of one layer's of them (2.1 GB)."""
     from mxnet_tpu.ops.kda import kda_scan

@@ -8,9 +8,10 @@ reader finds.
 
 Tolerances: both sides are float32 on the CPU, where a product is a
 float32 product whatever the precision asked for; they differ in the
-order of their sums (a chunked delta rule with a triangular solve against
-a recurrence, a sorted grouped product against a dense loop over the
-experts), which is a few ulps of the largest term: rel 1e-4, with an abs
+order of their sums (a chunked delta rule that inverts its chunks by
+products against a recurrence or a float64 solve, a sorted grouped
+product against a dense loop over the experts), which is a few ulps of
+the largest term: rel 1e-4, with an abs
 of 1e-6 of the array's largest element (or of 1) for the elements that
 nearly cancel. Where a chunk's decays pass 88 the chunked form takes the
 difference of two running sums of that size, so its decays carry an error
@@ -127,6 +128,89 @@ def test_a_padded_position_neither_decays_nor_writes():
 def test_a_chunk_that_is_no_multiple_of_a_block_is_refused():
     with pytest.raises(ValueError, match='no multiple of 16'):
         kda.kda_scan(*rule_inputs(24), chunk_size=24)
+
+
+def chunk_system(chunk, decay, beta, alike=0.0, seed=0, systems=6, width=32):
+    """``A_kk`` (systems, C, C) and the right-hand sides ``beta k o e^G``
+    and ``beta v`` (systems, C, width) of one chunk a system, as the rule
+    makes them; ``alike`` mixes each key with the one before it, as a
+    short convolution makes neighbours alike."""
+    rng = np.random.default_rng(seed)
+    k = rng.normal(0, 1, (systems, chunk, width))
+    for i in range(1, chunk):
+        k[:, i] = alike * k[:, i - 1] + (1 - alike) * k[:, i]
+    k = jnp.asarray(k / np.linalg.norm(k, axis=-1, keepdims=True),
+                    jnp.float32)
+    g = jnp.cumsum(-jnp.asarray(rng.uniform(*decay, k.shape), jnp.float32),
+                   axis=-2)
+    b = jnp.asarray(rng.uniform(*beta, (systems, chunk, 1)), jnp.float32)
+    v = jnp.asarray(rng.normal(0, 1, k.shape), jnp.float32)
+    a_kk = b * kda._decayed_products(k, k, g, strict=True)
+    return a_kk, b * k * jnp.exp(g), b * v
+
+
+@pytest.mark.parametrize('chunk, decay, beta, alike', [
+    (4, (0.001, 0.5), (0.0, 1.0), 0.0),
+    (16, (0.001, 0.5), (0.0, 1.0), 0.0),
+    (32, (0.001, 0.5), (0.0, 1.0), 0.0),
+    (64, (0.001, 0.5), (0.0, 1.0), 0.0),
+    (64, (3.0, 20.0), (0.0, 1.0), 0.0),          # decays pass 88
+    (64, (0.001, 0.5), (0.0, 1e-3), 0.0),
+    (64, (1e-4, 1e-3), (0.999, 1.0), 0.0),
+    # neighbours alike: the powers of A_kk grow like binomials here
+    (64, (1e-3, 0.1), (0.3, 0.7), 0.9),
+], ids=['chunk_4', 'chunk_16', 'chunk_32', 'chunk_64', 'strong_decays',
+        'beta_near_0', 'beta_near_1', 'neighbours_alike'])
+def test_the_inverse_by_products_is_the_float64_solve(chunk, decay, beta,
+                                                      alike):
+    a_kk, rk, rv = chunk_system(chunk, decay, beta, alike)
+    if decay[0] > 1:
+        assert (np.asarray(rk) == 0).any()       # e^G underflows
+    got = jax.jit(kda._wy_solve)(a_kk, rk, rv)
+    eye = np.eye(chunk)
+    system = eye + np.asarray(a_kk, np.float64)
+    for g, r in zip(got, (rk, rv)):
+        close(g, np.linalg.solve(system, np.asarray(r, np.float64)))
+    close(kda._unit_lower_inverse(a_kk), np.linalg.inv(system))
+
+
+def test_the_solves_own_backward_is_the_triangular_solves():
+    """Every gradient of ``(I + N)^-1 [Rk | Rv]`` as JAX gives them
+    through ``solve_triangular``; what lies on and above N's diagonal is
+    read by neither and gets no gradient."""
+    a_kk, rk, rv = chunk_system(64, (0.001, 0.5), (0.0, 1.0), alike=0.5)
+    rng = np.random.default_rng(1)
+    above = jnp.asarray(np.triu(rng.normal(0, 1, a_kk.shape)), jnp.float32)
+    n = a_kk + above
+    cot = [jnp.asarray(rng.normal(0, 1, r.shape), jnp.float32)
+           for r in (rk, rv)]
+    eye = jnp.eye(64, dtype=jnp.float32)
+    by_solve = lambda n, rk, rv: [jax.scipy.linalg.solve_triangular(
+        eye + n, r, lower=True, unit_diagonal=True) for r in (rk, rv)]
+    grads = lambda fn: jax.jit(jax.grad(
+        lambda *a: sum((y * c).sum() for y, c in zip(fn(*a), cot)),
+        argnums=(0, 1, 2)))(n, rk, rv)
+    got = grads(kda._wy_solve)
+    with jax.default_matmul_precision('highest'):
+        want = grads(by_solve)
+        for g, w in zip(kda._wy_solve(n, rk, rv), by_solve(n, rk, rv)):
+            close(g, w)
+    for name, g, w in zip(('N', 'Rk', 'Rv'), got, want):
+        close(g, w, err_msg=name)
+    assert not np.triu(np.asarray(got[0])).any()
+
+
+def test_no_triangular_solve_is_left_in_the_rule():
+    """The value and every gradient of the rule in chunks of 64, lowered
+    for the TPU (the CPU lowers a solve to LAPACK's ``trsm``): the inverse
+    is products, in the forward and in the backward."""
+    args = rule_inputs(64, batch=1, heads=1, width=8, values=8)
+    traced = jax.jit(jax.value_and_grad(
+        lambda *a: (kda.kda_scan(*a, chunk_size=64) ** 2).sum(),
+        argnums=tuple(range(5)))).trace(*args)
+    assert 'triangular_solve' not in str(traced.jaxpr)
+    text = traced.lower(lowering_platforms=('tpu',)).as_text(dialect='hlo')
+    assert ' dot(' in text and 'triangular-solve' not in text
 
 
 # ------------------------------------------------------------ the zoo model
