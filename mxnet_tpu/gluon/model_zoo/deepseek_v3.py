@@ -115,9 +115,10 @@ class DeepseekV3Config:
 _ROPE = Op('rope', _rope)
 
 
-def _rotary(x, theta):
+def _rotary(x, theta, rotate_half=False):
     """``_rope`` on an NDArray (B, S, H, d), eager or traced."""
-    return apply_op(_ROPE, [x], lambda raw: _rope(raw, theta))
+    return apply_op(_ROPE, [x], lambda raw: _rope(
+        raw, theta, rotate_half=rotate_half))
 
 
 class MLAttention(HybridBlock):

@@ -68,11 +68,15 @@ class RMSNorm(HybridBlock):
         return npx.rms_norm(x, self.weight.data(), eps=self._eps)
 
 
-def _rope(x, theta, offset=0):
+def _rope(x, theta, offset=0, rotate_half=False):
     """Apply rotary position embeddings to (B, S, H, Dh) — interleaved
     even/odd-pair convention (NOT HuggingFace's rotate-half: converting HF
     checkpoints requires their q/k weight permutation). Pure function of
     shape: folds into the jit as constants.
+
+    ``rotate_half`` takes HuggingFace's convention instead: channel j is
+    paired with channel j + Dh/2 (the two halves rotate together), as
+    LFM2 publishes it.
 
     ``offset`` is a scalar (shared position shift — prefill / lockstep
     decode) or a ``(B,)`` array of per-row positions (continuous-batching
@@ -88,12 +92,18 @@ def _rope(x, theta, offset=0):
     ang = pos[..., None] * inv[None, None, :]          # (1|B, S, Dh/2)
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
-    x1, x2 = x[..., ::2], x[..., 1::2]
+    if rotate_half:
+        x1, x2 = x[..., :Dh // 2], x[..., Dh // 2:]
+    else:
+        x1, x2 = x[..., ::2], x[..., 1::2]
     xf1 = x1.astype(jnp.float32)
     xf2 = x2.astype(jnp.float32)
     r1 = xf1 * cos - xf2 * sin
     r2 = xf2 * cos + xf1 * sin
-    out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
+    if rotate_half:
+        out = jnp.concatenate([r1, r2], axis=-1)
+    else:
+        out = jnp.stack([r1, r2], axis=-1).reshape(x.shape)
     return out.astype(x.dtype)
 
 

@@ -1,5 +1,6 @@
-"""State-space layers: ``Mamba2Mixer``, the Mamba-2 block of a hybrid
-decoder (``ops/ssm.py`` is the convolution and the scan)."""
+"""State-space and convolution layers: ``Mamba2Mixer``, the Mamba-2 block
+of a hybrid decoder, and ``ShortConv``, LFM2's gated short convolution
+(``ops/ssm.py`` is the convolutions and the scan)."""
 
 import math
 
@@ -13,7 +14,7 @@ from ..parameter import Parameter
 from ... import initializer
 from ...ops.registry import Op, apply_op
 
-__all__ = ['Mamba2Mixer']
+__all__ = ['Mamba2Mixer', 'ShortConv']
 
 
 class _Filled(initializer.Initializer):
@@ -152,3 +153,37 @@ class Mamba2Mixer(HybridBlock):
                 xbc[..., inner + g * n:].reshape(b, t, g, n),
                 self.D.data(), chunk_size=self._chunk)
         return self.out_proj(self.norm(y.reshape(b, t, inner), z))
+
+
+class ShortConv(HybridBlock):
+    """LFM2's gated short convolution (Liquid AI 2025), (B, T, units) ->
+    (B, T, units)::
+
+        [b; c; x] = in_proj(u)                 three blocks of units
+        s_t = sum_k w[:, k] (b x)_{t - K + 1 + k}   depthwise, causal
+        out = out_proj(c s)
+
+    no bias anywhere and **no activation**; positions before a row's
+    start read as zero. The gates and the taps are ``npx.short_conv``.
+    Leaves: ``in_proj.weight`` (3 units, units), ``conv.weight`` (units,
+    kernel), uniform in +-1/sqrt(kernel) as ``Conv1d`` draws it, and
+    ``out_proj.weight`` (units, units).
+    """
+
+    def __init__(self, units, kernel=3, weight_initializer=None, **kwargs):
+        super().__init__(**kwargs)
+        self._units = units
+        self.in_proj = Dense(3 * units, use_bias=False, flatten=False,
+                             in_units=units,
+                             weight_initializer=weight_initializer)
+        self.conv = _CausalConv(units, kernel, use_bias=False)
+        self.out_proj = Dense(units, use_bias=False, flatten=False,
+                              in_units=units,
+                              weight_initializer=weight_initializer)
+
+    def forward(self, u):
+        n = self._units
+        bcx = self.in_proj(u)
+        return self.out_proj(_op('short_conv', bcx[..., :n],
+                                 bcx[..., n:2 * n], bcx[..., 2 * n:],
+                                 self.conv.weight.data()))

@@ -1,5 +1,6 @@
 """State-space layers (Mamba-2): the causal depthwise convolution before
-the scan, and the selective scan itself in its chunked (SSD) form.
+the scan, and the selective scan itself in its chunked (SSD) form; and
+the gated short convolution of LFM2, which shares the convolution's taps.
 
 NEW capability over the reference (it has no state-space layer). The
 recurrence of one head, state ``S`` of (P, N), from zero at the start of
@@ -32,25 +33,46 @@ from .registry import register
 
 CONV_SCOPE = 'mx.ssm_conv'      # the depthwise causal convolution + silu
 SCAN_SCOPE = 'mx.ssm_scan'      # the chunked selective scan
+SHORT_CONV_SCOPE = 'mx.short_conv'  # LFM2's two gates and the taps
+
+
+def causal_taps(x, weight):
+    """The causal depthwise convolution along the positions, no bias.
+
+    x: (B, T, C). weight: (C, K), tap K - 1 on the position itself and
+    tap 0 on the one K - 1 before it (a ``Conv1d(C, C, K, groups=C,
+    padding=K - 1)`` cut to its first T outputs). Positions before a
+    row's start read as zero. Returns (B, T, C).
+    """
+    taps = weight.shape[1]
+    t = x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+    return sum(padded[:, k:k + t] * weight[:, k] for k in range(taps))
 
 
 @register('ssm_conv', f32_only=True)
 def ssm_conv(x, weight, bias=None):
-    """silu of a causal depthwise convolution along the positions.
-
-    x: (B, T, C). weight: (C, K), tap K - 1 on the position itself and
-    tap 0 on the one K - 1 before it (a ``Conv1d(C, C, K, groups=C,
-    padding=K - 1)`` cut to its first T outputs). bias: (C,) or None.
-    Positions before a row's start read as zero. Returns (B, T, C).
-    """
+    """silu of a causal depthwise convolution along the positions
+    (:func:`causal_taps`); bias: (C,) or None. Returns (B, T, C)."""
     with jax.named_scope(CONV_SCOPE):
-        taps = weight.shape[1]
-        t = x.shape[1]
-        padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-        out = sum(padded[:, k:k + t] * weight[:, k] for k in range(taps))
+        out = causal_taps(x, weight)
         if bias is not None:
             out = out + bias
         return jax.nn.silu(out).astype(x.dtype)
+
+
+@register('short_conv', f32_only=True)
+def short_conv(b, c, x, weight):
+    """LFM2's gated short convolution: ``c * conv(b * x)``, the
+    convolution :func:`causal_taps` with no bias and **no activation**.
+
+    b, c, x: (B, T, C), the input gate, the output gate and the values.
+    weight: (C, K). Both gates lie inside the scope, so that its device
+    time is the whole of the mixer but its two projections. Returns
+    (B, T, C).
+    """
+    with jax.named_scope(SHORT_CONV_SCOPE):
+        return (c * causal_taps(b * x, weight)).astype(x.dtype)
 
 
 @jax.checkpoint

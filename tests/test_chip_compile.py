@@ -624,3 +624,72 @@ def test_kda_scan_fwd_bwd_compiles(one_chip):
     assert 'tpu_custom_call' not in compiled.as_text()
     whole_chunk = 4 * KL_ROWS * KL_SEQ * KL_HEADS * KL_CHUNK * KL_D
     assert compiled.memory_analysis().temp_size_in_bytes < whole_chunk / 2
+
+
+# ------------------------------------------------------------------------
+# The lfm2_moe train path at lfm2_24b_a2b's widths
+# (chipbench/configs/lfm2_24b_a2b.json: 2 rows x 2048 positions, 2048
+# wide; short convolutions of 3 taps; 32 query and 8 key/value heads of
+# 64).
+
+LF_ROWS, LF_SEQ, LF_UNITS, LF_HEADS, LF_KV = 2, 2048, 2048, 32, 8
+
+
+def test_the_lfm2_shapes_are_what_the_model_has():
+    """469.3 M parameters under Adam, by the zoo's leaves and by the
+    benchmark's count, no array made; the head is the embedding."""
+    import json
+    import os
+    from mxnet_tpu.gluon.model_zoo.lfm2_moe import (Lfm2MoeConfig,
+                                                    Lfm2MoeForCausalLM)
+    from chipbench.flops.lfm2_moe import moved_param_count
+    here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(here, 'chipbench', 'configs',
+                           'lfm2_24b_a2b.json')) as f:
+        cfg = json.load(f)
+    params = Lfm2MoeForCausalLM(Lfm2MoeConfig(**cfg)).collect_params()
+    assert params['model.layers0.conv.in_proj.weight'].shape == \
+        (3 * LF_UNITS, LF_UNITS)
+    assert params['model.layers1.self_attn.k_proj.weight'].shape == \
+        (LF_KV * 64, LF_UNITS)
+    assert not any('lm_head' in n for n in params)
+    assert sum(math.prod(p.shape) for p in params.values()
+               if p.grad_req != 'null') == moved_param_count(cfg) \
+        == 469_284_992
+
+
+def test_short_conv_fwd_bwd_compiles(one_chip):
+    """The gates and the taps at the cell's shapes, forward and backward:
+    plain XLA, no custom call."""
+    from mxnet_tpu.ops.ssm import short_conv
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                            sharding=one_chip)
+    x = s(LF_ROWS, LF_SEQ, LF_UNITS)
+
+    def loss(b, c, x, w):
+        return (short_conv(b, c, x, w) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        x, x, x, s(LF_UNITS, 3)).compile().as_text()
+    assert 'tpu_custom_call' not in text
+
+
+def test_lfm2_attention_takes_the_flash_pair(one_chip, on_tpu):
+    """32 query heads of 64 over 2048 positions, K and V repeated from 8
+    heads as the zoo's layer does: two custom calls, forward and
+    backward, and no (heads, 2048, 2048) tensor."""
+    from mxnet_tpu.ops.contrib import multi_head_attention
+    s = lambda heads: jax.ShapeDtypeStruct(
+        (LF_ROWS, LF_SEQ, heads * 64), jnp.float32, sharding=one_chip)
+
+    def loss(q, k, v):
+        rep = lambda a: jnp.repeat(
+            a.reshape(LF_ROWS, LF_SEQ, LF_KV, 64), LF_HEADS // LF_KV,
+            axis=2).reshape(LF_ROWS, LF_SEQ, -1)
+        out = multi_head_attention(q, rep(k), rep(v), LF_HEADS, causal=True)
+        return (out ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        s(LF_HEADS), s(LF_KV), s(LF_KV)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert f'{LF_HEADS},{LF_SEQ},{LF_SEQ}]' not in text
